@@ -203,7 +203,6 @@ class Tensor:
         out = self
         if device is not None:
             kind = device.split(":")[0]
-            from .device import _platform_of
             if kind in ("tpu", "gpu", "cuda", "xla"):
                 want = "tpu"  # accelerator strings route to the TPU backend
             elif kind == "cpu":
@@ -212,7 +211,7 @@ class Tensor:
                 raise ValueError(
                     f"Tensor.to({device!r}): unknown device kind {kind!r} "
                     "(supported: tpu/gpu/cuda/xla → TPU, cpu)")
-            targets = [d for d in jax.devices() if _platform_of(d) == want]
+            targets = [d for d in jax.devices() if d.platform == want]
             if not targets and want == "cpu":
                 try:
                     targets = jax.devices("cpu")

@@ -23,7 +23,6 @@ __all__ = ["set_device", "get_device", "get_all_devices", "device_count",
 
 
 def _dev(device=None):
-    from ..core.device import _platform_of
     if device is None:
         return jax.devices()[0]
     if isinstance(device, int):
@@ -31,7 +30,7 @@ def _dev(device=None):
     if isinstance(device, str):
         kind, _, idx = device.partition(":")
         want = "cpu" if kind == "cpu" else "tpu"
-        devs = [d for d in jax.devices() if _platform_of(d) == want]
+        devs = [d for d in jax.devices() if d.platform == want]
         if not devs and want == "cpu":
             devs = jax.devices("cpu")
         if not devs:

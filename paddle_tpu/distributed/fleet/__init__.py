@@ -90,8 +90,20 @@ def distributed_model(model):
     """Reference: fleet/model.py:32. With mp/pp the parallel layers already
     carry their shardings; pure-dp wraps in DataParallel, routing the
     strategy's comm-overlap knobs (buffer sizes, overlap toggle, quantized
-    transport) into the bucket scheduler."""
+    transport) into the bucket scheduler.
+
+    Parameters no parallel layer placed (norms, the position table) were
+    created on the default device; they are committed to the mesh,
+    replicated, here. A staged step returns them in that layout anyway,
+    and an input layout that changes after the first step costs a second
+    compile of the whole program."""
     hcg = get_hybrid_communicate_group()
+    from jax.sharding import NamedSharding, PartitionSpec
+    from ..placement import place_global
+    replicated = NamedSharding(hcg.mesh, PartitionSpec())
+    for p in model.parameters():
+        if not isinstance(p._data.sharding, NamedSharding):
+            p._data = place_global(p._data, replicated)
     if hcg.get_model_parallel_world_size() == 1 and \
             hcg.get_pipe_parallel_world_size() == 1:
         from ..parallel import DataParallel

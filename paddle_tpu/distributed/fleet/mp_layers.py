@@ -20,7 +20,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...core.dispatch import apply
 from ...nn import Layer, functional as F
-from ...nn import initializer as I
 from ..topology import get_hybrid_communicate_group
 
 __all__ = ["VocabParallelEmbedding", "ColumnParallelLinear",
@@ -97,9 +96,11 @@ class VocabParallelEmbedding(Layer):
         self._embedding_dim = embedding_dim
         mesh, axis = _mp_mesh(mp_group)
         self._mesh, self._axis = mesh, axis
+        # same default initializer as nn.Embedding (reference
+        # mp_layers.py:47 passes none either): a tensor-parallel model
+        # draws the weights its single-device twin draws from one seed
         self.weight = self.create_parameter(
-            shape=[num_embeddings, embedding_dim], attr=weight_attr,
-            default_initializer=I.XavierNormal())
+            shape=[num_embeddings, embedding_dim], attr=weight_attr)
         _place(self.weight, mesh, P(axis, None))
 
     def forward(self, x):

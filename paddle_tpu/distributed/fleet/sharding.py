@@ -158,8 +158,10 @@ class DygraphShardingOptimizer:
                     return place_global(arr, NamedSharding(
                         mesh, _merged(p, arr.shape, True)))
                 return jnp.asarray(arr)
-            if created and arr.ndim > 0:
-                # merge the ZeRO axis with the param's TP dims (see hooks)
+            if created:
+                # merge the ZeRO axis with the param's TP dims (see hooks);
+                # scalars (beta_pow) land replicated on the mesh, the
+                # layout the staged step hands them back in
                 arr = place_global(arr, NamedSharding(
                     mesh, _merged(p, arr.shape, True)))
                 optimizer._accumulators[name][id(p)] = arr

@@ -216,12 +216,9 @@ def _flash_eligible(query, key, attn_mask, dropout_p, training, is_causal):
         return False
     if query.shape[-1] > 128 or query.ndim != 4:
         return False
-    import jax as _jax
-
-    from ...core.device import _platform_of
-    if _platform_of(_jax.devices()[0]) != "tpu":
-        return False
     from ...ops.pallas import _common as _gate
+    if not _gate.on_tpu():
+        return False
     return _gate.pallas_default(
         "flash_attention", _gate.shape_sig(query, key), allow_nearest=True)
 
@@ -244,7 +241,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     def fwd(q, k, v, *m):
         qf = q.astype(jnp.float32)
         kf = k.astype(jnp.float32)
-        scale = 1.0 / np.sqrt(q.shape[-1])
+        # np.float32: under jax_enable_x64 a bare numpy float64 scalar
+        # promotes the [B, H, S, S] scores, and everything after them, to
+        # f64 — which the TPU emulates
+        scale = np.float32(1.0 / np.sqrt(q.shape[-1]))
         # [B, S, H, D] -> [B, H, S, D]
         qt = jnp.swapaxes(qf, 1, 2)
         kt = jnp.swapaxes(kf, 1, 2)

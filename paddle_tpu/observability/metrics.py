@@ -484,23 +484,26 @@ def observe_replication(head_seq, acked_seq, shipped=0, torn=0):
 
 # ---------------------------------------------------------- hardware table
 
-def peak_flops(device_kind=""):
-    """Per-chip bf16 peak FLOP/s by device kind — the ONE copy of the
-    table bench.py and the MFU gauge share. ``PADDLE_TPU_PEAK_FLOPS``
-    overrides (useful on CPU plumbing runs)."""
-    env = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    kind = str(device_kind).lower()
-    if "v5 lite" in kind or "v5e" in kind:
-        return 197e12
-    if "v5p" in kind or "v5" in kind:
-        return 459e12
-    if "v4" in kind:
-        return 275e12
-    if "v6" in kind or "trillium" in kind:
-        return 918e12
-    return 197e12
+# Published dense bf16 peak of one chip, keyed by the ``device_kind`` jax
+# reports for it. The ONE copy: bench.py and the in-run MFU gauge both
+# read it.
+_PEAK_BF16_FLOPS = {
+    # device_kind: (FLOP/s, source)
+    "TPU v4": (275e12, 'Google Cloud documentation, "TPU v4"'),
+    "TPU v5 lite": (197e12, 'Google Cloud documentation, "TPU v5e"'),
+    "TPU v5": (459e12, 'Google Cloud documentation, "TPU v5p"'),
+    "TPU v6 lite": (918e12, 'Google Cloud documentation, "TPU v6e"'),
+}
+
+
+def peak_flops(device_kind):
+    """Per-chip bf16 peak FLOP/s of ``device_kind``. A device that is not
+    in the table raises ``KeyError``: a utilization against a guessed
+    peak is not a measurement."""
+    try:
+        return _PEAK_BF16_FLOPS[str(device_kind)][0]
+    except KeyError:
+        raise KeyError(
+            f"no published peak for device kind {device_kind!r} (known: "
+            f"{sorted(_PEAK_BF16_FLOPS)}); add it to "
+            "observability/metrics.py with its source") from None

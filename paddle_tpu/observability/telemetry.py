@@ -37,6 +37,7 @@ MFU peak) and only when metrics are on.
 """
 from __future__ import annotations
 
+import sys
 import time
 
 from . import metrics as _metrics
@@ -246,14 +247,18 @@ class TelemetryCallback:
 
     # ---- MFU plumbing ----------------------------------------------------
     def _peak_flops(self):
+        """Published peak of this process's device — or 0.0, which leaves
+        ``mfu_pct`` unset, on a device the table does not list (the CPU
+        a test runs on has no peak to be a fraction of)."""
         if self._peak is None:
-            kind = ""
+            import jax
             try:
-                import jax
-                kind = jax.devices()[0].device_kind
-            except Exception:
-                pass
-            self._peak = _metrics.peak_flops(kind)
+                self._peak = _metrics.peak_flops(
+                    jax.devices()[0].device_kind)
+            except KeyError as e:
+                self._peak = 0.0
+                print(f"[telemetry] mfu_pct stays unset: {e.args[0]}",
+                      file=sys.stderr, flush=True)
         return self._peak
 
     def _probe_flops(self, x):

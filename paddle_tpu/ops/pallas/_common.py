@@ -50,6 +50,8 @@ _MODES = ("xla", "pallas", "auto")
 # (kernel name, shape sig) -> {"backend", "xla_ms", "pallas_ms", "reason"}
 _verdicts: dict = {}
 _cache_loaded = False
+# (kernel, sig) pairs whose on-TPU Pallas failure was already printed
+_failures_logged: set = set()
 
 # auto-mode behavior when NO verdict (exact or nearest) exists for a shape.
 # flash_attention is the incumbent winner (it carried the MFU headline
@@ -66,6 +68,7 @@ def _reset_state():
     persistent cache file was loaded."""
     global _cache_loaded
     _verdicts.clear()
+    _failures_logged.clear()
     _cache_loaded = False
 
 
@@ -153,10 +156,7 @@ def kernels_mode() -> str:
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def shape_sig(*arrays):
@@ -246,11 +246,14 @@ def ab_gate(kernel, xla_fn, pallas_fn, args, repeats=10, record=True,
     """Time the jitted XLA reference vs the Pallas kernel at this exact
     shape and cache the verdict. Off-TPU the Pallas leg is skipped
     (interpret mode measures the emulator, not the chip) and XLA wins by
-    default; a Pallas failure (unsupported shape/dtype) also demotes.
+    default. A Pallas leg that raises ON the TPU is not a measured loss:
+    the row carries ``"failed": True``, the error goes to stderr once per
+    (kernel, shape), and XLA serves — callers that must not run degraded
+    (``chip_smoke.py``) check the flag in :func:`gate_report`.
     ``sig`` overrides the recorded signature — it must match what the
     kernel's call site queries (e.g. flash attention gates on (q, k)
     while the timing needs (q, k, v)).
-    -> ``{"backend", "xla_ms", "pallas_ms", "reason"}``."""
+    -> ``{"backend", "xla_ms", "pallas_ms", "reason"[, "failed"]}``."""
     for a in args:
         if isinstance(a, jax.core.Tracer):
             raise RuntimeError(
@@ -278,8 +281,16 @@ def ab_gate(kernel, xla_fn, pallas_fn, args, repeats=10, record=True,
         return row
     try:
         pallas_ms = _time_jitted(jax.jit(pallas_fn), args, repeats)
-    except Exception as e:  # unsupported shape/dtype: gate stays on XLA
-        row["reason"] = f"pallas failed: {type(e).__name__}: {e}"[:160]
+    except Exception as e:
+        row["failed"] = True
+        row["reason"] = f"pallas FAILED on tpu: {type(e).__name__}: {e}"[:160]
+        if (kernel, sig) not in _failures_logged:
+            _failures_logged.add((kernel, sig))
+            import sys
+            print(f"[kernels] PALLAS KERNEL FAILED ON TPU — {kernel} at "
+                  f"{sig}: {type(e).__name__}: {e}\n[kernels] serving the "
+                  "XLA reference instead; this is a defect, not an A/B "
+                  "verdict", file=sys.stderr, flush=True)
         if record:
             record_verdict(kernel, sig, row)
         return row

@@ -143,13 +143,15 @@ class Optimizer:
                 if self._dist_grad_hook is not None:
                     garr = self._dist_grad_hook(p, garr)
                 new_w = self._update(p, w, garr, plr, group)
+                if use_master:
+                    # the f32 master is optimizer state and keeps the
+                    # (ZeRO-sharded) layout of the update; the out hook
+                    # regathers only the copy the forward reads
+                    self._master_weights[id(p)] = new_w
+                    new_w = new_w.astype(p._data.dtype)
                 if self._dist_out_hook is not None:
                     new_w = self._dist_out_hook(p, new_w)
-                if use_master:
-                    self._master_weights[id(p)] = new_w
-                    p._data = new_w.astype(p._data.dtype)
-                else:
-                    p._data = new_w
+                p._data = new_w
         self._global_step += 1
 
     def _update(self, p, w, g, lr, group):

@@ -276,7 +276,7 @@ class ServingEngine:
         # the pairs it has served tracked in _prefill_fns so the
         # bounded-compile contract is observable (tested); steady-state
         # prefill dispatch is one compiled-program launch instead of the
-        # eager per-op tunnel that used to sit on TTFT (ROADMAP item 3)
+        # eager per-op dispatch that used to sit on TTFT (ROADMAP item 3)
         self._prefill_fn = self._build_prefill()
         self._prefill_fns = {}
         # the chunk step doubles as the prefix-hit tail prefill (both are
@@ -1303,6 +1303,34 @@ class ServingEngine:
         return False
 
     # --------------------------------------------------------------- stats
+    def compiled_text(self, total_tokens=None):
+        """Optimized-HLO text of the ragged round program at one token
+        pad (default: the smallest pad served so far) — the serving twin
+        of ``StaticFunction.compiled_text``: a caller can assert which
+        attention the round really compiled (``tpu_custom_call`` for the
+        Pallas kernel) instead of trusting ``attn_backend``."""
+        if not (self.ragged and self._jit):
+            raise RuntimeError(
+                "compiled_text() reads the jitted ragged round program; "
+                "this engine runs bucketed or un-jitted")
+        if total_tokens is None:
+            if not self._ragged_shapes:
+                raise RuntimeError(
+                    "no ragged round has run yet — call warm_ragged() or "
+                    "step() before compiled_text()")
+            total_tokens = min(self._ragged_shapes)
+        from ..jit.api import _aval
+        T, R = int(total_tokens), self.max_slots
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        return self._ragged_fn.lower(
+            [_aval(a) for a in self._param_arrays], i32(T), i32(R), i32(R),
+            i32(R), i32(R, self.max_pages),
+            [_aval(a) for a in self.kv.k],
+            [_aval(a) for a in self.kv.v]).compile().as_text()
+
     def stats(self):
         out = {
             "engine_id": self.engine_id,

@@ -10,8 +10,8 @@ runtime machinery diagnoses after one:
 * ``trace-purity`` (TP) — side effects baked into traced/cached programs
   (the stale `_jit_cache` replay class);
 * ``host-sync`` (HS) — blocking fetches on designated hot paths;
-* ``jax-compat`` (JC) — jax surfaces that must route through
-  ``core/jax_compat``;
+* ``jax-compat`` (JC) — deprecated ``jax.experimental`` spellings the
+  installed jax dropped;
 * ``donation`` (DN) — reads of buffers already donated to a jitted call;
 * ``locks`` (LK) — ABBA lock order, blocking calls under contended
   locks, signal/atexit-reachable acquisitions;

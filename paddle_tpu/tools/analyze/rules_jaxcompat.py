@@ -1,12 +1,12 @@
-"""jax-compat (JC) — jax surfaces that must route through core/jax_compat.
+"""jax-compat (JC) — the deprecated jax spellings the installed jax dropped.
 
-``core/jax_compat.py`` shims this image's jax 0.4.x: it publishes top-level
-``jax.shard_map`` (adapting the ``check_vma`` kwarg to the old ``check_rep``
-spelling), ``jax.lax.pcast``, and ``jax.enable_x64``.  Code that bypasses
-the shim — importing ``jax.experimental.shard_map`` directly, or passing
-``check_rep=`` straight through — works on exactly one runtime generation
-and breaks on the other.  These rules enforce the ROADMAP standing note
-mechanically: the shimmed spelling is the only one that works everywhere.
+This repo runs on one installation (jax 0.9): ``jax.shard_map`` with the
+``check_vma=`` kwarg, ``jax.lax.pcast`` and ``jax.enable_x64`` are all
+top-level there. The ``jax.experimental`` spellings they replaced
+(``jax.experimental.shard_map``, ``check_rep=``,
+``jax.experimental.enable_x64``) are gone or rejected on it, so code that
+uses them fails at import or at the first sharded call — on the chip,
+where it costs the most to find. These rules catch them at lint time.
 """
 from __future__ import annotations
 
@@ -18,16 +18,11 @@ FAMILY = "jax-compat"
 
 RULES = {
     "JC001": ("error", "direct jax.experimental.shard_map import"),
-    "JC002": ("error", "check_rep= passed to shard_map (pre-shim kwarg)"),
+    "JC002": ("error", "check_rep= passed to shard_map (removed kwarg)"),
     "JC003": ("error", "direct jax.experimental enable_x64 import"),
 }
 
-_SHIM_FILE = "core/jax_compat.py"  # the one place the raw surface is legal
-
-
 def run(ctx):
-    if ctx.pkg_relpath == _SHIM_FILE:
-        return []
     findings = []
     for node in ctx.nodes:
         if isinstance(node, ast.ImportFrom) and node.module:
@@ -35,23 +30,20 @@ def run(ctx):
                 findings.append(Finding(
                     file=ctx.relpath, line=node.lineno, col=node.col_offset,
                     rule="JC001", family=FAMILY, severity="error",
-                    message="direct `jax.experimental.shard_map` import "
-                            "bypasses core/jax_compat — only the shimmed "
-                            "`from jax import shard_map` works on every "
-                            "supported runtime",
-                    hint="use `from jax import shard_map` (the shim "
-                         "publishes the alias at package import)",
+                    message="`jax.experimental.shard_map` is the "
+                            "deprecated spelling — the installed jax "
+                            "ships `jax.shard_map`",
+                    hint="use `from jax import shard_map`",
                     source_line=ctx.src(node)))
             elif node.module == "jax.experimental" and any(
                     a.name == "enable_x64" for a in node.names):
                 findings.append(Finding(
                     file=ctx.relpath, line=node.lineno, col=node.col_offset,
                     rule="JC003", family=FAMILY, severity="error",
-                    message="direct `jax.experimental.enable_x64` import "
-                            "bypasses core/jax_compat — modern runtimes "
-                            "promoted it to `jax.enable_x64`",
-                    hint="use `jax.enable_x64` (the shim back-fills it on "
-                         "0.4.x)",
+                    message="`jax.experimental.enable_x64` is the "
+                            "deprecated spelling — the installed jax "
+                            "ships `jax.enable_x64`",
+                    hint="use `jax.enable_x64`",
                     source_line=ctx.src(node)))
         elif isinstance(node, ast.Attribute) \
                 and node.attr in ("shard_map", "enable_x64"):
@@ -60,16 +52,16 @@ def run(ctx):
                 findings.append(Finding(
                     file=ctx.relpath, line=node.lineno, col=node.col_offset,
                     rule="JC001", family=FAMILY, severity="error",
-                    message="attribute use of `jax.experimental.shard_map` "
-                            "bypasses core/jax_compat",
+                    message="attribute use of the deprecated "
+                            "`jax.experimental.shard_map`",
                     hint="use `jax.shard_map` / `from jax import shard_map`",
                     source_line=ctx.src(node)))
             elif dotted(node) == "jax.experimental.enable_x64":
                 findings.append(Finding(
                     file=ctx.relpath, line=node.lineno, col=node.col_offset,
                     rule="JC003", family=FAMILY, severity="error",
-                    message="attribute use of `jax.experimental.enable_x64` "
-                            "bypasses core/jax_compat",
+                    message="attribute use of the deprecated "
+                            "`jax.experimental.enable_x64`",
                     hint="use `jax.enable_x64`",
                     source_line=ctx.src(node)))
         elif isinstance(node, ast.Call) \
@@ -80,12 +72,9 @@ def run(ctx):
                         file=ctx.relpath, line=kw.value.lineno,
                         col=kw.value.col_offset,
                         rule="JC002", family=FAMILY, severity="error",
-                        message="`check_rep=` is the pre-shim kwarg — on a "
-                                "modern jax the native `jax.shard_map` "
-                                "rejects it with a TypeError; the shim "
-                                "adapts `check_vma=` to whichever runtime "
-                                "is installed",
-                        hint="pass `check_vma=` and let core/jax_compat "
-                             "translate",
+                        message="`check_rep=` is the removed kwarg — "
+                                "`jax.shard_map` rejects it with a "
+                                "TypeError",
+                        hint="pass `check_vma=`",
                         source_line=ctx.src(node)))
     return findings

@@ -1,6 +1,6 @@
-"""tpu-lint fixture: the shimmed spellings — zero findings expected."""
+"""tpu-lint fixture: the installed jax's spellings — zero findings expected."""
 import jax
-from jax import shard_map  # published by core/jax_compat.install()
+from jax import shard_map
 
 
 def build(mesh, impl, spec):
@@ -9,5 +9,5 @@ def build(mesh, impl, spec):
 
 
 def with_x64():
-    with jax.enable_x64():  # back-filled on 0.4.x by the shim
+    with jax.enable_x64():
         return jax.numpy.arange(3)

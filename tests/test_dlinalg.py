@@ -613,9 +613,8 @@ def test_bench_guarded_legs_keep_prior_json():
     raises must record its error rows WITHOUT dropping any prior leg's
     JSON, and a leg's soft ``<name>_ok: False`` must fail the run while
     keeping every row — so new bench legs can't regress the
-    keep-prior-legs contract. Run in a subprocess: importing bench.py
-    flips process-global jax config (compilation cache) the test suite
-    must not inherit."""
+    keep-prior-legs contract. Run in a subprocess: bench.py imports the
+    whole framework under its own environment."""
     code = """
 import json
 import bench
@@ -630,7 +629,7 @@ print("GUARD " + json.dumps({"ok": ok, "sub": sub}))
 """
     env = {k: v for k, v in os.environ.items()
            if not k.startswith(("PADDLE_TPU_", "PADDLE_TRAINER"))}
-    env.update({"JAX_PLATFORMS": "cpu", "PADDLE_TPU_BENCH_CPU": "1",
+    env.update({"JAX_PLATFORMS": "cpu",
                 "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
                 "PYTHONPATH": REPO})
     r = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,

@@ -849,6 +849,24 @@ def test_warm_ragged_precompiles_pad_schedule(tiny_model):
     assert wide.stats()["distinct_programs"] == before  # no mid-run compile
 
 
+def test_compiled_text_reads_the_round_program(tiny_model):
+    """The serving twin of StaticFunction.compiled_text(): the optimized
+    HLO of the ragged round at a served token pad — what chip_smoke.py
+    looks for ``tpu_custom_call`` in. It needs a round to have run, names
+    the pad it was asked for, and leaves the engine serving."""
+    eng = _engine(tiny_model, prefill_chunk=8, prefill_token_budget=8)
+    with pytest.raises(RuntimeError, match="warm_ragged"):
+        eng.compiled_text()
+    eng.warm_ragged()
+    small, large = eng.compiled_text(), eng.compiled_text(16)
+    assert "HloModule" in small and "tpu_custom_call" not in small
+    assert small != large               # one program per token pad
+    assert eng.stats()["distinct_programs"] == 2    # reading installs none
+    assert len(eng.generate([3, 1, 4], max_new_tokens=3)) == 3
+    with pytest.raises(RuntimeError, match="bucketed"):
+        _engine(tiny_model, ragged=False).compiled_text()
+
+
 def test_prefix_metrics_flow_through_registry(tiny_model):
     """Hit/miss/shared-page rows land in the PR-5 registry."""
     from paddle_tpu.observability import metrics as obsm
@@ -996,3 +1014,6 @@ def test_engine_install_sigterm_drains_and_exits_75(tiny_model,
         assert len(req.result(timeout=1)) == 3  # drained, not dropped
     finally:
         _signal.signal(_signal.SIGTERM, prev)
+        # the flag is process-wide: left set, the next guarded fit on this
+        # xdist worker exits 75 at its first preemption poll
+        _fault._preempt_event.clear()

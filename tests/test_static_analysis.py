@@ -494,11 +494,10 @@ def test_cli_json_exit7_and_schema_on_injected_violation():
 
 # ---- regression: the three real findings the first scan surfaced -----------
 
-def test_check_vma_routes_through_shim():
+def test_check_vma_is_the_installed_shard_map_kwarg():
     # serving/decode.py + ops/pallas/flash_attention.py passed check_rep=
-    # straight through; the fix passes check_vma= which core/jax_compat
-    # translates on 0.4.x and modern jax accepts natively — prove the
-    # shimmed call shape works on THIS runtime
+    # straight through; the fix passes check_vma= — prove the call shape
+    # JC002 steers to works on the installed jax
     import jax
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P

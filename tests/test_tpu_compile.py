@@ -1,0 +1,150 @@
+"""The main path's Pallas kernels compile for a TPU v5e that is described,
+not attached — at gpt_1p3b widths (hidden 2048, 16 heads x 128, ffn 8192,
+vocab 50304), the sizes ``chip_smoke.py`` runs on the chip.
+
+Interpret mode, which every other kernel test uses, cannot show what the
+chip's compiler refuses: a slice off the tiling, too much VMEM for one
+block. These compiles can, in about two seconds each and with no chip.
+A compile that passes is not a chip run and says nothing about results
+or speed.
+
+The topology is described inside a module-scoped fixture and nowhere
+else: the process that describes it loads libtpu and holds its lock until
+it exits, so no import, ``skipif`` or ``parametrize`` argument may do it —
+under several xdist workers every worker imports this file, and only the
+one that is handed it may load the library. For the same reason these
+tests stay in this one file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+H, HEADS, DH, FFN, VOCAB = 2048, 16, 128, 8192, 50304
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for an absent chip is written to the
+    # persistent cache but cannot be read back without one: keep these
+    # compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    """Compile ``fn`` for the described chip from shapes alone; -> the
+    optimized HLO text, which must hold the Mosaic kernel."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+# [B, S, H, D] attention operands: the smoke's sequence 2048, a sequence
+# that needs padding to the 128 block, and gpt_small's 12 x 64 heads
+@pytest.mark.parametrize("b,s,heads,dh", [
+    (2, 2048, HEADS, DH), (2, 1024, HEADS, DH), (2, 1000, HEADS, DH),
+    (2, 1024, 12, 64)])
+def test_flash_forward(one_chip, b, s, heads, dh):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
+    qkv = ((b, s, heads, dh), BF16)
+    _compile(lambda q, k, v: flash_attention_bshd(q, k, v, causal=True),
+             one_chip, qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("s", [2048, 1024])
+def test_flash_backward(one_chip, s):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
+    qkv = ((2, s, HEADS, DH), BF16)
+
+    def loss(q, k, v):
+        return flash_attention_bshd(q, k, v, causal=True) \
+            .astype(F32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    qkv, qkv, qkv)
+    # forward, dq and dk/dv are three kernels
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_layer_norm(one_chip):
+    from paddle_tpu.ops.pallas.layer_norm import layer_norm
+    _compile(lambda x, w, b: layer_norm(x, w, b), one_chip,
+             ((4096, H), BF16), ((H,), BF16), ((H,), BF16))
+
+
+def test_rms_norm(one_chip):
+    from paddle_tpu.ops.pallas.rms_norm import rms_norm
+    _compile(lambda x, w: rms_norm(x, w), one_chip,
+             ((4096, H), BF16), ((H,), BF16))
+
+
+# the MLP weight and the embedding table, f32 masters as AdamW holds them
+@pytest.mark.parametrize("shape", [(H, FFN), (VOCAB, H)])
+def test_fused_adamw(one_chip, shape):
+    from paddle_tpu.ops.pallas.fused_adamw import fused_adamw
+    t = (shape, F32)
+    _compile(lambda w, g, m, v: fused_adamw(
+        w, g, m, v, 2e-4, 0.9, 0.999, 1e-8, 0.01, 10.0, 1000.0),
+        one_chip, t, t, t, t)
+
+
+# serving pools [pages, page, KVH, D] and 2048 tokens of context a row
+POOL = ((512, 16, HEADS, DH), BF16)
+MAX_PAGES = 128
+
+
+def test_paged_decode_attention(one_chip):
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    b = 8
+    _compile(lambda q, k, v, bt, lens: paged_attention(q, k, v, bt, lens),
+             one_chip, ((b, HEADS, DH), BF16), POOL, POOL,
+             ((b, MAX_PAGES), jnp.int32), ((b,), jnp.int32))
+
+
+# the round sizes of the smoke's engine (8 slots + a 64-token chunk pads
+# to 128) and a larger one
+@pytest.mark.parametrize("tokens", [128, 256])
+def test_ragged_paged_attention(one_chip, tokens):
+    from paddle_tpu.ops.pallas.ragged_attention import \
+        ragged_paged_attention
+    rows = 8
+    row = ((rows,), jnp.int32)
+    _compile(lambda q, k, v, rs, rl, kl, bt: ragged_paged_attention(
+        q, k, v, rs, rl, kl, bt), one_chip,
+        ((tokens, HEADS, DH), BF16), POOL, POOL, row, row, row,
+        ((rows, MAX_PAGES), jnp.int32))
+
+
+def test_xla_reference_attention_stays_in_f32(one_chip):
+    """The XLA attention that stands in where flash is not eligible must
+    not widen to f64 under jax_enable_x64 (a numpy float64 scale did):
+    the TPU emulates f64, and one [2, 16, 2048, 2048] layer of it took
+    15 GiB by this compiler's count."""
+    import paddle_tpu  # noqa: F401  (turns jax_enable_x64 on)
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.nn import functional as F
+
+    def attend(q, k, v):
+        # flash is never eligible here: jax.devices() is the CPU
+        return F.scaled_dot_product_attention(
+            Tensor(q), Tensor(k), Tensor(v), is_causal=True)._data
+
+    qkv = jax.ShapeDtypeStruct((2, 2048, HEADS, DH), BF16,
+                               sharding=one_chip)
+    compiled = jax.jit(attend).lower(qkv, qkv, qkv).compile()
+    assert "f64[" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30

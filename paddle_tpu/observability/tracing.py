@@ -15,10 +15,51 @@ or programmatic :func:`start` / :func:`stop`. Disabled (the default),
 ``span()`` yields immediately off one module-global ``None`` check and
 event feeds return without allocating.
 
-Timestamps are ``time.time()`` µs — the same wall clock the flight
-recorder stamps, so collective events and spans line up in one timeline.
-Nesting needs no explicit parent ids: Perfetto nests same-thread "X"
-events by interval containment.
+Two clocks, stated plainly:
+
+* Every event in the buffer is stamped ``time.time()`` µs — the same wall
+  clock the flight recorder stamps, so collective events, request spans
+  and host spans of several processes line up in one waterfall. Nesting
+  needs no explicit parent ids: Perfetto nests same-thread "X" events by
+  interval containment.
+* The serving round and its phases (:class:`phase`) are ALSO recorded as
+  ``jax.profiler.TraceAnnotation`` (TraceMe level 1). While a profile is
+  being taken they land on ``/host:CPU`` of the same ``.xplane.pb`` as
+  the device's ops, on the profiler's clock, so a device idle gap can be
+  laid against the host phase that overlaps it. With no profile running
+  the annotation is one flag test; the buffer copy is there either way
+  (``PADDLE_TPU_TRACE=1`` alone, for hunting a slow round over many
+  untraced runs).
+
+The phase spans, named once, here (``serving/engine.py`` ``_step_ragged``
+and ``_serve_loop`` open them; ``cat`` is ``serving``):
+
+``decode_round``
+    one whole scheduler round. Args: ``round`` (the engine's step
+    counter), ``pad`` (the launch's padded token count), ``tokens`` (valid
+    tokens in it), ``row_lens`` / ``kv_lens`` (per launched row: query
+    tokens, context length after them; at most ``max_slots`` each),
+    ``decode_rows``, ``prefill_rows``, ``prefill_tokens``.
+``round.schedule``
+    ``scheduler.schedule()``, ``ensure_decode_capacity()``, admission and
+    eviction bookkeeping.
+``round.assemble``
+    the plan (decode rows, prefill chunks) and the numpy metadata.
+``round.launch``
+    the six host-to-device uploads and the call of the round's program.
+``round.fetch``
+    the token (and logit) fetch: the host blocked on the device.
+``round.emit``
+    sampling, ``complete_step`` (``on_token`` / ``on_done`` callbacks run
+    here, a closed loop's resubmits among them), prefill bookkeeping,
+    metrics hooks.
+``serve.idle_wait``
+    one ``_wake.wait(0.02)`` of the serve loop: no work pending.
+
+The five ``round.*`` phases follow one another inside their
+``decode_round`` and carry its ``round``, which ties a phase to its round
+and to the program launch it caused. A round that finds nothing to launch
+records no ``decode_round`` in the buffer.
 
 Request tracing (ISSUE 20): :func:`mint_context` mints a trace context
 (``{"tid": <hex id>, "ps": <parent span, 0 = root>}``) that rides the
@@ -33,7 +74,8 @@ process makes the SAME decision without extra wire bits). Everything
 else is dropped before export. Undecided traces still pending at export
 time are flushed as-is so a shutdown mid-request stays visible.
 
-Stdlib-only at import time.
+Stdlib-only at import time (``jax`` is imported by the first
+:class:`phase` that opens).
 """
 from __future__ import annotations
 
@@ -47,7 +89,8 @@ import threading
 import time
 import zlib
 
-__all__ = ["TraceBuffer", "span", "add_complete", "collective_event",
+__all__ = ["TraceBuffer", "span", "phase", "add_complete",
+           "collective_event",
            "mint_context", "req_event", "finish_request",
            "enabled", "get_buffer", "start", "stop", "export",
            "_reset_state"]
@@ -356,6 +399,82 @@ def span(name, cat="host", **args):
         yield buf
     finally:
         buf.add(name, t0, time.time() - t0, cat=cat, args=args or None)
+
+
+_ANNOTATION = None    # jax.profiler.TraceAnnotation; False: no jax here
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except Exception:
+            _ANNOTATION = False
+    return _ANNOTATION
+
+
+def _stats(args):
+    """An annotation's stats: a list rides as space-separated text (the
+    profiler splits an annotation's arguments on commas)."""
+    return {k: " ".join(map(str, v)) if isinstance(v, (list, tuple)) else v
+            for k, v in args.items()}
+
+
+class phase:
+    """One named host phase, recorded twice from one call: into ``buf``
+    (``time.time()``, as every event here) and as a
+    ``jax.profiler.TraceAnnotation``, which a running profile puts on
+    ``/host:CPU`` of its ``.xplane.pb`` on the device ops' clock.
+
+    The caller has passed the gate and hands in the buffer, so the off
+    path never reaches this class: ``with phase(buf, "serve.idle_wait"):``,
+    or ``p = phase(buf, name, round=n).open()`` ... ``p.set(pad=T)`` ...
+    ``p = p.then("round.launch")`` ... ``p.close()`` where the phases of
+    one round follow one another."""
+
+    __slots__ = ("buf", "name", "cat", "args", "t0", "_ann")
+
+    def __init__(self, buf, name, cat="serving", **args):
+        self.buf, self.name, self.cat, self.args = buf, name, cat, args
+        self._ann = None
+
+    def open(self):
+        ann = _ANNOTATION or _annotation()
+        if ann and ann.is_enabled():      # a profile is being taken
+            self._ann = ann(self.name, **_stats(self.args))
+            self._ann.__enter__()
+        self.t0 = time.time()
+        return self
+
+    def set(self, **args):
+        """Arguments known only once the phase is under way."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**_stats(args))
+
+    def close(self, record=True):
+        """``record=False`` leaves the buffer as it was (a round that
+        launched nothing); the annotation, once opened, is closed."""
+        dur = time.time() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if record:
+            self.buf.add(self.name, self.t0, dur, cat=self.cat,
+                         args=self.args)
+
+    def then(self, name):
+        """Close this phase and open the next one of the same round."""
+        self.close()
+        return phase(self.buf, name, self.cat, **self.args).open()
+
+    __enter__ = open
+
+    def __exit__(self, *exc):
+        self.close()
+        return False
 
 
 def add_complete(name, ts_s, dur_s, cat="host", tid=None, args=None):

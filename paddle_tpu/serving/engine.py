@@ -490,9 +490,14 @@ class ServingEngine:
         semantics identical to the bucketed chunk step) into ONE flat
         launch. -> decode tokens emitted."""
         # the ONE tracing gate of the round (standing contract: off =
-        # one check, no allocation, no call)
+        # one check, no allocation, no call). On, the round and its five
+        # phases are spans in the buffer and annotations on the
+        # profiler's clock (observability/tracing.py lists them)
         tr = _trc._TR if _trc._loaded else _trc._load()
-        t0 = time.time() if tr is not None else 0.0
+        rnd = ph = None
+        if tr is not None:
+            rnd = _trc.phase(tr, "decode_round", round=self._steps).open()
+            ph = _trc.phase(tr, "round.schedule", round=self._steps).open()
         admitted = self.scheduler.schedule()
         for req in admitted:
             self.metrics.on_admit(req)
@@ -503,6 +508,8 @@ class ServingEngine:
             self.metrics.on_evict(req)
         self._prefilling = [r for r in self._prefilling
                             if r.state == "prefilling"]
+        if ph is not None:
+            ph = ph.then("round.assemble")
         decode_rows = sorted(
             (r for r in self.scheduler.active.values()
              if r.state == "active"), key=lambda r: r.slot)
@@ -526,6 +533,9 @@ class ServingEngine:
             plan.append((req, take,
                          p[req.num_cached:req.num_cached + take]))
         if not plan:
+            if rnd is not None:
+                ph.close()
+                rnd.close(record=False)
             return 0
         R, maxp = self.max_slots, self.max_pages
         total = sum(take for _, take, _ in plan)
@@ -546,6 +556,11 @@ class ServingEngine:
         if T not in self._ragged_shapes:
             self._ragged_shapes.add(T)
             self._note_program(("ragged", T))
+        if rnd is not None:
+            n = len(plan)
+            rnd.set(pad=T, tokens=total, row_lens=row_lens[:n].tolist(),
+                    kv_lens=kv_lens[:n].tolist())
+            ph = ph.then("round.launch")
         nxt, row_logits, self.kv.k, self.kv.v = self._ragged_fn(
             self._param_arrays, jnp.asarray(tokens),
             jnp.asarray(row_starts), jnp.asarray(row_lens),
@@ -556,11 +571,15 @@ class ServingEngine:
                       >= len(prompts[req.request_id])]
         any_sampling = any(r.temperature > 0.0
                            for r in decode_rows + completing)
+        if ph is not None:
+            ph = ph.then("round.fetch")
         # tpu-lint: ok[HS002] designed sync: ONE batched token fetch per ragged round feeds host-side scheduling/sampling
         nxt = np.asarray(nxt)
         # tpu-lint: ok[HS002] designed sync: the logit rows ride the same per-round host sampling fetch
         logits_np = np.asarray(row_logits) \
             if (any_sampling or self.capture_logits is not None) else None
+        if ph is not None:
+            ph = ph.then("round.emit")
         if self.capture_logits is not None and decode_rows:
             cap = np.zeros((self.max_slots,) + logits_np.shape[1:],
                            logits_np.dtype)
@@ -600,14 +619,15 @@ class ServingEngine:
         if spent:
             self._chunk_tokens += spent
             self.metrics.on_prefill_chunk(spent)
-        if tr is not None:
-            now = time.time()
+        if rnd is not None:
+            ph.close()
             # engine-lane round span: batched, ONE per round, row counts
             # in args (the waterfall's decode cadence)
-            tr.add("decode_round", t0, now - t0, cat="serving",
-                   args={"decode_rows": len(by_slot),
-                         "prefill_rows": len(plan) - len(decode_rows),
-                         "prefill_tokens": spent})
+            rnd.set(decode_rows=len(by_slot),
+                    prefill_rows=len(plan) - len(decode_rows),
+                    prefill_tokens=spent)
+            rnd.close()
+            t0, now = rnd.t0, time.time()
             for req, take, _ in plan[len(decode_rows):]:
                 if req.trace is not None:
                     _trc.req_event(req.trace, "prefill_chunk", t0,
@@ -1155,7 +1175,13 @@ class ServingEngine:
                 if self.scheduler.has_work():
                     self.step()
                 else:
-                    self._wake.wait(0.02)
+                    # the loop's one tracing gate, as the round's
+                    tr = _trc._TR if _trc._loaded else _trc._load()
+                    if tr is None:
+                        self._wake.wait(0.02)
+                    else:
+                        with _trc.phase(tr, "serve.idle_wait"):
+                            self._wake.wait(0.02)
                     self._wake.clear()
             except Exception as e:
                 # a broken step is terminal, not a silent hang: fail every

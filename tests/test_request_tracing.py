@@ -12,6 +12,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -191,7 +192,7 @@ def test_tracing_off_structurally_zero_overhead(tiny_model, monkeypatch):
     counting-dict convention. One module gate check per round is the
     entire budget."""
     calls = {"req_event": 0, "finish_request": 0, "add": 0,
-             "req_add": 0}
+             "req_add": 0, "phase": 0}
 
     def count(key, ret=None):
         def h(*a, **k):
@@ -205,6 +206,8 @@ def test_tracing_off_structurally_zero_overhead(tiny_model, monkeypatch):
     monkeypatch.setattr(tracing.TraceBuffer, "add", count("add"))
     monkeypatch.setattr(tracing.TraceBuffer, "req_add",
                         count("req_add"))
+    # the round/phase primitive: constructing one is already a call
+    monkeypatch.setattr(tracing, "phase", count("phase"))
     from tests.test_serving import _engine
     eng = _engine(tiny_model)
     # direct-step path
@@ -216,9 +219,125 @@ def test_tracing_off_structurally_zero_overhead(tiny_model, monkeypatch):
     eng.start()
     r2 = eng.submit([5, 6, 7], max_new_tokens=3)
     assert len(r2.result(30)) == 3
+    time.sleep(0.06)                 # the idle loop ticks a few times
     eng.close()
     assert calls == {"req_event": 0, "finish_request": 0, "add": 0,
-                     "req_add": 0}
+                     "req_add": 0, "phase": 0}
+
+
+def test_phase_records_twice_from_one_call(monkeypatch):
+    """``phase`` writes the buffer event and opens/closes a profiler
+    annotation of the same name; lists ride as space-separated text
+    (the profiler splits an annotation's arguments on commas), late
+    arguments reach both, ``then`` hands the round on to the next phase,
+    and ``close(record=False)`` leaves the buffer alone."""
+    log = []
+
+    class Ann:
+        is_enabled = staticmethod(lambda: True)   # a profile is running
+
+        def __init__(self, name, **kw):
+            self.name = name
+            log.append(("init", name, kw))
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+        def set_metadata(self, **kw):
+            log.append(("meta", self.name, kw))
+
+    monkeypatch.setattr(tracing, "_ANNOTATION", Ann)
+    buf = tracing.TraceBuffer(rank=0)
+    rnd = tracing.phase(buf, "decode_round", round=7).open()
+    ph = tracing.phase(buf, "round.schedule", round=7).open()
+    rnd.set(pad=8, row_lens=[1, 3])
+    ph = ph.then("round.assemble")
+    ph.close()
+    rnd.close()
+    with tracing.phase(buf, "serve.idle_wait"):
+        pass
+    tracing.phase(buf, "decode_round", round=8).open().close(record=False)
+    assert log == [
+        ("init", "decode_round", {"round": 7}), ("enter", "decode_round"),
+        ("init", "round.schedule", {"round": 7}),
+        ("enter", "round.schedule"),
+        ("meta", "decode_round", {"pad": 8, "row_lens": "1 3"}),
+        ("exit", "round.schedule"),
+        ("init", "round.assemble", {"round": 7}),
+        ("enter", "round.assemble"), ("exit", "round.assemble"),
+        ("exit", "decode_round"),
+        ("init", "serve.idle_wait", {}), ("enter", "serve.idle_wait"),
+        ("exit", "serve.idle_wait"),
+        ("init", "decode_round", {"round": 8}), ("enter", "decode_round"),
+        ("exit", "decode_round")]
+    assert [(e["name"], e.get("args")) for e in buf.events] == [
+        ("round.schedule", {"round": 7}), ("round.assemble", {"round": 7}),
+        ("decode_round", {"round": 7, "pad": 8, "row_lens": [1, 3]}),
+        ("serve.idle_wait", None)]
+    assert all(e["cat"] == "serving" and e["ph"] == "X"
+               for e in buf.events)
+
+
+ROUND_PHASES = ["round.schedule", "round.assemble", "round.launch",
+                "round.fetch", "round.emit"]
+
+
+def test_tracing_on_round_phases(tiny_model):
+    """Tracing ON: every round yields ONE ``decode_round`` that says what
+    it launched (``pad``, ``tokens``, ``row_lens``, ``kv_lens``,
+    ``round``) with its five phases nested inside it in order, and an
+    idle serve loop yields ``serve.idle_wait``; the traced twin stays
+    token-identical."""
+    from tests.test_serving import _engine
+    prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3]
+    eng = _engine(tiny_model)
+    base = eng.generate(prompt, max_new_tokens=5)
+    eng.close()
+    buf = tracing.start()
+    try:
+        eng = _engine(tiny_model)
+        req = eng.submit(prompt, max_new_tokens=5)
+        while not req.done():
+            eng.step()
+        rounds_run = eng._steps
+        eng.start()                  # nothing pending: the loop idles
+        time.sleep(0.1)
+        eng.close()
+    finally:
+        tracing.stop()
+    assert req.result(1) == base
+    events = [e for e in buf.events if e.get("cat") == "serving"]
+    rounds = [e for e in events if e["name"] == "decode_round"]
+    assert [e["args"]["round"] for e in rounds] == list(range(rounds_run))
+    # round 0: the whole 10-token prompt as one prefill row, padded to 16
+    first = rounds[0]["args"]
+    assert (first["pad"], first["tokens"]) == (16, 10)
+    assert first["row_lens"] == [10] and first["kv_lens"] == [10]
+    assert first["prefill_rows"] == 1 and first["decode_rows"] == 0
+    # then one decode row a round: one token against a growing context
+    for i, e in enumerate(rounds[1:], 1):
+        a = e["args"]
+        assert (a["pad"], a["tokens"], a["row_lens"]) == (8, 1, [1])
+        assert a["kv_lens"] == [10 + i] and a["decode_rows"] == 1
+    eps = 1.0                        # µs: float rounding of ts + dur
+    for e in rounds:
+        inside = [c for c in events if c["name"].startswith("round.")
+                  and c["args"]["round"] == e["args"]["round"]]
+        assert [c["name"] for c in inside] == ROUND_PHASES
+        assert all(set(c["args"]) == {"round"} for c in inside)
+        assert e["ts"] - eps <= inside[0]["ts"]
+        for a, b in zip(inside, inside[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + eps
+        assert inside[-1]["ts"] + inside[-1]["dur"] <= \
+            e["ts"] + e["dur"] + eps
+        assert sum(c["dur"] for c in inside) <= e["dur"] + eps
+    idle = [e for e in events if e["name"] == "serve.idle_wait"]
+    assert idle and all(e["dur"] <= 0.5e6 for e in idle)
+    assert {e["name"] for e in events} == \
+        {"decode_round", "serve.idle_wait", *ROUND_PHASES}
 
 
 def test_tracing_on_greedy_parity(tiny_model, tmp_path):

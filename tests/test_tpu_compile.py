@@ -25,7 +25,7 @@ BF16, F32 = jnp.bfloat16, jnp.float32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     try:
@@ -39,9 +39,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, one_chip, *shapes):
@@ -78,6 +83,93 @@ def test_flash_backward(one_chip, s):
                     qkv, qkv, qkv)
     # forward, dq and dk/dv are three kernels
     assert text.count("tpu_custom_call") >= 3
+
+
+def _kernel_names(text):
+    """Instruction names of the compiled text's Mosaic kernels, without
+    the number XLA appends: ``%flash_attention_fwd.3 = ...`` ->
+    ``flash_attention_fwd``."""
+    import re
+    return sorted(re.match(r"\s*(?:ROOT\s+)?%?([^\s=]+?)(?:\.\d+)*\s*=",
+                           line).group(1)
+                  for line in text.splitlines()
+                  if 'custom_call_target="tpu_custom_call"' in line)
+
+
+FLASH = ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+         "flash_attention_fwd"]
+
+
+def _flash_loss(attend):
+    return lambda q, k, v: jnp.sin(attend(q, k, v).astype(F32)).sum()
+
+
+def _tape_step(attend):
+    """Forward and backward as the program's tape runs them inside the
+    whole step: the forward at dispatch, ``jax.vjp`` materialised only at
+    backward (``core/autograd.py``)."""
+    def step(q, k, v):
+        out = attend(q, k, v)
+        _, pull = jax.vjp(attend, q, k, v)
+        return out, pull(jnp.cos(out.astype(F32)).astype(out.dtype))
+    return step
+
+
+# the kernels' names are a contract with the benchmark: a device trace
+# names an op by its HLO instruction, and ``pallas_call(name=...)`` must
+# reach it through whatever wraps the call on the training path
+def test_flash_kernels_keep_their_names(one_chip):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
+    qkv = ((2, 2048, HEADS, DH), BF16)
+
+    def attend(q, k, v):
+        return flash_attention_bshd(q, k, v, causal=True)
+
+    assert _kernel_names(_compile(attend, one_chip, qkv, qkv, qkv)) == \
+        ["flash_attention_fwd"]
+    # differentiated directly, jax wraps each name in its transforms'
+    # (``jvp_flash_attention_fwd_``): the kernel's own name is still there
+    names = _kernel_names(_compile(
+        jax.grad(_flash_loss(attend), argnums=(0, 1, 2)), one_chip,
+        qkv, qkv, qkv))
+    assert len(names) == 3
+    assert sorted(k for n in names for k in FLASH if k in n) == FLASH
+
+
+def test_flash_kernels_keep_their_names_under_checkpoint(one_chip):
+    """As the training step runs them: per-block recompute is
+    ``jax.checkpoint``, whose forward and rematerialised forward both
+    carry the kernel's name and not ``checkpoint`` or
+    ``rematted_computation``."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
+    qkv = ((2, 2048, HEADS, DH), BF16)
+    attend = jax.checkpoint(
+        lambda q, k, v: flash_attention_bshd(q, k, v, causal=True))
+    names = _kernel_names(_compile(_tape_step(attend), one_chip,
+                                   qkv, qkv, qkv))
+    assert sorted(set(names)) == FLASH
+    assert len(names) == 4 and names.count("flash_attention_fwd") == 2
+
+
+def test_flash_kernels_keep_their_names_under_shard_map(topo):
+    """``sharded_flash_attention`` (heads over 'model', batch over 'data')
+    inside ``jax.checkpoint``, compiled for four described chips: the
+    hybrid step's layout."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu.ops.pallas.flash_attention import \
+        sharded_flash_attention
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2),
+                ("data", "model"))
+    fa = jax.checkpoint(sharded_flash_attention(mesh, causal=True))
+    qkv = jax.ShapeDtypeStruct(
+        (4, 2048, HEADS, DH), BF16,
+        sharding=NamedSharding(mesh, P("data", None, "model", None)))
+
+    text = jax.jit(_tape_step(fa)).lower(qkv, qkv, qkv).compile().as_text()
+    names = _kernel_names(text)
+    assert sorted(set(names)) == FLASH
+    assert len(names) == 4 and names.count("flash_attention_fwd") == 2
 
 
 def test_layer_norm(one_chip):
@@ -123,10 +215,22 @@ def test_ragged_paged_attention(one_chip, tokens):
         ragged_paged_attention
     rows = 8
     row = ((rows,), jnp.int32)
-    _compile(lambda q, k, v, rs, rl, kl, bt: ragged_paged_attention(
+    text = _compile(lambda q, k, v, rs, rl, kl, bt: ragged_paged_attention(
         q, k, v, rs, rl, kl, bt), one_chip,
         ((tokens, HEADS, DH), BF16), POOL, POOL, row, row, row,
         ((rows, MAX_PAGES), jnp.int32))
+    assert _kernel_names(text) == ["ragged_paged_attention"]
+
+
+def test_ragged_kernel_keeps_its_name_under_checkpoint(one_chip):
+    from paddle_tpu.ops.pallas.ragged_attention import \
+        ragged_paged_attention
+    rows = 8
+    row = ((rows,), jnp.int32)
+    text = _compile(jax.checkpoint(ragged_paged_attention), one_chip,
+                    ((128, HEADS, DH), BF16), POOL, POOL, row, row, row,
+                    ((rows, MAX_PAGES), jnp.int32))
+    assert _kernel_names(text) == ["ragged_paged_attention"]
 
 
 def test_xla_reference_attention_stays_in_f32(one_chip):

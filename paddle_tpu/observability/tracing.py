@@ -61,6 +61,16 @@ The five ``round.*`` phases follow one another inside their
 and to the program launch it caused. A round that finds nothing to launch
 records no ``decode_round`` in the buffer.
 
+One event comes from a kernel, at trace time and not per step (``cat``
+``kernels``; ``ops/pallas/flash_attention.py`` behind the same one gate):
+
+``flash_attention.schedule``
+    the tile schedule one traced call of the flash kernels was compiled
+    with. Args: ``shape`` ([B, S, H, D]), ``sk``, ``dtype``, ``causal``,
+    ``padded`` (both lengths), ``fwd`` / ``bwd_dq`` / ``bwd_dkv`` (each
+    ``[block_q, block_k, chunk]``), ``steps_live`` / ``steps_dead`` (grid
+    steps a kernel's call takes, and those the causal mask empties).
+
 Request tracing (ISSUE 20): :func:`mint_context` mints a trace context
 (``{"tid": <hex id>, "ps": <parent span, 0 = root>}``) that rides the
 fleet wire; every process feeds that request's spans through

@@ -106,6 +106,226 @@ def test_flash_forward_unaligned_seq_noncausal():
                                atol=2e-3)
 
 
+# ------------------------------------- the scheduled blocks (ISSUE 28)
+
+def _qkv(shape, sk, dtype, seed):
+    rng = np.random.RandomState(seed)
+    b, s, h, d = shape
+    kshape = (b, sk or s, h, d)
+    return (jnp.asarray(rng.randn(*shape), dtype),
+            jnp.asarray(rng.randn(*kshape), dtype),
+            jnp.asarray(rng.randn(*kshape), dtype),
+            jnp.asarray(rng.randn(*shape), dtype))
+
+
+def _fwd_and_grads(attend, q, k, v, g):
+    """-> (o, dq, dk, dv) in float32 for the cotangent ``g``."""
+    o, pull = jax.vjp(attend, q, k, v)
+    return tuple(np.asarray(x, np.float32) for x in (o, *pull(g.astype(o.dtype))))
+
+
+# shape, key length (None: as the queries), causal. What each is there for:
+# 1024 x 128 is the cells' head at half their sequence (one 1024 x 1024
+# block whose diagonal is trimmed in four chunks of 256 queries); 1000 x 64
+# pads to 1024 and masks 24 keys inside a block larger than 128; 100 is a
+# sequence shorter than one block; 256 against 640 keys is non-causal cross
+# attention with different blocks on the two axes; 2304 x 2 heads of 16
+# makes two 1152 blocks a head (a dead step, a plain block under the
+# diagonal, chunks of 128); 256 against 640 keys under a causal mask is a
+# block that is not square, masked whole.
+SCHEDULED = [
+    ((1, 1024, 2, 128), None, True), ((1, 1024, 2, 128), None, False),
+    ((1, 1000, 2, 64), None, True), ((1, 1000, 2, 64), None, False),
+    ((1, 100, 2, 32), None, True), ((1, 256, 2, 64), 640, False),
+    ((1, 2304, 2, 16), None, True), ((1, 256, 2, 64), 640, True)]
+
+
+@pytest.mark.parametrize("shape,sk,causal", SCHEDULED)
+def test_scheduled_blocks_match_reference_f32(shape, sk, causal):
+    """Forward and all three gradients at the blocks ``block_schedule``
+    picks, float32 in and out: nothing is rounded, so the tolerances are
+    those of the 128 x 128 tests above."""
+    q, k, v, g = _qkv(shape, sk, jnp.float32, 5)
+    got = _fwd_and_grads(lambda q, k, v: flash_attention_bshd(
+        q, k, v, causal=causal, interpret=True), q, k, v, g)
+    ref = _fwd_and_grads(lambda q, k, v: _ref_attention(q, k, v, causal),
+                         q, k, v, g)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, ref):
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("shape,sk,causal", SCHEDULED[:3])
+def test_scheduled_blocks_match_reference_bf16(shape, sk, causal):
+    """bf16 operands go into the products as they are and ``p`` / ``ds``
+    are rounded to bf16 for theirs: against the float32 reference on the
+    same (bf16-valued) inputs, unit-normal data, ``o`` stays within 2e-2
+    and the gradients within 4e-2 absolute (measured 0.7e-2 to 1.3e-2:
+    one bf16 rounding, 2^-9 relative, of terms of a few units)."""
+    q, k, v, g = _qkv(shape, sk, jnp.bfloat16, 6)
+    got = _fwd_and_grads(lambda q, k, v: flash_attention_bshd(
+        q, k, v, causal=causal, interpret=True), q, k, v, g)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, g)]
+    ref = _fwd_and_grads(lambda q, k, v: _ref_attention(q, k, v, causal),
+                         *f32)
+    for name, a, b, tol in zip(("o", "dq", "dk", "dv"), got, ref,
+                               (2e-2, 4e-2, 4e-2, 4e-2)):
+        np.testing.assert_allclose(a, b, rtol=tol, atol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("shape,sk,causal", SCHEDULED[:4])
+def test_scheduled_blocks_agree_with_forced_128(shape, sk, causal):
+    """The explicit ``block_q`` / ``block_k`` override still forces all
+    three kernels, and its results agree with the scheduled blocks'."""
+    q, k, v, g = _qkv(shape, sk, jnp.float32, 7)
+    auto = _fwd_and_grads(lambda q, k, v: flash_attention_bshd(
+        q, k, v, causal=causal, interpret=True), q, k, v, g)
+    forced = _fwd_and_grads(lambda q, k, v: flash_attention_bshd(
+        q, k, v, causal=causal, block_q=128, block_k=128, interpret=True),
+        q, k, v, g)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), auto, forced):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+def test_float32_inputs_are_never_narrowed():
+    """The operand dtype follows the inputs. With float32 q/k/v no value
+    in the three kernels is narrower than 32 bits. With bf16 inputs the
+    products take bf16 operands, and every scratch buffer (``m``, ``l``,
+    the accumulators), every product's and every ``exp``'s result, and
+    the ``lse`` / ``delta`` blocks are float32 all the same."""
+    import re
+
+    def kernels_text(dtype):
+        q, k, v, g = _qkv((1, 256, 1, 64), None, dtype, 0)
+
+        def step(q, k, v):
+            o, pull = jax.vjp(lambda q, k, v: flash_attention_bshd(
+                q, k, v, causal=True, interpret=True), q, k, v)
+            return o, pull(g)
+        return str(jax.make_jaxpr(step)(q, k, v))
+
+    text = kernels_text(jnp.float32)
+    assert text.count("pallas_call") == 3
+    assert not re.search(r"\b(bf16|f16)\[", text)
+    text = kernels_text(jnp.bfloat16)
+    assert re.search(r"Ref\{bf16\[1,256,64\]\}", text)     # operands
+    scratch = re.findall(r"Ref<vmem>\{(\w+)\[", text)
+    assert scratch and set(scratch) == {"f32"}
+    results = re.findall(r":(\w+)\[[\d,]*\] = (?:dot_general\[|exp )", text)
+    assert len(results) >= 13 and set(results) == {"f32"}
+    # lse out of the forward and into dq (lane-replicated), lse and delta
+    # into dk/dv (sequence on the lanes)
+    assert "Ref{f32[1,256,128]}" in text and "Ref{f32[1,1,256]}" in text
+    assert not re.search(r"Ref\{bf16\[1,(256,128|1,256)\]\}", text)
+
+
+@pytest.mark.parametrize("sq,sk,d,dtype", [
+    (2048, 2048, 128, jnp.bfloat16), (2048, 2048, 128, jnp.float32),
+    (64, 64, 64, jnp.float32), (100, 100, 32, jnp.float32),
+    (1000, 1000, 128, jnp.bfloat16), (1152, 1152, 128, jnp.bfloat16),
+    (130, 130, 32, jnp.float32), (640, 1408, 64, jnp.bfloat16),
+    (8192, 8192, 128, jnp.bfloat16), (4, 4, 8, jnp.float32)])
+def test_block_schedule(sq, sk, d, dtype):
+    """``block_schedule`` alone: blocks divide the one padded length of
+    their axis and never exceed it, the padding is under one block and a
+    multiple of 128 (or the sequence itself below 128, as before this
+    schedule), the chunk of queries divides its block, the stated VMEM
+    budget holds, and the cells' shape takes 32 steps a call, not 8,192."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    sch = fa.block_schedule(sq, sk, d, dtype)
+    for s, pad in ((sq, sch.sq), (sk, sch.sk)):
+        if s < 128:
+            assert pad == max(s, 8)             # one block, today's
+        else:
+            assert pad % 128 == 0 and s <= pad
+    itemsize = jnp.dtype(dtype).itemsize
+    for kernel in fa.KERNELS:
+        bq, bk, chunk = getattr(sch, kernel)
+        assert sch.sq % bq == 0 and sch.sk % bk == 0
+        assert bq <= sch.sq and bk <= sch.sk
+        assert sch.sq - sq < bq and sch.sk - sk < bk
+        assert bq % chunk == 0 and (chunk <= fa.CHUNK or chunk == bq)
+        assert fa._step_vmem_bytes(kernel, bq, bk, chunk, d, itemsize) \
+            <= fa.VMEM_BUDGET
+    steps = fa.grid_steps(sch, True, bh=32)
+    if (sq, sk) == (2048, 2048):
+        for kernel, (live, dead) in steps.items():
+            assert live + dead <= 1024, (kernel, live, dead)
+            assert dead < live
+    if max(sq, sk) < 128:
+        # what the code before the schedule did: min(128, max(s, 8))
+        assert sch.fwd[:2] == sch.dq[:2] == sch.dkv[:2] == \
+            (max(sq, 8), max(sk, 8))
+        assert all(v == (32, 0) for v in steps.values())
+
+
+def test_block_schedule_ignores_everything_but_the_call(monkeypatch):
+    """No environment variable, flag or cache feeds the schedule: the
+    same arguments give the same answer under any environment."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    before = fa.block_schedule(2048, 2048, 128, jnp.bfloat16)
+    for name in ("PADDLE_TPU_KERNELS", "PADDLE_TPU_FLASH_BLOCK",
+                 "PADDLE_TPU_KERNELS_CACHE", "XLA_FLAGS"):
+        monkeypatch.setenv(name, "128")
+    assert fa.block_schedule(2048, 2048, 128, jnp.bfloat16) == before
+
+
+# ---------------------------- the schedule in the trace buffer (ISSUE 28)
+
+def test_schedule_event_recorded_once_per_traced_call():
+    """With the buffer on, tracing a call records exactly one
+    ``flash_attention.schedule`` event carrying what ``block_schedule``
+    returns; running the compiled program again records nothing."""
+    from paddle_tpu.observability import tracing
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    q, k, v, _ = _qkv((2, 1000, 2, 64), None, jnp.bfloat16, 0)
+    buf = tracing.start()
+    try:
+        step = jax.jit(lambda q, k, v: flash_attention_bshd(
+            q, k, v, causal=True, interpret=True))
+        step(q, k, v)
+        step(q, k, v)                      # cached: no second trace
+    finally:
+        tracing.stop()
+    events = [e for e in buf.events if e["name"] == "flash_attention.schedule"]
+    assert len(events) == 1
+    ev = events[0]
+    sch = fa.block_schedule(1000, 1000, 64, jnp.bfloat16)
+    steps = fa.grid_steps(sch, True, bh=4)
+    assert ev["cat"] == "kernels" and ev["ph"] == "X" and ev["dur"] == 0
+    assert ev["args"] == {
+        "shape": [2, 1000, 2, 64], "sk": 1000, "dtype": "bfloat16",
+        "causal": True, "padded": [sch.sq, sch.sk],
+        "fwd": list(sch.fwd), "bwd_dq": list(sch.dq),
+        "bwd_dkv": list(sch.dkv),
+        "steps_live": {n: s[0] for n, s in steps.items()},
+        "steps_dead": {n: s[1] for n, s in steps.items()}}
+
+
+def test_schedule_event_off_makes_no_call_into_tracing(monkeypatch):
+    """Tracing off (the default): the kernel module passes the buffer's
+    one gate and calls nothing in ``tracing`` — no buffer method, no
+    feed, no phase — and does not build the event either."""
+    from paddle_tpu.observability import tracing
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    tracing.stop()
+    calls = []
+
+    def count(name):
+        def h(*a, **k):
+            calls.append(name)
+        return h
+
+    for name in ("add_complete", "span", "phase", "req_event", "start",
+                 "get_buffer", "enabled"):
+        monkeypatch.setattr(tracing, name, count(name))
+    monkeypatch.setattr(tracing.TraceBuffer, "add", count("TraceBuffer.add"))
+    monkeypatch.setattr(fa, "_record_schedule", count("_record_schedule"))
+    q, k, v, _ = _qkv((1, 128, 2, 32), None, jnp.float32, 0)
+    jax.jit(lambda q, k, v: flash_attention_bshd(
+        q, k, v, causal=True, interpret=True))(q, k, v)
+    assert calls == []
+
+
 # -------------------------------------------- sharded flash (shard_map)
 
 def _mesh(shape, names):

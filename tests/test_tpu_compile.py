@@ -58,11 +58,18 @@ def _compile(fn, one_chip, *shapes):
     return text
 
 
-# [B, S, H, D] attention operands: the smoke's sequence 2048, a sequence
-# that needs padding to the 128 block, and gpt_small's 12 x 64 heads
-@pytest.mark.parametrize("b,s,heads,dh", [
-    (2, 2048, HEADS, DH), (2, 1024, HEADS, DH), (2, 1000, HEADS, DH),
-    (2, 1024, 12, 64)])
+# [B, S, H, D] attention operands under the schedule ``block_schedule``
+# picks: the one-chip cell's call (one 2048 x 2048 step a head), the
+# four-chip cell's share of a device, two longer sequences for the VMEM
+# budget (2048 x 2048 blocks, several a head), a sequence that needs
+# padding, and gpt_small's 12 x 64 heads
+FLASH_SHAPES = [
+    (2, 2048, HEADS, DH), (4, 2048, 20, DH), (1, 4096, HEADS, DH),
+    (1, 8192, HEADS, DH), (2, 1024, HEADS, DH), (2, 1000, HEADS, DH),
+    (2, 1024, 12, 64)]
+
+
+@pytest.mark.parametrize("b,s,heads,dh", FLASH_SHAPES)
 def test_flash_forward(one_chip, b, s, heads, dh):
     from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
     qkv = ((b, s, heads, dh), BF16)
@@ -70,10 +77,10 @@ def test_flash_forward(one_chip, b, s, heads, dh):
              one_chip, qkv, qkv, qkv)
 
 
-@pytest.mark.parametrize("s", [2048, 1024])
-def test_flash_backward(one_chip, s):
+@pytest.mark.parametrize("b,s,heads,dh", FLASH_SHAPES)
+def test_flash_backward(one_chip, b, s, heads, dh):
     from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
-    qkv = ((2, s, HEADS, DH), BF16)
+    qkv = ((b, s, heads, dh), BF16)
 
     def loss(q, k, v):
         return flash_attention_bshd(q, k, v, causal=True) \
@@ -82,6 +89,27 @@ def test_flash_backward(one_chip, s):
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
                     qkv, qkv, qkv)
     # forward, dq and dk/dv are three kernels
+    assert text.count("tpu_custom_call") >= 3
+
+
+# what else reaches the kernel through F.scaled_dot_product_attention:
+# float32 operands (twice the block bytes), no causal mask, fewer queries
+# than keys, a sequence shorter than one block
+@pytest.mark.parametrize("shape,sk,dtype,causal", [
+    ((2, 2048, HEADS, DH), None, F32, True),
+    ((2, 2048, HEADS, DH), None, BF16, False),
+    ((2, 640, 12, 64), 1408, BF16, False),
+    ((2, 100, 4, 32), None, BF16, True)])
+def test_flash_backward_other_callers(one_chip, shape, sk, dtype, causal):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
+    b, s, heads, dh = shape
+    q, kv = (shape, dtype), ((b, sk or s, heads, dh), dtype)
+
+    def loss(q, k, v):
+        return flash_attention_bshd(q, k, v, causal=causal) \
+            .astype(F32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q, kv, kv)
     assert text.count("tpu_custom_call") >= 3
 
 

@@ -176,14 +176,18 @@ def _default_impl(which):
 
 def _flash_constrain(x):
     """Constrain a [B, S, H, Dh] attention operand to the sharded-flash
-    layout: batch over 'data', heads over 'model' (the shard_map in_spec,
-    snippet [2])."""
+    layout, the shard_map's in_spec (snippet [2]): heads over 'model',
+    batch over every mesh axis that splits it (``flash_batch_axes``:
+    'data' and 'sharding'). Naming them all is what keeps collectives out
+    of the attention block: an axis the batch is split over and the spec
+    leaves out means q, k and v are gathered across it."""
     from ..distributed.topology import get_hybrid_communicate_group
-    hcg = get_hybrid_communicate_group()
-    spec = P("data", None, "model", None)
+    from ..ops.pallas.flash_attention import flash_batch_axes
+    mesh = get_hybrid_communicate_group().mesh
+    spec = P(flash_batch_axes(mesh, x.shape[0]) or None, None, "model", None)
     return apply("flash_shard_constraint",
                  lambda a: jax.lax.with_sharding_constraint(
-                     a, NamedSharding(hcg.mesh, spec)), [x])
+                     a, NamedSharding(mesh, spec)), [x])
 
 
 def _sp_constrain(x, sequence_parallel):
@@ -243,10 +247,11 @@ class GPTAttention(nn.Layer):
 
     def _sharded_flash(self, q, k):
         """The shard_map'd flash kernel for the training path (SNIPPETS
-        [1]–[3]): heads over the mesh 'model' axis, batch over 'data' —
-        or None when ineligible (no TP mesh, indivisible dims, mask/
-        dropout active, kernel demoted by the A/B gate). Built once per
-        mesh and cached."""
+        [1]–[3]): heads over the mesh 'model' axis, batch over the axes
+        that split it ('data', 'sharding') — or None when ineligible (no
+        TP mesh, indivisible heads, mask/dropout active, kernel demoted
+        by the A/B gate). Built once per mesh and cached; it picks the
+        batch axes from the batch it is traced with."""
         if not self._tp:
             return None
         override = GPTAttention._sharded_impl_override
@@ -260,17 +265,15 @@ class GPTAttention(nn.Layer):
             mesh = get_hybrid_communicate_group().mesh
         except Exception:
             return None
+        from ..ops.pallas.flash_attention import (flash_batch_axes,
+                                                  sharded_flash_attention)
         m_deg = int(mesh.shape.get("model", 1))
-        d_deg = int(mesh.shape.get("data", 1))
-        if m_deg * d_deg <= 1:
-            return None  # single shard: F.sdpa already picks the kernel
         b, _, h, _ = q.shape
-        if h % m_deg or b % d_deg:
-            return None
+        if h % m_deg or (m_deg <= 1 and not flash_batch_axes(mesh, b)):
+            return None  # single shard: F.sdpa already picks the kernel
         cached = self._sharded_fa
         if cached is not None and cached[0] == id(mesh):
             return cached[1]
-        from ..ops.pallas.flash_attention import sharded_flash_attention
         fa = sharded_flash_attention(mesh, causal=True, impl=override)
         self._sharded_fa = (id(mesh), fa)
         return fa

@@ -561,35 +561,63 @@ def _fa_bwd(scale, causal, schedule, kv_len, interpret, res, g):
 _flash_attention_bhsd.defvjp(_fa_fwd, _fa_bwd)
 
 
+# the mesh axes a training batch is split over (fleet's topology names);
+# 'pipe', 'sep' and 'model' never carry it
+BATCH_AXES = ("data", "sharding")
+
+
+def flash_batch_axes(mesh, batch, axes=BATCH_AXES):
+    """The mesh axes the sharded flash path partitions a batch of
+    ``batch`` sequences over: those of ``axes`` with a degree above 1,
+    dropped from the right until the product of their degrees divides
+    the batch (``()``: every rank keeps the whole batch)."""
+    axes = tuple(a for a in axes if int(mesh.shape.get(a, 1)) > 1)
+    while axes and batch % math.prod(int(mesh.shape[a]) for a in axes):
+        axes = axes[:-1]
+    return axes
+
+
 def sharded_flash_attention(mesh, causal=True, scale=None,
-                            data_axis="data", model_axis="model",
+                            batch_axes=BATCH_AXES, model_axis="model",
                             impl=None, block_q=None, block_k=None,
                             interpret=False):
     """Flash attention shard_map'd over the mesh (SNIPPETS [2]
     ``sharded_flash_attention`` shape): q/k/v ``[B, S, H, D]`` partitioned
-    ``P(data, None, model, None)`` — batch over the data axis, heads over
-    the model axis. Attention is head-local, so every shard runs the full
-    kernel on its slice and NO collective appears in the step; the
-    out_spec stitches the heads back for GSPMD.
+    ``P(batch axes, None, model, None)`` -- heads over the model axis, the
+    batch over EVERY axis of ``batch_axes`` that splits it
+    (``flash_batch_axes``: 'data' and, under ZeRO, 'sharding', which is
+    where ``shard_batch`` puts a hybrid step's sequences). Attention is
+    local to a sequence and a head, so each rank runs the kernel on its
+    own sequences and heads, and because the specs name every axis the
+    operands are already split over, no collective appears around it:
+    nothing is gathered on the way in, and the output leaves laid out as
+    the row-parallel ``out_proj`` takes it. (An axis left out of the spec
+    is an all-gather of q, k and v across it, forward and backward.)
 
-    ``impl(q, k, v)`` defaults to the Pallas kernel; pass the jnp
-    reference chain for CPU parity tests (interpret mode measures the
-    emulator, not the chip). Degenerate meshes (both axis degrees 1)
-    return the plain impl."""
+    The axes are chosen when the returned function is traced, from the
+    batch it is handed. ``impl(q, k, v)`` defaults to the Pallas kernel;
+    pass the jnp reference chain for CPU parity tests (interpret mode
+    measures the emulator, not the chip). A mesh with nothing to split
+    over (every degree 1) returns the plain impl."""
     if impl is None:
         def impl(q, k, v):
             return flash_attention_bshd(q, k, v, causal=causal, scale=scale,
                                         block_q=block_q, block_k=block_k,
                                         interpret=interpret)
-    d_deg = int(mesh.shape.get(data_axis, 1))
-    m_deg = int(mesh.shape.get(model_axis, 1))
-    if d_deg * m_deg <= 1:
+    if all(int(mesh.shape.get(a, 1)) <= 1 for a in (model_axis, *batch_axes)):
         return impl
     from jax.sharding import PartitionSpec as P
-    spec = P(data_axis, None, model_axis, None)
-    return jax.jit(jax.shard_map(impl, mesh=mesh,
-                                 in_specs=(spec, spec, spec),
-                                 out_specs=spec, check_vma=False))
+
+    @functools.lru_cache(maxsize=None)  # one entry a prefix of batch_axes
+    def mapped(axes):
+        spec = P(axes or None, None, model_axis, None)
+        return jax.jit(jax.shard_map(impl, mesh=mesh,
+                                     in_specs=(spec, spec, spec),
+                                     out_specs=spec, check_vma=False))
+
+    def attend(q, k, v):
+        return mapped(flash_batch_axes(mesh, q.shape[0], batch_axes))(q, k, v)
+    return attend
 
 
 def flash_attention_bshd(q, k, v, causal=True, scale=None, block_q=None,

@@ -329,33 +329,80 @@ def test_schedule_event_off_makes_no_call_into_tracing(monkeypatch):
 # -------------------------------------------- sharded flash (shard_map)
 
 def _mesh(shape, names):
-    devs = np.array(jax.devices()[:shape[0] * shape[1]]).reshape(shape)
+    devs = np.array(jax.devices()[:int(np.prod(shape))]).reshape(shape)
     return jax.sharding.Mesh(devs, names)
 
 
-def test_sharded_flash_matches_unsharded():
-    """Batch over 'data', heads over 'model' (SNIPPETS [2] shape): the
-    shard_map'd kernel is numerically identical to the unsharded impl —
-    attention is head-local, so sharding must not change a single bit."""
+# mesh shape, axis names, batch, the batch the kernel must see on a rank
+SHARDED_CASES = [
+    pytest.param((2, 4), ("data", "model"), 4, 2, id="data-model"),
+    pytest.param((2, 2), ("sharding", "model"), 4, 2, id="sharding-model"),
+    pytest.param((2, 2, 2), ("data", "sharding", "model"), 4, 1,
+                 id="data-sharding-model"),
+    # the fleet mesh's own order and its idle axes
+    pytest.param((1, 1, 2, 1, 2),
+                 ("data", "pipe", "sharding", "sep", "model"), 4, 2,
+                 id="fleet-mp2-sh2"),
+    # 6 sequences over data 2 x sharding 2: the right-most axis is
+    # dropped, so a rank keeps 3 of them
+    pytest.param((2, 2, 2), ("data", "sharding", "model"), 6, 3,
+                 id="batch-not-divisible-drops-sharding"),
+    # 3 over 2: no axis is left, every rank attends the whole batch
+    pytest.param((2, 2), ("sharding", "model"), 3, 3,
+                 id="batch-not-divisible-falls-back"),
+    pytest.param((2, 2), ("sharding", "model"), 1, 1, id="batch-of-one"),
+    pytest.param((4, 1), ("sharding", "model"), 4, 1, id="no-model-axis"),
+]
+
+
+@pytest.mark.parametrize("shape,names,batch,local", SHARDED_CASES)
+def test_sharded_flash_matches_unsharded(shape, names, batch, local):
+    """Heads over 'model', batch over every axis that splits it ('data',
+    'sharding'; SNIPPETS [2] shape): the shard_map'd kernel equals the
+    unsharded impl in its output and in the gradients of q, k and v --
+    attention is local to a sequence and a head -- and each rank is handed
+    its own sequences only."""
     from paddle_tpu.ops.pallas.flash_attention import sharded_flash_attention
-    mesh = _mesh((2, 4), ("data", "model"))
+    mesh = _mesh(shape, names)
     rng = np.random.RandomState(0)
-    shape = (4, 32, 8, 32)
-    q = jnp.asarray(rng.randn(*shape), jnp.float32)
-    k = jnp.asarray(rng.randn(*shape), jnp.float32)
-    v = jnp.asarray(rng.randn(*shape), jnp.float32)
+    qkv_shape = (batch, 32, 8, 32)
+    q, k, v, w = (jnp.asarray(rng.randn(*qkv_shape), jnp.float32)
+                  for _ in range(4))
+    seen = []
 
     def impl(q, k, v):  # the CPU mesh cannot run the Mosaic kernel
+        seen.append(q.shape)
         return _ref_attention(q, k, v, True)
 
     fa = sharded_flash_attention(mesh, impl=impl)
     out = fa(q, k, v)
+    heads = 8 // mesh.shape["model"]
+    assert seen == [(local, 32, heads, 32)]
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(impl(q, k, v)), rtol=1e-5,
                                atol=1e-5)
     # gradients flow through shard_map (training path requirement)
-    g = jax.grad(lambda a: jnp.sum(fa(a, k, v)))(q)
-    assert g.shape == shape and bool(jnp.all(jnp.isfinite(g)))
+    grads = jax.grad(lambda *a: jnp.sum(fa(*a) * w), argnums=(0, 1, 2))
+    ref = jax.grad(lambda *a: jnp.sum(impl(*a) * w), argnums=(0, 1, 2))
+    for g, r in zip(grads(q, k, v), ref(q, k, v)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-4,
+                                   atol=1e-5)
+
+
+@pytest.mark.parametrize("degrees,batch,axes", [
+    ({"data": 2, "model": 4}, 4, ("data",)),
+    ({"data": 1, "sharding": 2, "model": 2}, 4, ("sharding",)),
+    ({"data": 2, "sharding": 2, "model": 2}, 8, ("data", "sharding")),
+    ({"data": 2, "sharding": 2, "model": 2}, 6, ("data",)),
+    ({"data": 2, "sharding": 2, "model": 2}, 3, ()),
+    ({"data": 1, "sharding": 2, "model": 2}, 1, ()),
+    # the axes the batch never rides are never chosen
+    ({"data": 1, "pipe": 2, "sep": 2, "model": 2}, 4, ()),
+])
+def test_flash_batch_axes_come_from_mesh_and_batch(degrees, batch, axes):
+    from types import SimpleNamespace
+    from paddle_tpu.ops.pallas.flash_attention import flash_batch_axes
+    assert flash_batch_axes(SimpleNamespace(shape=degrees), batch) == axes
 
 
 def test_sharded_flash_degenerate_mesh_returns_impl():
@@ -368,30 +415,42 @@ def test_sharded_flash_degenerate_mesh_returns_impl():
     assert sharded_flash_attention(mesh, impl=impl) is impl
 
 
-@pytest.mark.slow  # ~8s: tier-1 sits at the 870s budget edge (slowest_tests gate); full coverage stays in the slow suite
-def test_gpt_attention_uses_sharded_flash_under_tp():
+@pytest.mark.parametrize("hybrid,batch,local", [
+    # ~8s: tier-1 sits at the 870s budget edge (slowest_tests gate); full
+    # coverage stays in the slow suite
+    pytest.param({"dp_degree": 2, "mp_degree": 4, "pp_degree": 1}, 8,
+                 (4, 16, 2, 8), marks=pytest.mark.slow, id="dp2-mp4"),
+    # the hybrid step's kind of mesh: the batch rides 'sharding'
+    pytest.param({"dp_degree": 1, "mp_degree": 2, "pp_degree": 1,
+                  "sharding_degree": 4}, 8, (2, 16, 4, 8), id="sh4-mp2"),
+    # eight devices left over two degrees of 2: fleet fills 'data' with 2,
+    # and the batch is split over data x sharding
+    pytest.param({"mp_degree": 2, "pp_degree": 1, "sharding_degree": 2}, 4,
+                 (1, 16, 4, 8), marks=pytest.mark.slow, id="dp2-sh2-mp2"),
+])
+def test_gpt_attention_uses_sharded_flash_under_tp(hybrid, batch, local):
     """GPT's training attention routes through the shard_map'd flash path
     when a TP mesh is active and the kernel is eligible — asserted by
-    injecting a marking impl through the test hook, and the loss stays
-    finite with gradients flowing to the TP-sharded qkv weights."""
+    injecting a marking impl through the test hook, which also sees the
+    shape a rank is handed (its own sequences and heads) — and the loss
+    stays finite with gradients flowing to the TP-sharded qkv weights."""
     import paddle_tpu as paddle
-    from paddle_tpu.distributed import fleet
+    from paddle_tpu.distributed import fleet, shard_batch
     from paddle_tpu.models import GPTConfig, GPTForCausalLM, \
         GPTPretrainingCriterion
     from paddle_tpu.models.gpt import GPTAttention
 
     paddle.seed(0)
     strategy = fleet.DistributedStrategy()
-    strategy.hybrid_configs = {"dp_degree": 2, "mp_degree": 4,
-                               "pp_degree": 1}
+    strategy.hybrid_configs = hybrid
     fleet.init(is_collective=True, strategy=strategy)
     from paddle_tpu.distributed.topology import \
         get_hybrid_communicate_group
     hcg = get_hybrid_communicate_group()
-    calls = {"n": 0}
+    shapes = []
 
     def marking_impl(q, k, v):
-        calls["n"] += 1
+        shapes.append(q.shape)
         return _ref_attention(q, k, v, True)
 
     cfg = GPTConfig(vocab_size=128, hidden_size=64, num_layers=1,
@@ -402,15 +461,13 @@ def test_gpt_attention_uses_sharded_flash_under_tp():
         model = GPTForCausalLM(cfg)
         crit = GPTPretrainingCriterion(cfg)
         ids = paddle.to_tensor(
-            np.random.RandomState(0).randint(0, 128, (8, 16))
+            np.random.RandomState(0).randint(0, 128, (batch, 16))
             .astype("int32"))
+        if hybrid.get("sharding_degree", 1) > 1:
+            # as the benchmark's hybrid step places its batch
+            ids = shard_batch(ids, hcg.get_sharding_parallel_group())
         loss = crit(model(ids), ids)
-        m_deg = int(hcg.mesh.shape.get("model", 1))
-        d_deg = int(hcg.mesh.shape.get("data", 1))
-        if m_deg * d_deg <= 1:
-            assert calls["n"] == 0  # degenerate mesh: plain path
-            return
-        assert calls["n"] >= 1, "sharded flash impl was not invoked"
+        assert shapes and set(shapes) == {local}, shapes
         assert np.isfinite(float(loss.numpy()))
         loss.backward()
         for p in model.parameters():

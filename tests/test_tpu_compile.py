@@ -200,6 +200,105 @@ def test_flash_kernels_keep_their_names_under_shard_map(topo):
     assert len(names) == 4 and names.count("flash_attention_fwd") == 2
 
 
+def _collectives(text, kind):
+    """The compiled text's instructions of one collective kind, started
+    or whole: ``all-gather(`` and ``all-gather-start(``."""
+    return [line.strip() for line in text.splitlines()
+            if f" {kind}(" in line or f" {kind}-start(" in line]
+
+
+def test_sharded_flash_moves_nothing_between_chips(topo):
+    """The hybrid step's mesh, 'sharding' 2 x 'model' 2, with the batch
+    split over 'sharding' as ``shard_batch`` leaves it: the shard_map names
+    that axis too, so forward, rematerialised forward and backward compile
+    with no collective, and each chip's kernel attends its own 2 of the 4
+    sequences and 20 of the 40 heads."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu.ops.pallas.flash_attention import \
+        sharded_flash_attention
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2),
+                ("sharding", "model"))
+    fa = jax.checkpoint(sharded_flash_attention(mesh, causal=True))
+    qkv = jax.ShapeDtypeStruct(
+        (4, 2048, 40, DH), BF16,
+        sharding=NamedSharding(mesh, P("sharding", None, "model", None)))
+
+    text = jax.jit(_tape_step(fa)).lower(qkv, qkv, qkv).compile().as_text()
+    names = _kernel_names(text)
+    assert sorted(set(names)) == FLASH and len(names) == 4
+    for kind in ("all-gather", "all-reduce", "collective-permute",
+                 "all-to-all"):
+        assert _collectives(text, kind) == []
+    # [B, S, H, D] -> [BH, S, D] inside the shard_map: 2 x 20 on a chip
+    assert "bf16[40,2048,128]" in text and "bf16[80,2048,128]" not in text
+
+
+def test_gpt_attention_gathers_no_qkv_under_the_fleet_mesh(topo,
+                                                           monkeypatch):
+    """One ``GPTAttention`` at GPT-3 13B widths under fleet's own mesh
+    (mp 2 x sharding 2 over the four described chips), forward and
+    backward, the input split over 'sharding': no all-gather puts q, k,
+    v, the output or a cotangent of theirs together across the sharding
+    group, and the kernels run on a chip's own share."""
+    import paddle_tpu as paddle
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.distributed import fleet, placement, topology
+    from paddle_tpu.distributed.fleet.pipeline_compiled import \
+        _functionalize
+    from paddle_tpu.models import GPTConfig
+    from paddle_tpu.models.gpt import GPTAttention
+
+    # fleet builds its mesh from jax.devices(): hand it the described
+    # chips (which also makes on_tpu() say yes, so the kernel is chosen as
+    # on the chip), and leave the weights where they were drawn -- nothing
+    # can be put on a chip that is not there
+    chips = list(topo.devices[:4])
+    monkeypatch.setattr(jax, "devices", lambda *a: chips)
+    monkeypatch.setattr(jax, "device_count", lambda *a: len(chips))
+    monkeypatch.setattr(placement, "place_global", lambda arr, s: arr)
+    for mod, name in ((topology, "_hcg"), (fleet, "_strategy"),
+                      (fleet, "_fleet_initialized")):
+        monkeypatch.setattr(mod, name, getattr(mod, name))  # put back after
+    strategy = fleet.DistributedStrategy()
+    strategy.hybrid_configs = {"dp_degree": 1, "pp_degree": 1,
+                               "sharding_degree": 2, "mp_degree": 2}
+    mesh = fleet.init(is_collective=True, strategy=strategy).mesh
+    assert dict(mesh.shape) == {"data": 1, "pipe": 1, "sharding": 2,
+                                "sep": 1, "model": 2}
+
+    hidden, heads, batch, seq = 5120, 40, 4, 2048
+    paddle.seed(0)
+    attn = GPTAttention(GPTConfig(
+        vocab_size=128, hidden_size=hidden, num_layers=1, num_heads=heads,
+        max_seq_len=seq, dropout=0.0, tensor_parallel=True))
+    fn, params = _functionalize(attn)
+    specs = {"qkv_proj.weight": P(None, "model"),
+             "qkv_proj.bias": P("model"),
+             "out_proj.weight": P("model", None), "out_proj.bias": P()}
+    names = [n for n, _ in attn.named_parameters()]
+    assert sorted(names) == sorted(specs)
+
+    def aval(shape, spec):
+        return jax.ShapeDtypeStruct(tuple(shape), BF16,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def loss(arrs, x):
+        return jnp.sin(fn(arrs, x).astype(F32)).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        [aval(p.shape, specs[n]) for n, p in zip(names, params)],
+        aval((batch, seq, hidden), P("sharding", None, None))
+    ).compile().as_text()
+    assert sorted(set(k for n in _kernel_names(text)
+                      for k in FLASH if k in n)) == FLASH
+    # on a chip: 2 sequences x 20 heads, never the sharding group's 4
+    assert "bf16[40,2048,128]" in text and "bf16[80,2048,128]" not in text
+    gathered = [line for line in _collectives(text, "all-gather")
+                if f"[{batch},{seq}," in line.split(" all-gather")[0]]
+    assert gathered == []
+
+
 def test_layer_norm(one_chip):
     from paddle_tpu.ops.pallas.layer_norm import layer_norm
     _compile(lambda x, w, b: layer_norm(x, w, b), one_chip,

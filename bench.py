@@ -541,16 +541,20 @@ def bench_kernels_ab():
             lambda q, k, v: flash_attention_bshd(q, k, v, causal=True),
             (q, k, v), sig=gate.shape_sig(q, k))
 
-    # paged attention at a serving decode shape (shares the serving
-    # engine's verdict cache through decode.ab_compare)
-    from paddle_tpu.serving.decode import ab_compare
+    # paged attention at a decode shape, recorded under the q sig the
+    # incubate paged_attention auto path queries
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_attention, paged_attention_reference)
     P, page, Hh, Dh, B = 256, 16, 8, 64, 8
     qd = jnp.asarray(rng.randn(B, Hh, Dh), jnp.float32)
     kp = jnp.asarray(rng.randn(P, page, Hh, Dh), jnp.float32)
     vp = jnp.asarray(rng.randn(P, page, Hh, Dh), jnp.float32)
     bt = rng.randint(1, P, (B, 8)).astype(np.int32)
     lens = rng.randint(1, 8 * page, B).astype(np.int32)
-    rows["paged_attention"] = ab_compare(qd, kp, vp, bt, lens, repeats=10)
+    rows["paged_attention"] = gate.ab_gate(
+        "paged_attention", paged_attention_reference, paged_attention,
+        (qd, kp, vp, jnp.asarray(bt), jnp.asarray(lens)), repeats=10,
+        sig=gate.shape_sig(qd))
     return rows
 
 
@@ -1336,109 +1340,6 @@ def run_linalg_chaos():
         shutil.rmtree(tmp, ignore_errors=True)
 
 
-def run_serving_bench(n_requests=None, qps=None):
-    """``--serving`` leg: the continuous-batching engine under a Poisson
-    OPEN-loop load (arrivals don't wait for the engine — tail latency is
-    honest; external yardstick: the Gemma-on-TPU serving study,
-    arxiv 2605.25645). Records decode tokens/s, TTFT + inter-token tail
-    latency, KV-pool pressure, and the paged-attention A/B gate rows
-    (Pallas only serves where it beat the XLA reference at this shape)."""
-    import numpy as np  # noqa: F401  (engine deps import it anyway)
-    import paddle_tpu as paddle
-    from paddle_tpu.models import GPTForCausalLM
-    from paddle_tpu.observability import metrics as obsm
-    from paddle_tpu.observability.metrics import hist_quantile
-    from paddle_tpu.serving import ServingEngine, run_poisson_load
-
-    paddle.seed(0)
-    # model/pool shapes shared with the prefix/chunked legs (ONE copy);
-    # the load parameters below stay leg-local so the legacy keys keep
-    # their r6 trajectory
-    device, cfg, kb = _serving_cfg_and_knobs()
-    pool_pages, slots, page = kb["pool"], kb["slots"], kb["page"]
-    n_requests = n_requests or 64
-    qps = qps or 16.0
-    new_tokens, plen = 32, (16, 64)
-    model = GPTForCausalLM(cfg)
-    model.eval()
-    # ragged=False: these are the r6-lineage legacy keys — they keep
-    # measuring the bucketed path for trajectory continuity; the ragged
-    # leg (run_ragged_serving_bench) records its own twins next to them
-    eng = ServingEngine(model, page_size=page, num_pages=pool_pages,
-                        max_slots=slots, ragged=False)
-    try:
-        # warm every compile — each (batch bucket × seq bucket) prefill
-        # shape plus the decode step — so TTFT/ITL measure serving, not
-        # first-call XLA compilation. nb simultaneous bucket-length
-        # submissions prefill at exactly the [nb, sb] shape.
-        from paddle_tpu.serving import ServingMetrics
-        for sb in eng.prefill_seq_buckets:
-            ln = min(sb, cfg.max_seq_len - 2)
-            for nb in eng.prefill_batch_buckets:
-                if nb > slots:
-                    continue
-                # a per-(seq, batch)-bucket token keeps every warm batch
-                # from prefix-hitting an earlier iteration's prompt (a
-                # hit would route to the chunk step and leave the dense
-                # [nb, sb] shape uncompiled for the measured load); the
-                # nb rows WITHIN one batch share a prompt safely — they
-                # admit in one round, before any of them is indexed
-                tok = (sb + 97 * nb) % 251 + 2
-                reqs = [eng.submit([tok] * ln, max_new_tokens=1)
-                        for _ in range(nb)]
-                eng.run_until_idle()
-                for r in reqs:
-                    r.result(60)
-        eng.generate([1, 2, 3], max_new_tokens=4)  # decode-step warm
-        # serving metrics flow through the PR-5 registry (tail rows are
-        # cross-checked against the loadgen's timestamps); attached only
-        # AFTER warmup so compile-time TTFTs never pollute the histograms
-        reg = obsm.enable(out_dir=None, interval_s=0)
-        eng.metrics = ServingMetrics(registry=reg)
-        eng.start()
-        res = run_poisson_load(eng, n_requests=n_requests, qps=qps,
-                               prompt_len=plen,
-                               max_new_tokens=new_tokens, seed=0,
-                               timeout=900.0)
-        stats = eng.stats()
-    finally:
-        eng.close()
-    sub = {
-        "serving_device": device,
-        "serving_tokens_per_sec": res["tokens_per_sec"],
-        "serving_qps_offered": res["qps_offered"],
-        "serving_qps_completed": res["qps_completed"],
-        "serving_requests_ok": res["requests_ok"],
-        "serving_requests_failed": res["requests_failed"],
-        "serving_ttft_ms_p50": res["ttft_ms_p50"],
-        "serving_ttft_ms_p99": res["ttft_ms_p99"],
-        "serving_itl_ms_p50": res["itl_ms_p50"],
-        "serving_itl_ms_p99": res["itl_ms_p99"],
-        "serving_e2e_ms_p99": res["e2e_ms_p99"],
-        "serving_evictions": res["evictions"],
-        "serving_kv_occupancy_peak_pct": stats["kv_occupancy_peak_pct"],
-        "serving_paged_attn_backend": stats["attn_backend"],
-    }
-    ab = stats.get("attn_ab") or {}
-    if ab.get("xla_ms") is not None:
-        sub["serving_paged_attn_xla_ms"] = ab["xla_ms"]
-    if ab.get("pallas_ms") is not None:
-        sub["serving_paged_attn_pallas_ms"] = ab["pallas_ms"]
-    if ab.get("reason"):
-        sub["serving_attn_gate"] = ab["reason"]
-    # registry-derived twin of the loadgen's TTFT tail: proves the
-    # serving metrics actually landed in the observability plane
-    h = reg.histogram("serving_ttft_ms").to_dict()
-    if h.get("count"):
-        sub["serving_ttft_ms_p99_telemetry"] = round(
-            hist_quantile(h, 0.99), 2)
-    obsm.disable()
-    ok = (res["requests_failed"] == 0
-          and res["requests_ok"] == res["n_requests"]
-          and res["tokens_per_sec"] > 0)
-    return sub, ok
-
-
 def _serving_cfg_and_knobs():
     """One copy of the serving bench shapes."""
     from paddle_tpu.models import GPTConfig
@@ -1446,253 +1347,8 @@ def _serving_cfg_and_knobs():
     cfg = GPTConfig(vocab_size=8192, hidden_size=512, num_layers=8,
                     num_heads=8, max_seq_len=512, dropout=0.0)
     knobs = dict(pool=512, slots=8, page=16, chunk=64, new_tokens=24,
-                 prefix_len=128, tail=(8, 32), n_req=32, qps=12.0,
-                 long_prompt=448, steady=16)
+                 tail=(8, 32), qps=12.0)
     return device, cfg, knobs
-
-
-def run_prefix_cache_bench():
-    """Shared-system-prompt leg: the SAME seeded Poisson workload (one
-    common prompt head + per-request tails, ``load.shared_prefix``)
-    against a prefix-cache engine and its cold twin — records the hit
-    rate and the hot-vs-cold TTFT delta (the compute+writes the shared
-    head no longer pays)."""
-    import paddle_tpu as paddle
-    from paddle_tpu.models import GPTForCausalLM
-    from paddle_tpu.serving import ServingEngine, run_poisson_load
-
-    device, cfg, kb = _serving_cfg_and_knobs()
-    paddle.seed(0)
-    model = GPTForCausalLM(cfg)
-    model.eval()
-
-    def leg(prefix_on):
-        eng = ServingEngine(model, page_size=kb["page"],
-                            num_pages=kb["pool"], max_slots=kb["slots"],
-                            prefix_cache=prefix_on, ragged=False)
-        try:
-            # warm the compiles so TTFT measures serving, not XLA: the
-            # dense head-sized prefill, a short prompt, and — on the hot
-            # engine — one HIT (the repeat) so the partial-prefix tail
-            # step's shape is compiled before the measured run
-            warm = [1] * kb["prefix_len"] + [2] * kb["tail"][0]
-            eng.generate(warm, max_new_tokens=2)
-            eng.generate(warm, max_new_tokens=2)
-            eng.generate([2, 3, 4], max_new_tokens=2)
-            if prefix_on:
-                # warm-run pages must not seed the measured run's cache:
-                # drop the whole index (not just the counters), so even a
-                # warm prompt sharing the measured head could not inflate
-                # the recorded hit rate
-                eng.prefix.clear()
-            eng.start()
-            res = run_poisson_load(
-                eng, n_requests=kb["n_req"], qps=kb["qps"],
-                prompt_len=kb["tail"], max_new_tokens=kb["new_tokens"],
-                seed=7, timeout=600.0, shared_prefix=kb["prefix_len"])
-            stats = eng.stats()
-        finally:
-            eng.close()
-        return res, stats
-
-    cold, _ = leg(False)
-    hot, hstats = leg(True)
-    sub = {
-        "serving_prefix_hit_rate": hstats["prefix_hit_rate"],
-        "serving_prefix_hit_tokens": hstats["prefix_hit_tokens"],
-        "serving_prefix_shared_prompt_len": kb["prefix_len"],
-        "serving_prefix_hot_ttft_ms_p50": hot["ttft_ms_p50"],
-        "serving_prefix_cold_ttft_ms_p50": cold["ttft_ms_p50"],
-        "serving_prefix_hot_ttft_ms_p99": hot["ttft_ms_p99"],
-        "serving_prefix_cold_ttft_ms_p99": cold["ttft_ms_p99"],
-        "serving_prefix_hot_tokens_per_sec": hot["tokens_per_sec"],
-        "serving_prefix_cold_tokens_per_sec": cold["tokens_per_sec"],
-    }
-    if hot["ttft_ms_p50"] and cold["ttft_ms_p50"]:
-        sub["serving_prefix_ttft_p50_speedup"] = round(
-            cold["ttft_ms_p50"] / max(hot["ttft_ms_p50"], 1e-9), 3)
-    ok = (hot["requests_failed"] == 0 and cold["requests_failed"] == 0
-          and hstats["prefix_hit_rate"] > 0
-          and hot["ttft_ms_p50"] is not None
-          and cold["ttft_ms_p50"] is not None
-          and hot["ttft_ms_p50"] < cold["ttft_ms_p50"])
-    sub["serving_prefix_leg_ok"] = bool(ok)
-    return sub, ok
-
-
-def run_chunked_itl_bench():
-    """Long-prompt-mid-stream ITL leg: steady short requests decode while
-    a near-max-seq prompt arrives. Unchunked, that round's decode stalls
-    for the whole prefill (the recorded ITL-p99 wart); chunked, each
-    round spends at most the chunk budget on prefill, so the steady
-    rows' ITL p99 is bounded by the budget. Greedy decode must be
-    token-identical between the two engines."""
-    import numpy as np
-    import paddle_tpu as paddle
-    from paddle_tpu.models import GPTForCausalLM
-    from paddle_tpu.serving import ServingEngine
-
-    device, cfg, kb = _serving_cfg_and_knobs()
-    paddle.seed(0)
-    model = GPTForCausalLM(cfg)
-    model.eval()
-    rng = np.random.RandomState(11)
-    steady_prompts = [rng.randint(1, cfg.vocab_size, size=6).tolist()
-                      for _ in range(2)]
-    long_prompt = rng.randint(1, cfg.vocab_size,
-                              size=kb["long_prompt"]).tolist()
-    steady_new = kb["steady"] + 12
-
-    def leg(chunk, ragged=False):
-        eng = ServingEngine(model, page_size=kb["page"],
-                            num_pages=kb["pool"], max_slots=kb["slots"],
-                            prefill_chunk=chunk, prefix_cache=False,
-                            ragged=ragged)
-        try:
-            # warm every shape this leg will hit (incl. the long-prompt
-            # prefill / chunk ladder — or, ragged, the token-pad
-            # schedule) so ITL measures scheduling, not XLA
-            if ragged:
-                eng.warm_ragged()
-            eng.generate(long_prompt[: kb["long_prompt"] - 1],
-                         max_new_tokens=2)
-            eng.generate([1, 2, 3], max_new_tokens=2)
-            steady = [eng.submit(p, max_new_tokens=steady_new)
-                      for p in steady_prompts]
-            for _ in range(kb["steady"] // 2):
-                eng.step()      # steady rows mid-decode
-            late = eng.submit(long_prompt, max_new_tokens=4)
-            eng.run_until_idle()
-            itl = [dt * 1e3 for r in steady for dt in r.inter_token_s()]
-            toks = [r.result(60) for r in steady] + [late.result(60)]
-        finally:
-            eng.close()
-        return itl, toks
-
-    itl_un, toks_un = leg(None)
-    itl_ch, toks_ch = leg(kb["chunk"])
-    # the ragged-path ITL twin (ISSUE 13 acceptance): the single-launch
-    # round must keep the chunked-prefill guarantee — budget spreading,
-    # no decode stalls — on the SAME seeded workload the bucketed value
-    # was recorded on
-    itl_rg, toks_rg = leg(kb["chunk"], ragged=True)
-    p99_un = float(np.percentile(itl_un, 99))
-    p99_ch = float(np.percentile(itl_ch, 99))
-    p99_rg = float(np.percentile(itl_rg, 99))
-    parity = toks_un == toks_ch == toks_rg
-    sub = {
-        "serving_unchunked_itl_ms_p99": round(p99_un, 2),
-        "serving_chunked_itl_ms_p99": round(p99_ch, 2),
-        "serving_ragged_chunked_itl_ms_p99": round(p99_rg, 2),
-        "serving_chunked_itl_ms_max": round(max(itl_ch), 2),
-        "serving_unchunked_itl_ms_max": round(max(itl_un), 2),
-        "serving_ragged_chunked_itl_ms_max": round(max(itl_rg), 2),
-        "serving_chunk_tokens": kb["chunk"],
-        "serving_long_prompt_len": kb["long_prompt"],
-        "serving_chunked_parity_ok": bool(parity),
-    }
-    # the ragged path must also beat the unchunked stall (the guarantee
-    # itself); ragged-vs-bucketed chunked is recorded for comparison but
-    # not gated — CPU wall noise between two already-bounded paths is
-    # not a regression signal
-    ok = parity and p99_ch < p99_un and p99_rg < p99_un
-    sub["serving_chunked_leg_ok"] = bool(ok)
-    return sub, ok
-
-
-def run_ragged_serving_bench():
-    """Ragged-vs-bucketed twin leg (ISSUE 13): the SAME seeded
-    mixed-length workload (``load.make_mixed_length_prompts`` — log-
-    uniform prompt lengths + decode-heavy/prefill-heavy mix, the shape
-    where bucketed padding hurts most) against the ragged single-launch
-    engine and its bucketed twin. Records tokens/s + ITL p99 twins,
-    greedy token parity, and the compile-count observability rows:
-    ``serving_distinct_programs`` (ragged — expect <= 4) next to the
-    bucket matrix's count."""
-    import numpy as np
-    import paddle_tpu as paddle
-    from paddle_tpu.models import GPTForCausalLM
-    from paddle_tpu.serving import (ServingEngine,
-                                    make_mixed_length_prompts,
-                                    run_poisson_load)
-
-    device, cfg, kb = _serving_cfg_and_knobs()
-    paddle.seed(0)
-    model = GPTForCausalLM(cfg)
-    model.eval()
-    prompts, news = make_mixed_length_prompts(
-        kb["n_req"], (4, cfg.max_seq_len // 2), cfg.vocab_size,
-        decode_heavy=0.6, max_new_tokens=(4, kb["new_tokens"]), seed=13)
-
-    def leg(ragged):
-        eng = ServingEngine(model, page_size=kb["page"],
-                            num_pages=kb["pool"], max_slots=kb["slots"],
-                            prefill_chunk=kb["chunk"], prefix_cache=False,
-                            ragged=ragged)
-        try:
-            # warm: the ragged engine compiles its whole token-pad
-            # schedule up front; the bucketed twin warms the ladder the
-            # same way its legacy legs do (long + short generate)
-            if ragged:
-                eng.warm_ragged()
-            eng.generate(prompts[int(np.argmax([len(p)
-                                                for p in prompts]))],
-                         max_new_tokens=2)
-            eng.generate([1, 2, 3], max_new_tokens=2)
-            eng.start()
-            res = run_poisson_load(eng, qps=kb["qps"], prompts=prompts,
-                                   max_new_tokens=news, seed=13,
-                                   timeout=600.0)
-            stats = eng.stats()
-        finally:
-            eng.close()
-        return res, stats
-
-    # token parity is checked on a deterministic foreground pass (the
-    # Poisson runs race admission order; greedy continuation is token-
-    # identical regardless, so one ordered pass per engine suffices).
-    # The bucketed parity twin runs UNCHUNKED dense prefill — the
-    # pre-chunking bucket matrix this workload inflates worst — so its
-    # program count is the O(|batch| x |seq| + 1) number the ragged
-    # path eliminates
-    def ordered_tokens(ragged):
-        eng = ServingEngine(model, page_size=kb["page"],
-                            num_pages=kb["pool"], max_slots=kb["slots"],
-                            prefill_chunk=kb["chunk"] if ragged else None,
-                            prefix_cache=False, ragged=ragged)
-        try:
-            reqs = [eng.submit(p, max_new_tokens=n, timeout=600.0)
-                    for p, n in zip(prompts, news)]
-            eng.run_until_idle()
-            return [r.result(60) for r in reqs], eng.stats()
-        finally:
-            eng.close()
-
-    rag, rag_stats = leg(True)
-    buck, buck_stats = leg(False)
-    toks_rag, _ = ordered_tokens(True)
-    toks_dense, dense_stats = ordered_tokens(False)
-    parity = toks_rag == toks_dense
-    sub = {
-        "serving_ragged_tokens_per_sec": rag["tokens_per_sec"],
-        "serving_bucketed_tokens_per_sec": buck["tokens_per_sec"],
-        "serving_ragged_itl_ms_p99": rag["itl_ms_p99"],
-        "serving_bucketed_itl_ms_p99": buck["itl_ms_p99"],
-        "serving_ragged_ttft_ms_p99": rag["ttft_ms_p99"],
-        "serving_bucketed_ttft_ms_p99": buck["ttft_ms_p99"],
-        "serving_distinct_programs": rag_stats["distinct_programs"],
-        "serving_distinct_programs_bucketed":
-            buck_stats["distinct_programs"],
-        "serving_distinct_programs_dense_bucketed":
-            dense_stats["distinct_programs"],
-        "serving_ragged_token_pads": rag_stats["ragged_token_pads"],
-        "serving_ragged_parity_ok": bool(parity),
-    }
-    ok = (rag["requests_failed"] == 0 and buck["requests_failed"] == 0
-          and parity
-          and rag_stats["distinct_programs"] <= 4)
-    sub["serving_ragged_leg_ok"] = bool(ok)
-    return sub, ok
 
 
 def _fleet_workload(cfg, kb):
@@ -2488,46 +2144,6 @@ def main_serving_fleet():
     return _emit(merged, ok)
 
 
-def main_serving():
-    argv = sys.argv
-    def _opt(name, cast):
-        if name in argv:
-            return cast(argv[argv.index(name) + 1])
-        return None
-    try:
-        sub, ok = run_serving_bench(n_requests=_opt("--requests", int),
-                                    qps=_opt("--qps", float))
-    except Exception as e:
-        sub, ok = {"serving_error": repr(e)[-300:]}, False
-    # ISSUE 9 legs ride NEXT TO the legacy serving keys, each failing
-    # independently (one broken leg never hides the others' numbers)
-    try:
-        psub, pok = run_prefix_cache_bench()
-        sub.update(psub)
-        ok = ok and pok
-    except Exception as e:
-        sub.update({"serving_prefix_error": repr(e)[-300:],
-                    "serving_prefix_leg_ok": False})
-        ok = False
-    try:
-        csub, cok = run_chunked_itl_bench()
-        sub.update(csub)
-        ok = ok and cok
-    except Exception as e:
-        sub.update({"serving_chunked_error": repr(e)[-300:],
-                    "serving_chunked_leg_ok": False})
-        ok = False
-    try:
-        rsub, rok = run_ragged_serving_bench()
-        sub.update(rsub)
-        ok = ok and rok
-    except Exception as e:
-        sub.update({"serving_ragged_error": repr(e)[-300:],
-                    "serving_ragged_leg_ok": False})
-        ok = False
-    return _emit(sub, ok)
-
-
 def main_linalg():
     """``--linalg``: distributed linear algebra rows (ISSUE 18) — the
     in-process SUMMA perf/parity leg plus the elastic-SIGKILL chaos
@@ -2589,8 +2205,6 @@ def main():
     paddle.jit.use_compile_cache(_HERE)
     if "--serving-fleet" in sys.argv:
         sys.exit(main_serving_fleet())
-    if "--serving" in sys.argv:
-        sys.exit(main_serving())
     if "--linalg" in sys.argv:
         sys.exit(main_linalg())
     # telemetry registry as the single source of truth for the rows that

@@ -14,10 +14,10 @@ Default run, three phases through ``import paddle_tpu as paddle``:
   kernel in the compiled step, first-step loss against the same step under
   the XLA attention reference.
 * **serve** — the same widths at the full 24 layers in bf16 in a
-  ``ServingEngine`` on its default ragged path over a deployment-sized
-  page pool: eight greedy requests, prompts of 16..1024 tokens, once per
-  attention backend (Pallas ragged kernel, XLA reference); token agreement,
-  and the dense forward as judge where the two part.
+  ``ServingEngine`` over a deployment-sized page pool: eight greedy
+  requests, prompts of 16..1024 tokens, once per attention backend
+  (Pallas ragged kernel, XLA reference); token agreement, and the dense
+  forward as judge where the two part.
 
 ``--chips 4`` runs nothing of the above but the device phase: it trains
 the depth-cut model three steps unsharded on one device, then under

@@ -118,9 +118,9 @@ def _ragged_attend(q, pools, block_tables, row_starts, row_lens,
                    kv_lens, impl):
     """Ragged paged attention over the flat stream `q` [1, T, H, Dh]:
     token t attends causally over its OWN row's pages up to its absolute
-    position (its K/V was just written — write-then-attend, same order
-    as the decode step). `impl` runs on raw arrays — the serving tier
-    injects the A/B-gated / KV-head-sharded variant."""
+    position (its K/V was just written: write, then attend). `impl`
+    runs on raw arrays — the serving tier injects the A/B-gated /
+    KV-head-sharded variant."""
     names = list(pools)
 
     def fwd(qa, bta, rs, rl, kl, *pa):
@@ -133,45 +133,12 @@ def _ragged_attend(q, pools, block_tables, row_starts, row_lens,
                  + [pools[n] for n in names])
 
 
-def _paged_prefill_attend(q, pools, block_tables, positions, lens, impl):
-    """Partial-prefix attention for a prefill chunk `q` [B, S, H, Dh]:
-    query token i of row b sees pool positions <= positions[b] + i (its
-    own KV was just written). `impl` runs on raw arrays — the serving
-    tier injects the sharded variant for multi-chip prefill."""
-    names = list(pools)
-
-    def fwd(qa, bta, pos, ln, *pa):
-        return impl(qa, dict(zip(names, pa)), bta.astype(jnp.int32),
-                    pos.astype(jnp.int32), ln.astype(jnp.int32))
-    return apply("paged_prefill_attention", fwd,
-                 [q, block_tables, positions, lens]
-                 + [pools[n] for n in names])
-
-
-def _paged_attend(q, pools, block_tables, positions, impl):
-    """Paged attention over the pool for query `q` [B, 1, H, Dh]; the
-    context length per row is positions + 1 (the query token's own KV was
-    just written). `impl` runs on raw arrays (the serving tier injects
-    the sharded / Pallas-gated variant)."""
-    names = list(pools)
-
-    def fwd(qa, bta, pos, *pa):
-        out = impl(qa[:, 0], dict(zip(names, pa)), bta.astype(jnp.int32),
-                   pos.astype(jnp.int32) + 1)
-        return out[:, None]
-    return apply("paged_attention", fwd,
-                 [q, block_tables, positions] + [pools[n] for n in names])
-
-
-def _default_impl(which):
+def _default_impl():
     """The XLA reference over ``{"k", "v"}`` pools, for a caller that
     injected no attention of its own."""
-    from ..ops.pallas import paged_attention as _pa
     from ..ops.pallas.ragged_attention import \
         ragged_paged_attention_reference as _ragged
-    fn = {"ragged": _ragged, "decode": _pa.paged_attention_reference,
-          "prefill": _pa.paged_prefill_reference}[which]
-    return lambda q, p, *meta: fn(q, p["k"], p["v"], *meta)
+    return lambda q, p, *meta: _ragged(q, p["k"], p["v"], *meta)
 
 
 def _flash_constrain(x):
@@ -325,39 +292,8 @@ class GPTAttention(nn.Layer):
             from ..serving import kv_cache as _kvc
             pools = _write_pools(cache, {"k": k, "v": v},
                                  _kvc.pool_write_ragged, bt, rs, rl, kl)
-            impl = cache.get("attn_impl") or _default_impl("ragged")
+            impl = cache.get("attn_impl") or _default_impl()
             out = _ragged_attend(q, pools, bt, rs, rl, kl, impl)
-        elif cache is not None and cache.get("paged"):
-            # serving decode over the paged KV pool (serving/ engine):
-            # one query token per row; this row's K/V goes into the page
-            # pool at its absolute position, then attention runs over the
-            # row's block table (Ragged Paged Attention shape). The attn
-            # impl is injected by the engine (XLA reference, Pallas
-            # kernel, or the KV-head-sharded shard_map variant).
-            pos = cache["positions"]            # [B] int32: tokens cached
-            bt = cache["block_tables"]          # [B, max_pages] int32
-            from ..serving import kv_cache as _kvc
-            if s == 1:
-                pools = _write_pools(cache, {"k": k, "v": v},
-                                     _kvc.pool_write, bt, pos)
-                impl = cache.get("attn_impl") or _default_impl("decode")
-                out = _paged_attend(q, pools, bt, pos, impl)
-            else:
-                # chunked prefill: a chunk of s tokens per row is written
-                # into the row's pages at positions[b]..positions[b]+s-1
-                # (ragged tails via chunk_lens, padding to scrap), then
-                # attends causally over its own tokens PLUS the already-
-                # written prefix pages — partial-prefix attention
-                if "chunk_lens" not in cache:
-                    raise ValueError(
-                        "multi-token paged forward is chunked prefill "
-                        "and needs cache['chunk_lens'] ([B] valid tokens "
-                        "per row); single-token decode omits it")
-                lens = cache["chunk_lens"]      # [B] valid chunk tokens
-                pools = _write_pools(cache, {"k": k, "v": v},
-                                     _kvc.pool_write_seq, bt, pos, lens)
-                impl = cache.get("prefill_impl") or _default_impl("prefill")
-                out = _paged_prefill_attend(q, pools, bt, pos, lens, impl)
         elif cache is not None:
             from .. import ops
             if cache.get("k") is not None:

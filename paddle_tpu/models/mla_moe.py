@@ -273,9 +273,6 @@ class MLAttention(nn.Layer):
                 cache["pools"]["latent"], latent, cache["block_tables"],
                 cache["row_starts"], cache["row_lens"], cache["kv_lens"])
             out = self._absorbed(q_nope, q_rope, cache)
-        elif cache is not None and cache.get("paged"):
-            raise NotImplementedError(
-                "a latent cache is served on the engine's ragged path")
         else:
             if cache is not None:
                 # the dense cache protocol: the rows this layer declared

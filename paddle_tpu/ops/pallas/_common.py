@@ -4,7 +4,7 @@
 the default path only where it MEASURABLY beats its XLA counterpart at the
 exact shape, on the real chip. BENCH_r05 showed all three fused kernels
 (AdamW, rms_norm, layer_norm) losing to plain jnp/XLA on the v5e — the
-gate generalizes ``serving/decode.py``'s A/B mechanism to every kernel:
+gate gives every kernel the serving attention's A/B mechanism:
 
 * ``PADDLE_TPU_KERNELS=xla|pallas|auto`` (default ``auto``) — ``xla``
   demotes every kernel, ``pallas`` forces every eligible kernel (still

@@ -16,9 +16,6 @@ Layouts:
     block_tables [B, max_pages]     physical page id per logical page
     context_lens [B]                valid KV length per sequence
 Returns o [B, H, D].
-
-:func:`paged_prefill_reference` is the chunked-prefill sibling: S query
-tokens per row attending the row's pages with a ragged causal mask.
 """
 from __future__ import annotations
 
@@ -73,50 +70,6 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables,
     # a fully-masked row softmaxes to uniform: zero it (context_len == 0)
     o = jnp.where((context_lens > 0)[:, None, None], o, 0.0)
     return o.astype(q.dtype)
-
-
-def paged_prefill_reference(q, k_cache, v_cache, block_tables, q_start,
-                            q_lens, scale=None):
-    """Partial-prefix attention for **chunked prefill** (jnp gather
-    formulation; the always-correct path the serving engine's chunk step
-    compiles). A chunk of ``S`` query tokens per row starts at absolute
-    position ``q_start[b]`` and attends causally over the row's pages —
-    which already hold the previously-written prefix PLUS this chunk's own
-    K/V (the chunk is scattered into the pool before attending, mirroring
-    the decode step's write-then-attend order):
-
-        q            [B, S, H, D]     (rows past ``q_lens[b]`` are padding)
-        k/v_cache    [num_pages, page_size, KVH, D]
-        block_tables [B, max_pages]
-        q_start      [B]   tokens already in the pool before this chunk
-        q_lens       [B]   valid query tokens in this chunk
-
-    Query token ``i`` of row ``b`` sees pool positions
-    ``<= q_start[b] + i``. Returns ``[B, S, H, D]``; padded query rows
-    produce garbage the caller discards (their pool writes were routed to
-    the scrap page)."""
-    B, S, H, D = q.shape
-    KVH = k_cache.shape[2]
-    G = _grouped(H, KVH)
-    page_size = k_cache.shape[1]
-    scale = np.float32(scale if scale is not None else 1.0 / np.sqrt(D))
-    block_tables = jnp.clip(block_tables, 0, k_cache.shape[0] - 1)
-    k = jnp.take(k_cache, block_tables, axis=0)
-    v = jnp.take(v_cache, block_tables, axis=0)
-    T = block_tables.shape[1] * page_size
-    k = k.reshape(B, T, KVH, D)
-    v = v.reshape(B, T, KVH, D)
-    qg = q.reshape(B, S, KVH, G, D).astype(jnp.float32)
-    s = jnp.einsum("bskgd,btkd->bskgt", qg,
-                   k.astype(jnp.float32)) * scale
-    key_pos = jnp.arange(T, dtype=jnp.int32)[None, None, :]      # [1,1,T]
-    q_pos = (q_start[:, None].astype(jnp.int32)
-             + jnp.arange(S, dtype=jnp.int32)[None, :])[:, :, None]
-    visible = key_pos <= q_pos                                   # [B,S,T]
-    s = jnp.where(visible[:, :, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bskgt,btkd->bskgd", p, v.astype(jnp.float32))
-    return o.reshape(B, S, H, D).astype(q.dtype)
 
 
 def _kernel(blk_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,

@@ -2,10 +2,9 @@
 
 The serving tier (SURVEY layer 11; ROADMAP items 2+3): ONE ragged paged
 attention launch per scheduler round (mixed decode rows + prefill chunks
-over a paged KV cache — no bucket-compile matrix; ``ragged=False`` keeps
-the bucketed fixed-slot fallback), iteration-level scheduling between
-rounds, streaming token callbacks, A/B-gated attention backends, and
-Poisson open-loop load tooling for the bench.
+over a paged KV cache), iteration-level scheduling between rounds,
+streaming token callbacks, A/B-gated attention backends, and Poisson
+open-loop load tooling for the bench.
 
     from paddle_tpu.serving import ServingEngine
     eng = ServingEngine(model, page_size=16, num_pages=128, max_slots=8)
@@ -21,13 +20,9 @@ from .scheduler import (  # noqa: F401
     ContinuousBatchingScheduler, EngineClosed, EngineShuttingDown,
     GenerationRequest, OutOfSlots, QueueFull,
 )
-from .decode import (  # noqa: F401
-    ab_compare, paged_decode_attention, paged_prefill_attention,
-    resolve_backend, sharded_paged_attention, sharded_paged_prefill,
-)
 from .ragged_attention import (  # noqa: F401
     ab_compare_ragged, pad_total_tokens, ragged_paged_attention,
-    sharded_ragged_attention,
+    resolve_backend, sharded_ragged_attention,
 )
 from .engine import ServingEngine  # noqa: F401
 from .metrics import ServingMetrics  # noqa: F401

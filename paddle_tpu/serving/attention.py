@@ -4,16 +4,14 @@
 The engine knows no model. A model's ``cache_spec()`` says what each layer
 keeps for a token (:class:`~.kv_cache.LayerState`); the ``kind`` of that
 declaration picks, here, the attention that can read such rows: how the
-start-up gate times its two backends, and the functions the round's
-program hands the layer (``attn_impl`` for single-token decode,
-``prefill_impl`` for a bucketed chunk, ``ragged_impl`` for the ragged
-round). Every function works on raw arrays and takes the layer's pools as
-a dict ``name -> [P, page, *row]``.
+start-up gate times its two backends, and the function the round's
+program hands the layer. It works on raw arrays and takes the layer's
+pools as a dict ``name -> [P, page, *row]``.
 
-* ``kv`` — keys and values by head: ``decode.py`` / ``ragged_attention.py``.
+* ``kv`` — keys and values by head: ``ragged_attention.py``.
 * ``mla_latent`` — one shared latent row a token, read by every query
   head, values the first ``value_width`` entries of the same row:
-  ``ops/pallas/mla_ragged_attention.py``. Served on the ragged path only.
+  ``ops/pallas/mla_ragged_attention.py``.
 """
 from __future__ import annotations
 
@@ -21,7 +19,6 @@ import jax
 import numpy as np
 
 from ..ops.pallas import _common as _gate
-from . import decode as _decode
 from .ragged_attention import (ab_compare_ragged, pad_total_tokens,
                                ragged_paged_attention,
                                sharded_ragged_attention)
@@ -33,7 +30,6 @@ class KVAttention:
     """Keys and values by head, GQA-grouped; pools ``k`` and ``v``."""
 
     kind = "kv"
-    bucketed = True
     rows_read = "kv_rows"       # the key of ``decode_round``'s row count
 
     def __init__(self, spec):
@@ -47,59 +43,33 @@ class KVAttention:
                 f"mesh axis {axis}={degree} — GQA sharding splits both,"
                 " keeping each query-head group with its KV head")
 
-    def _gate_inputs(self, tokens, rows, pools, page_size, max_pages,
-                     max_seq_len):
+    def gate_ragged(self, pools, rows, tokens, page_size, max_pages,
+                    max_seq_len):
         q = jax.random.normal(jax.random.PRNGKey(0),
                               (tokens,) + self.spec.query, self.spec.dtype)
         bt = np.zeros((rows, max_pages), np.int32)
-        lens = np.full((rows,), min(page_size, max_seq_len), np.int32)
-        return q, pools["k"], pools["v"], bt, lens
-
-    def gate_decode(self, pools, rows, page_size, max_pages, max_seq_len):
-        q, k, v, bt, lens = self._gate_inputs(rows, rows, pools, page_size,
-                                              max_pages, max_seq_len)
-        return _decode.ab_compare(q, k, v, bt, lens)
-
-    def gate_ragged(self, pools, rows, tokens, page_size, max_pages,
-                    max_seq_len):
-        q, k, v, bt, kl = self._gate_inputs(tokens, rows, pools, page_size,
-                                            max_pages, max_seq_len)
-        return ab_compare_ragged(q, k, v, np.arange(rows, dtype=np.int32),
+        kl = np.full((rows,), min(page_size, max_seq_len), np.int32)
+        return ab_compare_ragged(q, pools["k"], pools["v"],
+                                 np.arange(rows, dtype=np.int32),
                                  np.ones(rows, np.int32), kl, bt)
 
     def impls(self, backend, mesh=None, mesh_axis="model"):
         if mesh is not None:
-            decode = _decode.sharded_paged_attention(
-                mesh, axis_name=mesh_axis, backend=backend)
-            prefill = _decode.sharded_paged_prefill(mesh,
-                                                    axis_name=mesh_axis)
             ragged = sharded_ragged_attention(mesh, axis_name=mesh_axis,
                                               backend=backend)
         else:
-            def decode(q, kp, vp, bt, lens):
-                return _decode.paged_decode_attention(q, kp, vp, bt, lens,
-                                                      backend=backend)
-            prefill = _decode.paged_prefill_attention
-
             def ragged(q, kp, vp, rs, rl, kl, bt):
                 return ragged_paged_attention(q, kp, vp, rs, rl, kl, bt,
                                               backend=backend)
-        return {
-            "attn_impl": lambda q, p, bt, lens: decode(q, p["k"], p["v"],
-                                                       bt, lens),
-            "prefill_impl": lambda q, p, bt, pos, lens: prefill(
-                q, p["k"], p["v"], bt, pos, lens),
-            "ragged_impl": lambda q, p, rs, rl, kl, bt: ragged(
-                q, p["k"], p["v"], rs, rl, kl, bt),
-        }
+        return lambda q, p, rs, rl, kl, bt: ragged(q, p["k"], p["v"], rs,
+                                                   rl, kl, bt)
 
 
 class LatentAttention:
     """One latent row a token shared by all query heads (MLA, absorbed
-    form); pool ``latent``. Ragged path only."""
+    form); pool ``latent``."""
 
     kind = "mla_latent"
-    bucketed = False
     rows_read = "latent_rows"
 
     def __init__(self, spec):
@@ -134,8 +104,8 @@ class LatentAttention:
         from ..ops.pallas import mla_ragged_attention as _mla
         fn = _mla.mla_ragged_attention if backend == "pallas" \
             else _mla.mla_ragged_attention_reference
-        return {"ragged_impl": lambda q, p, rs, rl, kl, bt, **kw: fn(
-            q, p["latent"], rs, rl, kl, bt, **kw)}
+        return lambda q, p, rs, rl, kl, bt, **kw: fn(
+            q, p["latent"], rs, rl, kl, bt, **kw)
 
 
 _KINDS = {c.kind: c for c in (KVAttention, LatentAttention)}

@@ -7,64 +7,42 @@ and runs it as a concurrent serving loop. It knows no model: page pools
 are allocated from the declaration, and its ``kind`` picks the attention
 that reads them (``serving/attention.py``).
 
-* **ragged serving (default; ISSUE 13)** — every scheduler round is ONE
-  launch of one jitted program (Ragged Paged Attention, arxiv
-  2604.15464): single-token decode rows, budgeted prefill chunks and
-  prefix-hit prompt tails flatten into a ``[total_tokens]`` token stream
-  with per-row metadata (``row_starts``/``row_lens``/``kv_lens``/block
-  tables); K/V scatter into pages and causal ragged attention happen in
-  the same program. Only ``total_tokens`` is padded (power-of-two
-  schedule) — the (batch, seq) prefill bucket matrix, the per-(batch,
-  chunk) chunk-step compiles, and the fixed-slot decode program collapse
-  into a handful of shape-specializations of ONE callable, counted by
-  ``serving_compiles_total`` / ``serving_distinct_programs``.
-  ``PADDLE_TPU_SERVING_RAGGED=0`` (or ``ragged=False``) falls back to
-  the bucketed paths below, which the bucket knobs now exist for.
-
-The bucketed fallback keeps the pre-ISSUE-13 shape:
-
-* **prefill** — newly admitted requests run the dense causal forward at
-  bucketed shapes (batch buckets AND sequence buckets share
-  ``inference.pick_bucket`` with :class:`~paddle_tpu.inference.
-  BatchingPredictor`, whose pad-to-bucket idea this generalizes),
-  compiled ONCE per (batch, seq) bucket pair with ``jax.jit`` (the
-  bucket sets bound the compile cache; eager per-op dispatch no longer
-  sits on TTFT), their K/V is written into pages of the shared pool,
-  and the first token streams out (TTFT ends here).
-* **decode** — ONE fixed-shape step over all ``max_slots`` slots: embed
-  the last token of every row at its own absolute position, scatter its
-  K/V into the pool, paged attention over each row's block table, greedy
-  argmax on device (host-side temperature/top-k sampling per request when
-  asked). Compiled once with ``jax.jit`` — params, block tables and pools
-  are arguments, pools are donated on TPU, so steady-state decode is one
-  XLA program launch per token regardless of admission churn.
+* **the round** — every scheduler round is ONE launch of one jitted
+  program (Ragged Paged Attention, arxiv 2604.15464): single-token
+  decode rows, budgeted prefill chunks and prefix-hit prompt tails
+  flatten into a ``[total_tokens]`` token stream with per-row metadata
+  (``row_starts``/``row_lens``/``kv_lens``/block tables); K/V scatter
+  into pages and causal ragged attention happen in the same program.
+  Only ``total_tokens`` is padded (power-of-two schedule, or the
+  ``token_pads`` ladder), so the one callable has a handful of
+  shape-specializations, counted by ``serving_compiles_total`` /
+  ``serving_distinct_programs``. Params, block tables and pools are
+  arguments, pools are donated on TPU; greedy argmax runs on device
+  (host-side temperature/top-k sampling per request when asked).
 * **chunked prefill** (ISSUE 9) — ``prefill_chunk=C`` splits prompts
   into C-token chunks advanced at most ``prefill_token_budget`` tokens
-  per scheduler round, interleaved with decode: each chunk scatters its
-  K/V into the request's pages and runs partial-prefix attention
-  (:func:`~.decode.paged_prefill_attention`) over itself + the already-
-  written prefix, so a long prompt arriving mid-stream never stalls
-  in-flight decodes (ITL p99 is bounded by the budget).
+  per scheduler round beside the decode rows, so a long prompt arriving
+  mid-stream never stalls in-flight decodes (ITL p99 is bounded by the
+  budget). Without it every pending prompt rides the round whole.
 * **prefix caching** (ISSUE 9, on by default) — full prompt pages are
   indexed in a page-granular trie (:class:`~.prefix_cache.PrefixCache`);
   an admission hit takes the shared head by refcounted reference
-  (skipping its prefill compute AND page writes — only the tail runs
-  the chunk step), shared pages are copy-on-write read-only, and
+  (skipping its prefill compute AND page writes — only the tail rides
+  the round), shared pages are copy-on-write read-only, and
   reclamation drains only refcount-0 cached pages, LRU-first.
-* **scheduling** — between steps the
+* **scheduling** — between rounds the
   :class:`~.scheduler.ContinuousBatchingScheduler` finishes / evicts /
-  admits, so a request arriving mid-stream joins the next step without
+  admits, so a request arriving mid-stream joins the next round without
   stalling in-flight rows (the no-decode-gap acceptance test).
 
-The paged-attention backend is A/B gated (``serving/decode.py``): Pallas
-only where it measurably beats the XLA reference at the serving shape;
-``PADDLE_TPU_SERVING_ATTN`` overrides. Pass ``mesh=`` to shard the decode
-along KV heads over the fleet mesh's ``model`` axis for multi-chip
+The attention backend is A/B gated (``serving/ragged_attention.py``):
+Pallas only where it measurably beats the XLA reference at the serving
+shape; ``PADDLE_TPU_SERVING_ATTN`` overrides. Pass ``mesh=`` to shard the
+round along KV heads over the fleet mesh's ``model`` axis for multi-chip
 serving.
 
 Metrics flow through the PR-5 registry via :class:`~.metrics.
-ServingMetrics`; ``bench.py --serving`` drives a Poisson open-loop load
-(``serving/load.py``) and records tokens/s + tail latency.
+ServingMetrics`.
 """
 from __future__ import annotations
 
@@ -80,10 +58,9 @@ import numpy as np
 
 from ..core.autograd import no_grad
 from ..core.tensor import Tensor
-from ..inference import pick_bucket
 from ..observability import tracing as _trc
 from . import attention as _attention
-from . import decode as _decode
+from . import ragged_attention as _ragged
 from .ragged_attention import pad_total_tokens as _pad_total_tokens
 from .kv_cache import PagedKVCache, pages_for
 from .metrics import ServingMetrics
@@ -97,7 +74,7 @@ __all__ = ["ServingEngine"]
 @contextlib.contextmanager
 def _swap_params(params, arrays):
     """Temporarily back the model's Parameters with (traced) arrays so the
-    decode step jits with weights as real arguments — no giant closure
+    round jits with weights as real arguments — no giant closure
     constants, donation-friendly."""
     olds = [p._data for p in params]
     for p, a in zip(params, arrays):
@@ -142,11 +119,10 @@ class ServingEngine:
     """
 
     def __init__(self, model, page_size=16, num_pages=64, max_slots=4,
-                 max_queue=256, prefill_seq_buckets=None,
-                 prefill_batch_buckets=None, attn_backend=None, mesh=None,
+                 max_queue=256, attn_backend=None, mesh=None,
                  mesh_axis="model", jit=True, registry=None,
                  prefill_chunk=None, prefill_token_budget=None,
-                 prefix_cache=True, ragged=None, engine_id=None,
+                 prefix_cache=True, engine_id=None,
                  page_share=None, token_pads=None, emit_logits=False):
         cfg = model.config
         self.model = model
@@ -198,7 +174,7 @@ class ServingEngine:
         self.metrics.on_cache_spec(kinds[0], sum(self.kv.bytes_per_token()))
         # chunked prefill: split prompts into prefill_chunk-token chunks
         # and interleave at most prefill_token_budget chunk-tokens per
-        # scheduler round with the decode step — a long prompt arriving
+        # scheduler round beside the decode rows — a long prompt arriving
         # mid-stream no longer stalls in-flight decodes (ITL p99 becomes
         # bounded by the budget, not the longest prompt)
         self.prefill_chunk = int(prefill_chunk) if prefill_chunk else None
@@ -212,49 +188,16 @@ class ServingEngine:
         self._prefill_budget = int(prefill_token_budget) \
             if prefill_token_budget else (self.prefill_chunk or 0)
         self._prefilling: list = []     # FIFO of mid-prefill requests
-        # seq buckets cap padding waste at ~2x; batch buckets keep the
-        # prefill compile cache small (one shape per bucket pair)
-        if prefill_seq_buckets is None:
-            prefill_seq_buckets, b = [], 16
-            while b < cfg.max_seq_len:
-                prefill_seq_buckets.append(b)
-                b *= 2
-            prefill_seq_buckets.append(cfg.max_seq_len)
-        self.prefill_seq_buckets = sorted(set(prefill_seq_buckets))
-        self.prefill_batch_buckets = sorted(set(
-            prefill_batch_buckets or [1, 2, 4, self.max_slots]))
-        # chunk-step shapes: partial tail chunks bucket to powers of two
-        # below the chunk size (or the prefill seq buckets when chunking
-        # is off and only prefix-hit tails ride this path)
-        if self.prefill_chunk:
-            cb, b = {self.prefill_chunk}, 8
-            while b < self.prefill_chunk:
-                cb.add(b)
-                b *= 2
-            self._chunk_buckets = sorted(cb)
-        else:
-            self._chunk_buckets = list(self.prefill_seq_buckets)
-        # ragged serving (ISSUE 13): the whole scheduler round is ONE
-        # launch of one jitted program; the bucketed paths (and their
-        # bucket knobs above) stay as the explicit fallback
-        if ragged is None:
-            ragged = os.environ.get("PADDLE_TPU_SERVING_RAGGED",
-                                    "1") not in ("0", "false", "off")
-        self.ragged = bool(ragged)
-        if not self.ragged and not self._attention.bucketed:
-            raise ValueError(f"cached state of kind {kinds[0]!r} is served "
-                             "on the ragged path only")
-        # the ragged round's token pads: the power-of-two schedule, or an
+        # the round's token pads: the power-of-two schedule, or an
         # explicit ladder (every round pads up to its next entry)
         self._token_pads = sorted(int(p) for p in token_pads) \
             if token_pads else None
         self.emit_logits = bool(emit_logits)
-        # ---- paged-attention backend (A/B gated; standing kernel rule)
-        requested = _decode.resolve_backend(attn_backend)
+        # ---- attention backend (A/B gated; standing kernel rule)
+        requested = _ragged.resolve_backend(attn_backend)
         self.attn_ab = None
         if requested == "auto":
-            self.attn_ab = self._run_ab_gate_ragged() if self.ragged \
-                else self._run_ab_gate()
+            self.attn_ab = self._run_ab_gate_ragged()
             self.attn_backend = self.attn_ab["backend"]
         else:
             self.attn_backend = requested
@@ -262,32 +205,15 @@ class ServingEngine:
             self._attention.check_mesh(int(mesh.shape[mesh_axis]),
                                        mesh_axis)
         # what the round's program hands each layer beside its pools
-        self._attn_impls = self._attention.impls(
+        self._attn_impl = self._attention.impls(
             self.attn_backend, mesh=mesh, mesh_axis=mesh_axis)
         self._params = list(model.parameters())
         self._param_arrays = [p._data for p in self._params]
         self._jit = bool(jit)
-        self._step_fn = self._build_step()
-        # prefill compiles once per (batch bucket, seq bucket) pair — ONE
-        # jitted callable (jax's cache specializes per bucket shape), with
-        # the pairs it has served tracked in _prefill_fns so the
-        # bounded-compile contract is observable (tested); steady-state
-        # prefill dispatch is one compiled-program launch instead of the
-        # eager per-op dispatch that used to sit on TTFT (ROADMAP item 3)
-        self._prefill_fn = self._build_prefill()
-        self._prefill_fns = {}
-        # the chunk step doubles as the prefix-hit tail prefill (both are
-        # partial-prefix attention over already-written pages); one jitted
-        # callable, shape-specialized per (batch, chunk) bucket pair
-        self._chunk_fn = self._build_chunk_prefill()
-        self._chunk_fns = {}
-        # the ragged round: ONE callable; jax.jit shape-specializes it
-        # per padded total_tokens only (pad_total_tokens schedule). The
-        # pads it has served live in _ragged_shapes; every installed
-        # shape-specialized program — ragged pad, prefill/chunk bucket
-        # pair, the fixed-slot decode step — lands in _programs, feeding
-        # serving_compiles_total / serving_distinct_programs (the
-        # bucket-matrix elimination as a measured number)
+        # the round: ONE callable; jax.jit shape-specializes it per padded
+        # total_tokens only. The pads it has served live in
+        # _ragged_shapes; every installed program lands in _programs,
+        # feeding serving_compiles_total / serving_distinct_programs
         self._ragged_fn = self._build_ragged_step()
         self._ragged_shapes: set = set()
         self._programs: set = set()
@@ -312,13 +238,6 @@ class ServingEngine:
         self._step_lock = threading.RLock()
 
     # ------------------------------------------------------------ A/B gate
-    def _run_ab_gate(self):
-        """Measure XLA vs Pallas at this engine's decode shape; 'auto'
-        resolves to the winner (Pallas never wins off-TPU)."""
-        return self._attention.gate_decode(
-            self.kv.pools[0], self.max_slots, self.page_size,
-            self.max_pages, self.cfg.max_seq_len)
-
     def _run_ab_gate_ragged(self):
         """Measure XLA vs Pallas at this engine's ragged launch shape
         (a full round: every slot a decode row, padded to the schedule);
@@ -340,15 +259,15 @@ class ServingEngine:
                          f"token pad {self._token_pads[-1]}")
 
     def _note_program(self, key):
-        """Record the installation of a new shape-specialized callable
-        (ragged pad, prefill/chunk bucket pair, decode step) — the
-        bounded-compile contract as a measured number."""
+        """Record the installation of a new shape-specialized program
+        (one a token pad) — the bounded-compile contract as a measured
+        number."""
         if key in self._programs:
             return
         self._programs.add(key)
         self.metrics.on_compile(len(self._programs))
 
-    # ----------------------------------------------------------- decode fn
+    # -------------------------------------------------------- ragged round
     def _layer_caches(self, pools, **shared):
         """One cache dict a layer: its own pools (as Tensors, by the row
         names it declared) beside what the whole round shares."""
@@ -359,30 +278,6 @@ class ServingEngine:
     def _pools_out(caches):
         return [{n: t._data for n, t in c["pools"].items()} for c in caches]
 
-    def _build_step(self):
-        model, params = self.model, self._params
-        attn_impl = self._attn_impls.get("attn_impl")
-
-        def step(arrays, tokens, positions, bt, pools):
-            with no_grad(), _swap_params(params, arrays):
-                caches = self._layer_caches(
-                    pools, paged=True, block_tables=Tensor(bt),
-                    positions=Tensor(positions), attn_impl=attn_impl)
-                logits = model(Tensor(tokens[:, None]), caches=caches,
-                               pos_offset=Tensor(positions))
-                last = logits._data[:, -1]
-                nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
-                return nxt, last, self._pools_out(caches)
-
-        if not self._jit:
-            return step
-        # donation saves the pool double-buffer on TPU; CPU/older
-        # backends warn and ignore it, so only ask where it works
-        if _decode.on_tpu():
-            return jax.jit(step, donate_argnums=(4,))
-        return jax.jit(step)
-
-    # -------------------------------------------------------- ragged round
     def _build_ragged_step(self):
         """ONE program for the whole scheduler round: embed the flat
         token stream at per-token positions, scatter every row's K/V into
@@ -393,7 +288,7 @@ class ServingEngine:
         constants), pools are donated on TPU; jax.jit specializes per
         padded total_tokens ONLY."""
         model, params = self.model, self._params
-        attn_impl = self._attn_impls["ragged_impl"]
+        attn_impl = self._attn_impl
         emit_logits = self.emit_logits
         from ..ops.pallas.ragged_attention import ragged_row_index
 
@@ -430,7 +325,9 @@ class ServingEngine:
 
         if not self._jit:
             return rstep
-        if _decode.on_tpu():
+        # donation saves the pool double-buffer on TPU; CPU/older
+        # backends warn and ignore it, so only ask where it works
+        if _ragged.on_tpu():
             return jax.jit(rstep, donate_argnums=(6,))
         return jax.jit(rstep)
 
@@ -449,8 +346,6 @@ class ServingEngine:
         touched. Serialized against concurrent rounds — the launches
         consume (and on TPU donate) the live pools. -> the list of pads
         compiled."""
-        if not self.ragged:
-            return []
         if max_tokens is None:
             if self.prefill_chunk is not None:
                 # a round carries max(1, budget // chunk) prefill rows of
@@ -486,10 +381,11 @@ class ServingEngine:
         return pads
 
     def _step_ragged(self):
-        """One ragged scheduler round: admit, grow/evict, then assemble
-        decode rows + prefill chunks (budget-bounded FIFO, chunk-boundary
-        semantics identical to the bucketed chunk step) into ONE flat
-        launch. -> decode tokens emitted."""
+        """One scheduler round: admit, grow/evict, then assemble decode
+        rows + prefill chunks (budget-bounded FIFO: a row advances by one
+        chunk a round and emits its first token in the round that
+        completes its prompt) into ONE flat launch. -> decode tokens
+        emitted."""
         # the ONE tracing gate of the round (standing contract: off =
         # one check, no allocation, no call). On, the round and its five
         # phases are spans in the buffer and annotations on the
@@ -515,9 +411,8 @@ class ServingEngine:
             (r for r in self.scheduler.active.values()
              if r.state == "active"), key=lambda r: r.slot)
         # prefill rows: FIFO, at most budget // chunk rows per round each
-        # contributing one chunk (same budget spreading as the bucketed
-        # chunk step — ITL stays bounded by the budget); unchunked mode
-        # takes every pending row's whole remaining tail
+        # contributing one chunk (ITL stays bounded by the budget);
+        # unchunked mode takes every pending row's whole remaining tail
         if self.prefill_chunk is not None:
             n_rows = max(1, self._prefill_budget // self.prefill_chunk)
             prefill_rows = self._prefilling[:n_rows]
@@ -601,8 +496,8 @@ class ServingEngine:
                 cap[req.slot] = logits_np[i]
             self.capture_logits.append(
                 (dict((r.slot, r.request_id) for r in decode_rows), cap))
-        # decode rows: account through the scheduler like the fixed-slot
-        # step did (num_cached advance, emit, finish)
+        # decode rows: account through the scheduler (num_cached
+        # advance, emit, finish)
         by_slot = {}
         for i, req in enumerate(decode_rows):
             if req.temperature > 0.0:
@@ -661,8 +556,7 @@ class ServingEngine:
 
     # ------------------------------------------------------------- prefill
     def _finish_prompt(self, req, prompt, tok, logit=None):
-        """Prompt-completion protocol — ONE copy for the dense, chunked
-        and ragged prefill paths: emit the first generated token (TTFT
+        """Prompt-completion protocol: emit the first generated token (TTFT
         ends here), flip the row to decoding, index the PRE-emit
         prompt's pages for prefix sharing, and finish if the budget is
         already met. ``prompt`` MUST be the pre-emit prompt:
@@ -705,282 +599,13 @@ class ServingEngine:
                       f"{req.request_id}: {type(e).__name__}: {e} — "
                       "decoding locally", file=sys.stderr, flush=True)
 
-    def _prefill_admitted(self, admitted):
-        """Route newly-admitted requests to a prefill path:
-
-        * chunked mode — everything queues on ``_prefilling`` and advances
-          ``prefill_token_budget`` tokens per scheduler round, interleaved
-          with decode.
-        * unchunked + prefix hit — the non-shared tail runs the partial-
-          prefix chunk step once, whole-tail (shared head skipped).
-        * unchunked + miss — the legacy dense bucketed prefill.
-        """
-        dense = []
-        for req in admitted:
-            self.metrics.on_admit(req)
-            if (self.prefill_chunk is not None or req.num_cached > 0
-                    or len(req.effective_prompt())
-                    > self.prefill_seq_buckets[-1]):
-                # the third arm is the pick_bucket clamp-down fix (ISSUE
-                # 13 satellite): a prompt longer than the largest
-                # configured seq bucket used to clamp DOWN and blow up
-                # mid-launch — route it through the partial-prefix chunk
-                # step instead, which splits it across launches
-                req.state = "prefilling"
-                self._prefilling.append(req)
-            else:
-                dense.append(req)
-        groups = {}
-        for req in dense:
-            sb = pick_bucket(len(req.effective_prompt()),
-                             self.prefill_seq_buckets)
-            groups.setdefault(sb, []).append(req)
-        step_rows = min(self.max_slots, self.prefill_batch_buckets[-1])
-        for sb, reqs in sorted(groups.items()):
-            i = 0
-            while i < len(reqs):
-                chunk = reqs[i:i + step_rows]
-                i += step_rows
-                self._prefill_batch(chunk, sb)
-        if self.prefill_chunk is None:
-            # prefix-hit tails finish within the admission round (only
-            # chunked mode spreads prefill across rounds)
-            while self._prefilling:
-                self._run_chunk_batch()
-
-    def _build_prefill(self):
-        """The compiled prefill: the dense causal forward with params as
-        real arguments (same no-giant-closure treatment as the decode
-        step), returning logits + per-layer K/V for the pool writes.
-        jax.jit specializes one program per (batch, seq) bucket shape."""
-        model, params = self.model, self._params
-        specs = self.kv.specs
-
-        def prefill(arrays, ids):
-            with no_grad(), _swap_params(params, arrays):
-                # the dense cache protocol: the layer fills the rows it
-                # declared, by name
-                caches = [dict.fromkeys(spec.rows) for spec in specs]
-                logits = model(Tensor(ids), caches=caches)
-                return (logits._data,
-                        [{n: c[n]._data for n in spec.rows}
-                         for c, spec in zip(caches, specs)])
-
-        return jax.jit(prefill) if self._jit else prefill
-
-    def _build_chunk_prefill(self):
-        """The compiled chunk step: write one chunk of tokens per row into
-        the row's pages, then partial-prefix attention over the pages
-        (chunk tokens + everything previously written). Same params-as-
-        arguments treatment as the decode step; pools are donated on TPU.
-        jax.jit specializes per (batch bucket, chunk bucket) shape."""
-        model, params = self.model, self._params
-        prefill_impl = self._attn_impls.get("prefill_impl")
-        attn_impl = self._attn_impls.get("attn_impl")
-
-        def chunk_step(arrays, tokens, positions, lens, bt, pools):
-            with no_grad(), _swap_params(params, arrays):
-                caches = self._layer_caches(
-                    pools, paged=True, block_tables=Tensor(bt),
-                    positions=Tensor(positions), chunk_lens=Tensor(lens),
-                    attn_impl=attn_impl, prefill_impl=prefill_impl)
-                logits = model(Tensor(tokens), caches=caches,
-                               pos_offset=Tensor(positions))
-                return logits._data, self._pools_out(caches)
-
-        if not self._jit:
-            return chunk_step
-        if _decode.on_tpu():
-            return jax.jit(chunk_step, donate_argnums=(5,))
-        return jax.jit(chunk_step)
-
-    def _run_chunk_batch(self):
-        """Advance pending prefills by ONE batched chunk launch: up to
-        ``budget // chunk`` requests (FIFO) each contribute their next
-        chunk. Requests whose prompt completes emit their first token and
-        join the decode batch the same round."""
-        self._prefilling = [r for r in self._prefilling
-                            if r.state == "prefilling"]
-        pending = self._prefilling
-        if not pending:
-            return 0
-        tr = _trc._TR if _trc._loaded else _trc._load()
-        t0 = time.time() if tr is not None else 0.0
-        cap = self.prefill_chunk
-        # never take more rows than the largest batch bucket can carry
-        # (pick_bucket clamps DOWN to its largest entry; a batch wider
-        # than that would index past the padded launch)
-        max_rows = min(self.max_slots, self.prefill_batch_buckets[-1])
-        if cap is not None:
-            rows = max(1, self._prefill_budget // cap)
-            batch = pending[:min(rows, max_rows)]
-        else:
-            batch = pending[:max_rows]
-        longest = max(len(r.effective_prompt()) - r.num_cached
-                      for r in batch)
-        want = min(cap, longest) if cap is not None else longest
-        sb = pick_bucket(want, self._chunk_buckets)
-        # batch was pre-clamped to the largest batch bucket above;
-        # strict turns any future violation into a loud error instead of
-        # a silent clamp-down that truncates the round
-        nb = pick_bucket(len(batch), self.prefill_batch_buckets,
-                         strict=True)
-        tokens = np.zeros((nb, sb), np.int32)
-        positions = np.zeros(nb, np.int32)
-        lens = np.zeros(nb, np.int32)
-        bt = np.zeros((nb, self.max_pages), np.int32)
-        prompts = []
-        for i, req in enumerate(batch):
-            p = req.effective_prompt()
-            prompts.append(p)
-            take = len(p) - req.num_cached
-            if cap is not None:
-                take = min(take, cap)
-            take = min(take, sb)
-            seg = p[req.num_cached:req.num_cached + take]
-            tokens[i, :take] = seg
-            positions[i] = req.num_cached
-            lens[i] = take
-            bt[i, :len(req.pages)] = req.pages
-        self._chunk_fns.setdefault((nb, sb), self._chunk_fn)
-        self._note_program(("chunk", nb, sb))
-        logits_arr, self.kv.pools = self._chunk_fn(
-            self._param_arrays, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(lens), jnp.asarray(bt),
-            self.kv.pools)
-        spent = 0
-        for i, req in enumerate(batch):
-            take = int(lens[i])
-            req.num_cached += take
-            spent += take
-            if req.num_cached < len(prompts[i]):
-                continue
-            # prompt complete: last chunk's final logit row is the first
-            # generated token, and the prompt's full pages become
-            # shareable for future prefix-cache hits
-            # tpu-lint: ok[HS002] designed sync: host-side sampling consumes this logit row once per completed prompt
-            row = np.asarray(logits_arr[i, take - 1])
-            self._finish_prompt(req, prompts[i], _select_token(row, req))
-        self._chunk_tokens += spent
-        self.metrics.on_prefill_chunk(spent)
-        if tr is not None:
-            now = time.time()
-            for i, req in enumerate(batch):
-                if req.trace is not None:
-                    _trc.req_event(req.trace, "prefill_chunk", t0,
-                                   now - t0,
-                                   args={"tokens": int(lens[i]),
-                                         "cached": req.num_cached})
-        return spent
-
-    def _prefill_batch(self, reqs, seq_bucket):
-        """Dense causal forward at [batch_bucket, seq_bucket]; right
-        padding is causal-safe (position i never attends j > i), so each
-        row's first `len` K/V rows are exact. Jitted per bucket pair —
-        prompts of different lengths share the bucket's one program."""
-        n = len(reqs)
-        tr = _trc._TR if _trc._loaded else _trc._load()
-        t0 = time.time() if tr is not None else 0.0
-        # strict: the caller split the round by the largest batch bucket,
-        # so a clamp-down here could only mean indexing past the pad
-        nb = pick_bucket(n, self.prefill_batch_buckets, strict=True)
-        ids = np.zeros((nb, seq_bucket), np.int64)
-        lens, prompts = [], []
-        for i, req in enumerate(reqs):
-            p = req.effective_prompt()
-            prompts.append(p)
-            ids[i, :len(p)] = p
-            lens.append(len(p))
-        self._prefill_fns.setdefault((nb, seq_bucket), self._prefill_fn)
-        self._note_program(("prefill", nb, seq_bucket))
-        logits_arr, rows = self._prefill_fn(self._param_arrays,
-                                            jnp.asarray(ids))
-        for i, req in enumerate(reqs):
-            ln = lens[i]
-            for layer in range(self.num_layers):
-                self.kv.write_rows(
-                    layer, {n: a[i] for n, a in rows[layer].items()},
-                    req.pages, ln)
-            req.num_cached = ln
-            if tr is not None and req.trace is not None:
-                _trc.req_event(req.trace, "prefill_chunk", t0,
-                               time.time() - t0,
-                               args={"tokens": ln, "dense": True})
-            # tpu-lint: ok[HS002] designed sync: host-side sampling consumes this logit row once per prefilled request
-            row = np.asarray(logits_arr[i, ln - 1])
-            self._finish_prompt(req, prompts[i], _select_token(row, req))
-
-    # ---------------------------------------------------------- decode step
-    def _decode_once(self, active):
-        self._note_program(("decode",))
-        tr = _trc._TR if _trc._loaded else _trc._load()
-        t0 = time.time() if tr is not None else 0.0
-        S, maxp = self.max_slots, self.max_pages
-        tokens = np.zeros(S, np.int32)
-        positions = np.zeros(S, np.int32)
-        bt = np.zeros((S, maxp), np.int32)
-        any_sampling = False
-        for slot, req in active.items():
-            tokens[slot] = req.generated[-1]
-            positions[slot] = req.num_cached
-            bt[slot, :len(req.pages)] = req.pages
-            any_sampling |= req.temperature > 0.0
-        nxt, last, self.kv.pools = self._step_fn(
-            self._param_arrays, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(bt), self.kv.pools)
-        # tpu-lint: ok[HS002] designed sync: ONE batched token fetch per decode round feeds host-side sampling
-        nxt = np.asarray(nxt)
-        # tpu-lint: ok[HS002] designed sync: the logits rows ride the same per-round host sampling fetch
-        logits_np = np.asarray(last) \
-            if (any_sampling or self.capture_logits is not None) else None
-        if self.capture_logits is not None:
-            self.capture_logits.append(
-                (dict((s, r.request_id) for s, r in active.items()),
-                 logits_np))
-        by_slot = {}
-        for slot, req in active.items():
-            if req.temperature > 0.0:
-                by_slot[slot] = _select_token(logits_np[slot], req)
-            else:
-                by_slot[slot] = int(nxt[slot])
-        finished = self.scheduler.complete_step(by_slot)
-        for slot, req in active.items():
-            tt = req.token_times
-            self.metrics.on_token(
-                req, tt[-1] - tt[-2] if len(tt) >= 2 else None)
-        for req in finished:
-            self.metrics.on_finish(req)
-        if tr is not None:
-            tr.add("decode_round", t0, time.time() - t0, cat="serving",
-                   args={"decode_rows": len(by_slot)})
-        self._decode_tokens += len(by_slot)
-        return len(by_slot)
-
     # ------------------------------------------------------------ stepping
-    def _step_bucketed(self):
-        """The bucketed fallback round (pre-ISSUE-13 shape): dense/chunk
-        prefill launches, then ONE fixed-slot decode step."""
-        admitted = self.scheduler.schedule()
-        if admitted:
-            self._prefill_admitted(admitted)
-        if self.prefill_chunk is not None and self._prefilling:
-            # budgeted interleave: one bounded chunk launch per round
-            self._run_chunk_batch()
-        _, evicted = self.scheduler.ensure_decode_capacity()
-        for req in evicted:
-            self.metrics.on_evict(req)
-        active = {slot: r for slot, r in self.scheduler.active.items()
-                  if r.state == "active"}
-        return self._decode_once(active) if active else 0
-
     def step(self):
-        """One scheduler round -> decode tokens emitted (0 when idle).
-        Ragged (default): admission, budgeted prefill chunks and every
-        active row's decode token ride ONE flat launch of one program.
-        Bucketed fallback: dense/chunked prefill launches then the
-        fixed-slot decode step. Either way a newcomer prefilling never
-        stalls in-flight rows — the gap between two decode steps is
-        bounded by the chunk budget, not by the longest prompt in the
+        """One scheduler round -> decode tokens emitted (0 when idle):
+        admission, budgeted prefill chunks and every active row's decode
+        token ride ONE flat launch of one program. A newcomer prefilling
+        never stalls in-flight rows — the gap between two decode tokens
+        is bounded by the chunk budget, not by the longest prompt in the
         queue."""
         if self._loop_error is not None:
             raise EngineClosed(
@@ -990,8 +615,7 @@ class ServingEngine:
         if self._closed:
             raise EngineClosed("engine is closed")
         with self._step_lock:
-            emitted = self._step_ragged() if self.ragged \
-                else self._step_bucketed()
+            emitted = self._step_ragged()
             occ = self.kv.occupancy_pct()
             self._peak_occupancy = max(self._peak_occupancy, occ)
             alloc = self.kv.allocator
@@ -1348,10 +972,10 @@ class ServingEngine:
         of ``StaticFunction.compiled_text``: a caller can assert which
         attention the round really compiled (``tpu_custom_call`` for the
         Pallas kernel) instead of trusting ``attn_backend``."""
-        if not (self.ragged and self._jit):
+        if not self._jit:
             raise RuntimeError(
-                "compiled_text() reads the jitted ragged round program; "
-                "this engine runs bucketed or un-jitted")
+                "compiled_text() reads the jitted round program; this "
+                "engine runs un-jitted")
         if total_tokens is None:
             if not self._ragged_shapes:
                 raise RuntimeError(
@@ -1391,7 +1015,6 @@ class ServingEngine:
                 self.kv.bytes_per_token(padded=True),
             "prefill_chunk": self.prefill_chunk,
             "prefill_chunk_tokens": self._chunk_tokens,
-            "ragged": self.ragged,
             "distinct_programs": len(self._programs),
             "ragged_token_pads": sorted(self._ragged_shapes),
         }

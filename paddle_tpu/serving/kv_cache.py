@@ -13,10 +13,10 @@ worst case and the continuous-batching scheduler can admit until the pool
 — not the batch shape — is full.
 
 Physical page 0 is reserved as the **scrap page**: padded block-table
-entries and inactive decode slots point at it, so masked lanes of the
-batched decode step have a legal write/read target without branching.
-All pool updates are functional (``.at[].set``) so the decode step can be
-one jitted XLA program with donated pool buffers.
+entries and a round's pad tokens point at it, so masked lanes of the
+round have a legal write/read target without branching. All pool updates
+are functional (``.at[].set``) so the round can be one jitted XLA program
+with donated pool buffers.
 """
 from __future__ import annotations
 
@@ -28,8 +28,7 @@ import numpy as np
 from ..core.dispatch import apply
 
 __all__ = ["BlockAllocator", "PagedKVCache", "LayerState", "kv_state",
-           "pages_for", "OutOfPages", "pool_write", "pool_write_seq",
-           "pool_write_ragged"]
+           "pages_for", "OutOfPages", "pool_write_ragged"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -236,7 +235,7 @@ class PagedKVCache:
     values ``[.., KVH, Dh]`` for a GPT layer (GQA pools carry only the KV
     heads, an ``H/KVH`` memory cut), one ``[.., 576]`` latent pool for an
     MLA layer. Round writes happen *inside* the model's attention through
-    the ``pool_write*`` functions below (functional scatter); this class
+    ``pool_write_ragged`` below (a functional scatter); this class
     owns prefill writes, the allocator, and test/debug gathers.
     """
 
@@ -311,7 +310,7 @@ class PagedKVCache:
 
 
 # ------------------------------------------------------------ pool writers
-# The scatters a model's attention calls on its own pools inside the
+# The scatter a model's attention calls on its own pools inside the
 # round's program. Generic in the row's shape: a pool is
 # [P, page, *row_shape] and ``new`` carries the same trailing dims (a
 # minor dimension narrower than the pool's is padded with zeros).
@@ -321,43 +320,6 @@ def _fit(new, pool):
     if short == 0:
         return new
     return jnp.pad(new, ((0, 0),) * (new.ndim - 1) + ((0, short),))
-
-
-def pool_write(pool, new, block_tables, positions):
-    """Serving decode: scatter one token's row per batch row (`new`
-    [B, 1, *row]) into the page pool at each row's
-    (block_tables[b, pos // page], pos % page). Inactive slots carry pos
-    0 + an all-scrap block table, so their write lands on the reserved
-    scrap page (never read)."""
-    def fwd(p, n, bt, pos):
-        page = p.shape[1]
-        idx = pos.astype(jnp.int32)
-        phys = jnp.take_along_axis(
-            bt.astype(jnp.int32), (idx // page)[:, None], axis=1)[:, 0]
-        return p.at[phys, idx % page].set(_fit(n[:, 0].astype(p.dtype), p))
-    return apply("paged_kv_write", fwd, [pool, new, block_tables,
-                                         positions])
-
-
-def pool_write_seq(pool, new, block_tables, positions, lens):
-    """Chunked prefill: scatter a chunk of `new` [B, S, *row] into the
-    page pool — row b's token i lands at absolute position
-    positions[b] + i for i < lens[b]; padded tokens (i >= lens[b]) are
-    redirected to the reserved scrap page 0 (never read), so one fixed
-    [B, S] launch serves ragged chunk tails."""
-    def fwd(p, n, bt, pos, ln):
-        page = p.shape[1]
-        B, S = n.shape[0], n.shape[1]
-        i = jnp.arange(S, dtype=jnp.int32)[None, :]
-        idx = pos[:, None].astype(jnp.int32) + i          # [B, S] abs pos
-        valid = i < ln[:, None].astype(jnp.int32)
-        logical = jnp.clip(idx // page, 0, bt.shape[1] - 1)
-        phys = jnp.take_along_axis(bt.astype(jnp.int32), logical, axis=1)
-        phys = jnp.where(valid, phys, 0)                  # scrap redirect
-        flat = _fit(n.reshape((B * S,) + n.shape[2:]).astype(p.dtype), p)
-        return p.at[phys.reshape(-1), (idx % page).reshape(-1)].set(flat)
-    return apply("paged_kv_write_seq", fwd,
-                 [pool, new, block_tables, positions, lens])
 
 
 def pool_write_ragged(pool, new, block_tables, row_starts, row_lens,

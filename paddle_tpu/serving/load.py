@@ -5,8 +5,8 @@ how fast the engine drains them (closed-loop generators hide tail latency
 by self-throttling; the Gemma-on-TPU serving study, arxiv 2605.25645, is
 the external comparison this mirrors). Drives a running
 :class:`~.engine.ServingEngine`, then reduces per-request timestamps into
-the tokens/s + TTFT + inter-token tail numbers ``bench.py --serving``
-records next to the training rows.
+tokens/s + TTFT + inter-token tail numbers (the soak tests and
+``bench.py --serving-fleet`` read them).
 """
 from __future__ import annotations
 

@@ -6,13 +6,12 @@ Paged Attention, arxiv 2604.15464; ROADMAP open item 2): the engine's
 whole scheduler round — single-token decode rows, budgeted prefill
 chunks, prompt tails behind prefix-cache hits — rides ONE flattened
 ``[total_tokens, H, Dh]`` launch described by per-row metadata
-(``row_starts`` / ``row_lens`` / ``kv_lens`` / block tables). The bucket
-compile matrix (``_prefill_fns`` per (batch, seq) pair, ``_chunk_fns``
-per (batch, chunk) pair, the fixed-slot decode program) collapses into a
-few shape-specializations of one callable: only ``total_tokens`` is
-padded, up the small power-of-two schedule of :func:`pad_total_tokens`.
+(``row_starts`` / ``row_lens`` / ``kv_lens`` / block tables). Only
+``total_tokens`` is padded, up the small power-of-two schedule of
+:func:`pad_total_tokens`, so the round's one callable has a few
+shape-specializations.
 
-Backend policy is the standing kernel rule, unchanged:
+Backend policy is the standing kernel rule:
 
 * ``xla`` — :func:`~paddle_tpu.ops.pallas.ragged_attention.
   ragged_paged_attention_reference`: the gather/segment formulation XLA
@@ -21,29 +20,34 @@ Backend policy is the standing kernel rule, unchanged:
 * ``auto`` — :func:`ab_compare_ragged` times both at the engine's ragged
   shape through ``ops/pallas/_common.ab_gate`` (verdict cached under
   ``ragged_paged_attention``); Pallas serves only where it measurably
-  wins and never off-TPU. Resolution order is the serving gate's:
-  ``PADDLE_TPU_SERVING_ATTN`` then ``PADDLE_TPU_KERNELS`` then ``auto``
-  (:func:`~.decode.resolve_backend`, one copy).
+  wins and never off-TPU. :func:`resolve_backend` reads the choice:
+  ``PADDLE_TPU_SERVING_ATTN`` then ``PADDLE_TPU_KERNELS`` then ``auto``.
 
 Multi-chip serving shards along **KV heads** over the fleet mesh's
-``model`` axis, exactly like ``sharded_paged_attention``: query heads
-stay with their GQA group's KV head, metadata replicates, no collective
-in the launch (:func:`sharded_ragged_attention`).
+``model`` axis: query heads stay with their GQA group's KV head,
+metadata replicates, no collective in the launch
+(:func:`sharded_ragged_attention`).
 """
 from __future__ import annotations
+
+import os
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.pallas import _common as _gate
+from ..ops.pallas._common import on_tpu
 from ..ops.pallas.ragged_attention import (
     ragged_paged_attention as _pallas_ragged,
     ragged_paged_attention_reference as _xla_ragged,
 )
 
 __all__ = ["ragged_paged_attention", "sharded_ragged_attention",
-           "ab_compare_ragged", "pad_total_tokens"]
+           "ab_compare_ragged", "pad_total_tokens", "resolve_backend",
+           "on_tpu"]
+
+BACKENDS = ("xla", "pallas", "auto")
 
 # smallest padded launch: decode-only rounds of small engines all share
 # one program instead of one per active-row count
@@ -78,9 +82,8 @@ def ragged_paged_attention(q, k_pool, v_pool, row_starts, row_lens,
 
 def sharded_ragged_attention(mesh, axis_name="model", backend="xla",
                              scale=None):
-    """Ragged attention sharded along KV heads over ``mesh[axis_name]``
-    (the ``sharded_paged_attention`` partitioning on the flat-token
-    layout): each shard attends its query-head groups against its head
+    """Ragged attention sharded along KV heads over ``mesh[axis_name]``:
+    each shard attends its query-head groups against its head
     slice of every page; row metadata and block tables replicate — no
     collective in the launch, the out_spec stitches heads back. Falls
     back to the unsharded fn when the axis degree is 1."""
@@ -105,6 +108,20 @@ def sharded_ragged_attention(mesh, axis_name="model", backend="xla",
     # tpu-lint: ok[RC001] built once per engine at a fixed shape and invoked inside the engine's jitted round (nested jit inlines) — the round program is counted at its _note_program install site
     return jax.jit(jax.shard_map(_impl, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=False))
+
+
+def resolve_backend(requested=None):
+    """Normalize the backend choice: explicit arg wins, then the
+    ``PADDLE_TPU_SERVING_ATTN`` env knob, then the global
+    ``PADDLE_TPU_KERNELS`` gate knob, default ``auto``."""
+    b = requested or os.environ.get("PADDLE_TPU_SERVING_ATTN") \
+        or os.environ.get(_gate.KERNELS_ENV) or "auto"
+    b = str(b).lower()
+    if b not in BACKENDS:
+        raise ValueError(
+            f"unknown serving attention backend {b!r}; pick from "
+            f"{BACKENDS}")
+    return b
 
 
 def ab_compare_ragged(q, k_pool, v_pool, row_starts, row_lens, kv_lens,

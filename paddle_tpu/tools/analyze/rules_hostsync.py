@@ -45,11 +45,6 @@ HOT_PATHS = {
     "serving/engine.py": {
         "ServingEngine.step",
         "ServingEngine._step_ragged",
-        "ServingEngine._step_bucketed",
-        "ServingEngine._decode_once",
-        "ServingEngine._run_chunk_batch",
-        "ServingEngine._prefill_batch",
-        "ServingEngine._prefill_admitted",
         "ServingEngine._serve_loop",
         "ServingEngine.snapshot_kv",
         "ServingEngine.adopt_request",

@@ -89,7 +89,7 @@ def test_engine_prefill_chunks_then_decode_match_the_reference(
     model = build(dtype=dtype)
     eng = ServingEngine(model, page_size=8, num_pages=32, max_slots=4,
                         prefill_chunk=8, emit_logits=True)
-    assert eng.stats()["cache_kind"] == "mla_latent" and eng.ragged
+    assert eng.stats()["cache_kind"] == "mla_latent"
     req = GenerationRequest(IDS[:21].tolist(), max_new_tokens=16)
     eng.submit_request(req)
     eng.run_until_idle()
@@ -370,12 +370,6 @@ def test_prefix_cache_shares_latent_pages_between_two_requests():
     want = ref_logits(model, seq, range(30, 34)).argmax(-1)
     assert req.generated == want.tolist()
     assert len(first) == 4
-
-
-def test_the_engine_refuses_a_latent_cache_off_the_ragged_path():
-    with pytest.raises(ValueError, match="ragged path only"):
-        ServingEngine(build(), page_size=8, num_pages=8, max_slots=2,
-                      ragged=False)
 
 
 def test_token_pads_ladder_and_the_round_counters():

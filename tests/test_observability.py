@@ -551,13 +551,16 @@ def _clean_env(extra=None):
 def test_launcher_two_worker_metrics_and_run_report(tmp_path):
     """Acceptance: a 2-worker elastic launcher run with metrics on emits
     parseable per-rank metrics JSONL and the launcher prints an
-    aggregated run report naming the slowest rank (rank 1 sleeps 30ms
-    per step)."""
+    aggregated run report naming the slowest rank. Rank 1 sleeps 300 ms
+    a step: the report ranks by mean step time over eight steps, the
+    first of which compiles (0.4 s alone, 1-2 s beside five loaded xdist
+    workers, and not the same on both ranks), so the sleeps have to
+    outweigh a compile's spread and not only a step."""
     log_dir = str(tmp_path / "logs")
     env = _clean_env({
         "PADDLE_TPU_METRICS": "1",
         "PADDLE_TPU_METRICS_INTERVAL_S": "0",
-        "PADDLE_TPU_TM_SLEEP_RANK": "1:30",
+        "PADDLE_TPU_TM_SLEEP_RANK": "1:300",
         "PADDLE_TPU_TM_BATCHES": "4",
     })
     r = subprocess.run(
@@ -594,7 +597,7 @@ def test_node_coordinator_metrics_run_report(tmp_path):
     env = _clean_env({
         "PADDLE_TPU_METRICS": "1",
         "PADDLE_TPU_METRICS_INTERVAL_S": "0",
-        "PADDLE_TPU_TM_SLEEP_RANK": "1:30",
+        "PADDLE_TPU_TM_SLEEP_RANK": "1:300",
         "PADDLE_TPU_TM_BATCHES": "4",
     })
     r = subprocess.run(

@@ -601,7 +601,7 @@ def test_optimizer_fused_auto_consults_gate(monkeypatch):
 
 
 def test_serving_backend_falls_back_to_kernels_env(monkeypatch):
-    from paddle_tpu.serving.decode import resolve_backend
+    from paddle_tpu.serving.ragged_attention import resolve_backend
     monkeypatch.delenv("PADDLE_TPU_SERVING_ATTN", raising=False)
     monkeypatch.setenv("PADDLE_TPU_KERNELS", "xla")
     assert resolve_backend() == "xla"

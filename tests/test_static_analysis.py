@@ -495,7 +495,7 @@ def test_cli_json_exit7_and_schema_on_injected_violation():
 # ---- regression: the three real findings the first scan surfaced -----------
 
 def test_check_vma_is_the_installed_shard_map_kwarg():
-    # serving/decode.py + ops/pallas/flash_attention.py passed check_rep=
+    # the serving shard_map wrapper + ops/pallas/flash_attention.py passed check_rep=
     # straight through; the fix passes check_vma= — prove the call shape
     # JC002 steers to works on the installed jax
     import jax
@@ -509,7 +509,8 @@ def test_check_vma_is_the_installed_shard_map_kwarg():
 
 
 def test_fixed_files_scan_clean_for_jax_compat():
-    for rel in ("serving/decode.py", "ops/pallas/flash_attention.py"):
+    for rel in ("serving/ragged_attention.py",
+                "ops/pallas/flash_attention.py"):
         path = os.path.join(package_root(), rel)
         fs = [f for f in analyze_file(path) if f.family == "jax-compat"]
         assert fs == [], f"{rel} regressed: {[f.rule for f in fs]}"

@@ -243,7 +243,8 @@ def test_gpt_attention_gathers_no_qkv_under_the_fleet_mesh(topo,
     group, and the kernels run on a chip's own share."""
     import paddle_tpu as paddle
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from paddle_tpu.distributed import fleet, placement, topology
+    from paddle_tpu.distributed import (collective, env, fleet, placement,
+                                        topology)
     from paddle_tpu.distributed.fleet.pipeline_compiled import \
         _functionalize
     from paddle_tpu.models import GPTConfig
@@ -257,9 +258,16 @@ def test_gpt_attention_gathers_no_qkv_under_the_fleet_mesh(topo,
     monkeypatch.setattr(jax, "devices", lambda *a: chips)
     monkeypatch.setattr(jax, "device_count", lambda *a: len(chips))
     monkeypatch.setattr(placement, "place_global", lambda arr, s: arr)
+    # fleet.init also builds the world mesh and, where this worker has
+    # none yet, the default process group from the same devices: put all
+    # of it back, or the next file's eager collectives on this worker try
+    # to place arrays on chips that are not there
     for mod, name in ((topology, "_hcg"), (fleet, "_strategy"),
-                      (fleet, "_fleet_initialized")):
+                      (fleet, "_fleet_initialized"), (env, "_world_mesh"),
+                      (env, "_initialized"),
+                      (collective, "_default_group")):
         monkeypatch.setattr(mod, name, getattr(mod, name))  # put back after
+    monkeypatch.setattr(collective, "_groups", dict(collective._groups))
     strategy = fleet.DistributedStrategy()
     strategy.hybrid_configs = {"dp_degree": 1, "pp_degree": 1,
                                "sharding_degree": 2, "mp_degree": 2}

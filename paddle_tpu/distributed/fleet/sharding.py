@@ -11,6 +11,16 @@ collectives at use sites. Stage 1/2 shard optimizer accumulators (and thus
 grad reductions become reduce-scatters feeding sharded updates under jit);
 stage 3 also shards the parameters themselves (all-gather on use — the
 reference's stage-3 param re-gather, compiler-scheduled).
+
+What the TPU compile shows of that reduce-scatter (the benchmark's hybrid
+step, mp 2 x sharding 2, compiled for a described v5e 2x2; ISSUE 32): the
+compiled text holds no ``reduce-scatter(`` instruction. Each weight-shaped
+gradient goes through a ``kind=kCustom`` fusion that calls a computation
+named ``%all-reduce-scatter.N``: an ``all-reduce`` over the sharding pairs
+with the ``dynamic-slice`` of the rank's share fused into it, which is the
+TPU's reduce-scatter. A count of the words ``reduce-scatter(`` in the text
+reads 0 and finds ``all-reduce(`` inside those fusions; a device trace
+names them ``fusion.N``. The CPU pipeline keeps all-reduce and slice apart.
 """
 from __future__ import annotations
 

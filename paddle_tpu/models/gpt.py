@@ -12,6 +12,7 @@ program per train step.
 from __future__ import annotations
 
 import math
+import time
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .. import nn
 from ..core.dispatch import apply
 from ..core.tensor import Tensor
+from ..distributed.fleet.recompute import (choose_keep, device_free_bytes,
+                                          keep_name, recompute)
 from ..nn import functional as F
+from ..observability import tracing as _trc
 
 __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM",
            "GPTPretrainingCriterion", "gpt_tiny", "gpt_small", "gpt_1p3b",
@@ -80,6 +84,50 @@ def gpt_1p3b(**kw):
 
 def gpt_13b(**kw):
     return GPTConfig(hidden_size=5120, num_layers=40, num_heads=40, **kw)
+
+
+def block_keep_bytes(config, tokens, itemsize, model_deg=1):
+    """Bytes on a device of the tensors one block tags for its backward
+    (``keep_name``; ``attn_out`` is the flash kernel's output and row
+    statistics, tagged in its own forward rule), for ``tokens`` tokens of
+    ``itemsize`` bytes a value on that device. In falling order of what a
+    byte saved on the chip (PERF.md, PR 32): ``attn_res`` an output
+    projection and, under tensor parallelism, its all-reduce; ``fc1`` its
+    matmul; ``attn_out`` the attention kernel's forward. ``fc1`` and the
+    heads are split over 'model'; the residual stream is whole. (The fused
+    qkv projection's output is not among them: kept, it cost the step as
+    much as its matmul saved.)"""
+    h = config.hidden_size
+    return {
+        "attn_res": tokens * h * itemsize,
+        "fc1": tokens * config.intermediate_size // model_deg * itemsize,
+        "attn_out": tokens * (h * itemsize + config.num_heads * 4)
+        // model_deg,
+    }
+
+
+# what the allocator must keep free beyond the compiler's count of a step:
+# the runtime's own buffers and fragmentation (15.0 of a v5e's 15.75 GiB;
+# PR 24 met a step that died at 14.9)
+_RUNTIME_HEADROOM = 3 << 28
+
+
+def step_reserve_bytes(config, tokens, itemsize, model_deg=1):
+    """What a training step under full recompute needs on a device, beyond
+    its parameters and optimizer state, at the moment every block's kept
+    tensors are alive: between the last block's forward and its backward.
+    Each block's input, one block's full activations, the [tokens, vocab]
+    logits in float32 with their gradient and the copy the loss reads, and
+    the runtime's headroom. (The step's other high-water mark, the end of
+    the backward with every gradient in hand, holds no kept tensor: one
+    that fits under full recompute fits with them. Calibrated against the
+    TPU compiler's count, ``benchmark/tools/compile_keep_v5e.py``: 2.43
+    GiB here against 2.28 counted at GPT-3 1.3B widths on one chip.)"""
+    h, ffn = config.hidden_size, config.intermediate_size
+    block_inputs = config.num_layers * tokens * h * itemsize
+    one_block = tokens * (6 * h + (3 * h + 2 * ffn) // model_deg) * itemsize
+    logits = tokens * config.vocab_size // model_deg * (4 + 4 + itemsize)
+    return block_inputs + one_block + logits + _RUNTIME_HEADROOM
 
 
 def _cache_write(buf, new, ln):
@@ -345,7 +393,8 @@ class GPTMLP(nn.Layer):
         self.dropout = nn.Dropout(config.dropout)
 
     def forward(self, x):
-        return self.dropout(self.fc2(F.gelu(self.fc1(x), approximate=True)))
+        y = keep_name(self.fc1(x), "fc1")
+        return self.dropout(self.fc2(F.gelu(y, approximate=True)))
 
 
 class GPTBlock(nn.Layer):
@@ -364,6 +413,9 @@ class GPTBlock(nn.Layer):
     def forward(self, x, cache=None):
         x = _sp_constrain(x, self._sp)
         x = x + self.dropout(self.attn(self.ln_1(x), cache=cache))
+        # kept, the backward's second forward skips out_proj and, under
+        # tensor parallelism, the all-reduce behind it
+        x = keep_name(x, "attn_res")
         x = x + self.mlp(self.ln_2(x))
         return x
 
@@ -387,6 +439,48 @@ class GPTModel(nn.Layer):
         norm = nn.RMSNorm if config.use_rms_norm else nn.LayerNorm
         self.ln_f = norm(config.hidden_size,
                          epsilon=config.layer_norm_epsilon)
+        self._keep_plans = {}  # traced shape -> (keep sets, bytes, budget)
+
+    def _keep_plan(self, x):
+        """Which tagged tensors each block's backward keeps (one tuple of
+        names a block), for a stack traced on the residual stream ``x``
+        [B, S, H]: as many as fit what the device has free now, with the
+        parameters and the optimizer's state in place, less
+        ``step_reserve_bytes``. The traced shapes are global, so the bytes
+        are cut to a device's share by the fleet mesh. Decided once a
+        shape, so every trace of the step compiles the same program; each
+        trace records it as a ``recompute.keep`` event."""
+        cfg = self.config
+        batch_div = model_deg = 1
+        if cfg.tensor_parallel:
+            from ..distributed.topology import get_hybrid_communicate_group
+            from ..ops.pallas.flash_attention import flash_batch_axes
+            mesh = get_hybrid_communicate_group().mesh
+            model_deg = int(mesh.shape.get("model", 1))
+            batch_div = math.prod(int(mesh.shape[a]) for a in
+                                  flash_batch_axes(mesh, x.shape[0]))
+        key = (tuple(x.shape), str(x.dtype), batch_div, model_deg)
+        plan = self._keep_plans.get(key)
+        if plan is None:
+            tokens = x.shape[0] * x.shape[1] // batch_div
+            itemsize = jnp.dtype(x.dtype).itemsize
+            sizes = block_keep_bytes(cfg, tokens, itemsize, model_deg)
+            free = device_free_bytes()
+            budget = 0 if free is None else free - step_reserve_bytes(
+                cfg, tokens, itemsize, model_deg)
+            plan = (choose_keep(budget, sizes, cfg.num_layers), sizes,
+                    budget)
+            self._keep_plans[key] = plan
+        tr = _trc.get_buffer()
+        if tr is not None:
+            keep, sizes, budget = plan
+            blocks = {n: sum(n in k for k in keep) for n in sizes}
+            tr.add("recompute.keep", time.time(), 0.0, cat="step", args={
+                "names": [n for n in sizes if blocks[n]],
+                "blocks": blocks, "layers": cfg.num_layers,
+                "bytes": sum(sizes[n] * blocks[n] for n in sizes),
+                "budget": int(budget), "name_bytes": sizes})
+        return plan[0]
 
     def forward(self, input_ids, caches=None, pos_offset=0):
         b, s = input_ids.shape
@@ -412,13 +506,14 @@ class GPTModel(nn.Layer):
         x = self.wte(input_ids) + self.wpe(pos)
         x = self.drop(x)
         remat = self.config.recompute and self.training and caches is None
+        if remat:
+            keep = self._keep_plan(x)
         for i, block in enumerate(self.h):
             if remat:
                 # jax.checkpoint per block: backward rematerializes the
-                # block, bounding live activations to one layer
-                # (reference: fleet recompute granularity "full")
-                from ..distributed.fleet.recompute import recompute
-                x = recompute(block, x)
+                # block (reference: fleet recompute granularity "full"),
+                # less the matmul outputs the device has room to keep
+                x = recompute(block, x, keep=keep[i])
             else:
                 x = block(x, cache=None if caches is None else caches[i])
         return self.ln_f(x)

@@ -86,6 +86,18 @@ One event comes from a kernel, at trace time and not per step (``cat``
     ``[block_q, block_k, chunk]``), ``steps_live`` / ``steps_dead`` (grid
     steps a kernel's call takes, and those the causal mask empties).
 
+One event comes from the training step, also at trace time (``cat``
+``step``; ``models/gpt.py`` ``GPTModel._keep_plan``):
+
+``recompute.keep``
+    what the blocks' backward keeps instead of recomputing, decided from
+    the device's free memory when the step is traced. Args: ``names``
+    (the tagged tensors kept somewhere, in order of worth), ``blocks``
+    (name -> in how many blocks), ``layers``, ``bytes`` (kept on a
+    device, all blocks), ``budget`` (free bytes less the step's reserve; 0
+    where the platform reports no memory) and ``name_bytes`` (one block's
+    tensor of each name on a device). ``names`` empty: full recompute.
+
 Request tracing (ISSUE 20): :func:`mint_context` mints a trace context
 (``{"tid": <hex id>, "ps": <parent span, 0 = root>}``) that rides the
 fleet wire; every process feeds that request's spans through

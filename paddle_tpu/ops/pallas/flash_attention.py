@@ -62,6 +62,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -551,7 +552,11 @@ def _fa_fwd(q, k, v, scale, causal, schedule, kv_len, interpret):
                         kv_len)
     # only lane 0 is meaningful — keep one lane in the fwd->bwd residual
     # (128x less HBM held across the backward) and re-broadcast in _flash_bwd
-    return o, (q, k, v, o, lse[..., :1])
+    # named for a jax.checkpoint policy: one that saves "attn_out" keeps
+    # this kernel out of the backward's second forward
+    o = checkpoint_name(o, "attn_out")
+    lse = checkpoint_name(lse[..., :1], "attn_out")
+    return o, (q, k, v, o, lse)
 
 
 def _fa_bwd(scale, causal, schedule, kv_len, interpret, res, g):

@@ -7,7 +7,13 @@ matched: group_sharded_stage2/3 reduce-scatter + gather-on-use semantics.
 
 Note: the all-reduce+dynamic-slice -> reduce-scatter fusion pass runs on
 TPU but not in the CPU SPMD pipeline, so tests accept either form while
-asserting the essential property — per-device-sharded update math.
+asserting the essential property — per-device-sharded update math. On the
+TPU the fused form is not spelled ``reduce-scatter(`` either: the slice is
+fused with the all-reduce into a ``kind=kCustom`` fusion that calls a
+computation named ``%all-reduce-scatter.N`` (the benchmark's hybrid step
+compiled for a described v5e 2x2, ISSUE 32), so a count of
+``reduce-scatter(`` in a TPU program's text reads 0 where the scatter is
+there.
 """
 import re
 

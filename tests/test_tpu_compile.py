@@ -234,26 +234,14 @@ def test_sharded_flash_moves_nothing_between_chips(topo):
     assert "bf16[40,2048,128]" in text and "bf16[80,2048,128]" not in text
 
 
-def test_gpt_attention_gathers_no_qkv_under_the_fleet_mesh(topo,
-                                                           monkeypatch):
-    """One ``GPTAttention`` at GPT-3 13B widths under fleet's own mesh
-    (mp 2 x sharding 2 over the four described chips), forward and
-    backward, the input split over 'sharding': no all-gather puts q, k,
-    v, the output or a cotangent of theirs together across the sharding
-    group, and the kernels run on a chip's own share."""
-    import paddle_tpu as paddle
-    from jax.sharding import NamedSharding, PartitionSpec as P
+def _fleet_mesh(topo, monkeypatch):
+    """fleet's own mesh, mp 2 x sharding 2, over the four described chips.
+    fleet builds its mesh from jax.devices(): hand it the described chips
+    (which also makes on_tpu() say yes, so the kernel is chosen as on the
+    chip), and leave the weights where they were drawn -- nothing can be
+    put on a chip that is not there."""
     from paddle_tpu.distributed import (collective, env, fleet, placement,
                                         topology)
-    from paddle_tpu.distributed.fleet.pipeline_compiled import \
-        _functionalize
-    from paddle_tpu.models import GPTConfig
-    from paddle_tpu.models.gpt import GPTAttention
-
-    # fleet builds its mesh from jax.devices(): hand it the described
-    # chips (which also makes on_tpu() say yes, so the kernel is chosen as
-    # on the chip), and leave the weights where they were drawn -- nothing
-    # can be put on a chip that is not there
     chips = list(topo.devices[:4])
     monkeypatch.setattr(jax, "devices", lambda *a: chips)
     monkeypatch.setattr(jax, "device_count", lambda *a: len(chips))
@@ -274,7 +262,24 @@ def test_gpt_attention_gathers_no_qkv_under_the_fleet_mesh(topo,
     mesh = fleet.init(is_collective=True, strategy=strategy).mesh
     assert dict(mesh.shape) == {"data": 1, "pipe": 1, "sharding": 2,
                                 "sep": 1, "model": 2}
+    return mesh
 
+
+def test_gpt_attention_gathers_no_qkv_under_the_fleet_mesh(topo,
+                                                           monkeypatch):
+    """One ``GPTAttention`` at GPT-3 13B widths under fleet's own mesh
+    (mp 2 x sharding 2 over the four described chips), forward and
+    backward, the input split over 'sharding': no all-gather puts q, k,
+    v, the output or a cotangent of theirs together across the sharding
+    group, and the kernels run on a chip's own share."""
+    import paddle_tpu as paddle
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.distributed.fleet.pipeline_compiled import \
+        _functionalize
+    from paddle_tpu.models import GPTConfig
+    from paddle_tpu.models.gpt import GPTAttention
+
+    mesh = _fleet_mesh(topo, monkeypatch)
     hidden, heads, batch, seq = 5120, 40, 4, 2048
     paddle.seed(0)
     attn = GPTAttention(GPTConfig(
@@ -305,6 +310,73 @@ def test_gpt_attention_gathers_no_qkv_under_the_fleet_mesh(topo,
     gathered = [line for line in _collectives(text, "all-gather")
                 if f"[{batch},{seq}," in line.split(" all-gather")[0]]
     assert gathered == []
+
+
+@pytest.mark.parametrize("keep,reduced,forwards", [
+    ((), 5, 2), (("attn_res",), 4, 2),
+    (("attn_res", "fc1", "attn_out"), 4, 1)])
+def test_kept_residual_drops_one_tensor_parallel_all_reduce(
+        topo, monkeypatch, keep, reduced, forwards):
+    """One ``GPTBlock`` under mp 2 x sharding 2 through
+    ``recompute(block, x, keep=...)`` and the program's tape: the
+    [B, S, H] all-reduces over the 'model' pairs, counted by
+    ``channel_id``, are two in the forward, two in the backward and one
+    in the forward that ``jax.checkpoint`` runs again; keeping
+    ``attn_res`` takes that one (behind ``out_proj``) out. The flash
+    forward kernel runs twice unless ``attn_out`` (tagged in its own
+    forward rule) is kept."""
+    import re
+    import paddle_tpu as paddle
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.distributed import fleet
+    from paddle_tpu.models import GPTConfig
+    from paddle_tpu.models.gpt import GPTBlock
+
+    mesh = _fleet_mesh(topo, monkeypatch)
+    hidden, heads, batch, seq = 1024, 8, 4, 512
+    paddle.seed(0)
+    block = GPTBlock(GPTConfig(
+        vocab_size=128, hidden_size=hidden, num_layers=1, num_heads=heads,
+        max_seq_len=seq, dropout=0.0, tensor_parallel=True))
+    named = list(block.named_parameters())
+    params = [p for _, p in named]
+    split = {"attn.qkv_proj.weight": P(None, "model"),
+             "attn.qkv_proj.bias": P("model"),
+             "attn.out_proj.weight": P("model", None),
+             "mlp.fc1.weight": P(None, "model"),
+             "mlp.fc1.bias": P("model"),
+             "mlp.fc2.weight": P("model", None)}
+    assert set(split) < {n for n, _ in named}
+
+    def grads(arrs, x):
+        saved = [p._data for p in params]
+        for p, a in zip(params, arrs):
+            p._data = a
+        try:
+            out = fleet.recompute(block, paddle.to_tensor(x), keep=keep)
+            out.astype("float32").sin().sum().backward()
+            return [p.grad._data for p in params]
+        finally:
+            for p, a in zip(params, saved):
+                p._data, p._grad = a, None
+
+    def aval(shape, spec):
+        return jax.ShapeDtypeStruct(tuple(shape), BF16,
+                                    sharding=NamedSharding(mesh, spec))
+
+    text = jax.jit(grads).lower(
+        [aval(p.shape, split.get(n, P())) for n, p in named],
+        aval((batch, seq, hidden), P("sharding", None, None))
+    ).compile().as_text()
+    on_chip = f"bf16[{batch // 2},{seq},{hidden}]"
+    channels = {re.search(r"channel_id=(\d+)", line).group(1)
+                for line in _collectives(text, "all-reduce")
+                if line.split(" all-reduce")[0].count(on_chip)
+                # the 'model' pairs {0,1},{2,3}; the gradients' reductions
+                # over the 'sharding' pairs read [2,2]<=[2,2]T(1,0)
+                and "replica_groups=[2,2]<=[4]," in line}
+    assert len(channels) == reduced
+    assert _kernel_names(text).count("flash_attention_fwd") == forwards
 
 
 def test_layer_norm(one_chip):

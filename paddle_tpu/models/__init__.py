@@ -18,3 +18,6 @@ from .vision_zoo import (  # noqa: F401
 from .mla_moe import (  # noqa: F401
     MLAMoEConfig, MLAMoEForCausalLM, mla_moe_tiny,
 )
+from .afmoe import (  # noqa: F401
+    AfmoeConfig, AfmoeForCausalLM, afmoe_tiny,
+)

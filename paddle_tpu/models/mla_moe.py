@@ -34,8 +34,9 @@ import numpy as np
 
 from .. import nn
 from ..core.dispatch import apply
-from ..core.tensor import Tensor
-from ..incubate.moe import DroplessMoELayer, gated_silu
+from ..incubate.moe import DroplessMoELayer
+from .decoder_common import (GatedMLP, greedy_generate, positions,
+                             rms as _rms, valid_tokens as _valid_tokens)
 
 __all__ = ["MLAMoEConfig", "MLAMoEForCausalLM", "mla_moe_tiny",
            "yarn_inv_freq", "yarn_mscale"]
@@ -145,12 +146,6 @@ def _rope(x, pos, inv_freq, mscale):
     a, b = xf[..., 0], xf[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
-
-
-def _rms(x, w, eps):
-    xf = x.astype(jnp.float32)
-    out = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    return (out * w.astype(jnp.float32)).astype(x.dtype)
 
 
 # ------------------------------------------------------------- attention
@@ -286,35 +281,6 @@ class MLAttention(nn.Layer):
 
 # ------------------------------------------------------------------ block
 
-class GatedMLP(nn.Layer):
-    def __init__(self, cfg: MLAMoEConfig, width):
-        super().__init__()
-        from ..nn import initializer as I
-        d = cfg.hidden_size
-        self.width = int(width)
-        self.w13 = self.create_parameter(
-            [d, 2 * self.width], dtype=cfg.dtype,
-            default_initializer=I.Normal(0.0, d ** -0.5))
-        self.w2 = self.create_parameter(
-            [self.width, d], dtype=cfg.dtype,
-            default_initializer=I.Normal(0.0, self.width ** -0.5))
-
-    def forward(self, x):
-        return apply("gated_silu_mlp",
-                     lambda a, w13, w2: jnp.matmul(
-                         gated_silu(jnp.matmul(a, w13), self.width), w2),
-                     [x, self.w13, self.w2])
-
-
-def _valid_tokens(cache, total):
-    """Which tokens of a ragged round's flat stream are real."""
-    def fwd(rs, rl, kl):
-        from ..ops.pallas.ragged_attention import ragged_row_index
-        return ragged_row_index(rs, rl, kl, total)[2]
-    return apply("ragged_valid_tokens", fwd,
-                 [cache["row_starts"], cache["row_lens"], cache["kv_lens"]])
-
-
 class MLAMoEBlock(nn.Layer):
     def __init__(self, cfg: MLAMoEConfig, index):
         super().__init__()
@@ -383,19 +349,7 @@ class MLAMoEForCausalLM(nn.Layer):
                            row_align=cfg.cache_row_align)] * cfg.num_layers
 
     def forward(self, input_ids, caches=None, pos_offset=0):
-        b, s = input_ids.shape
-        from .. import ops
-        if isinstance(pos_offset, Tensor) and len(pos_offset.shape) == 2:
-            pos = pos_offset.astype("int32")       # per-token positions
-        elif isinstance(pos_offset, Tensor) and len(pos_offset.shape) == 1:
-            pos = pos_offset.astype("int32").unsqueeze(1) \
-                + ops.arange(s, dtype="int32").unsqueeze(0)
-        elif isinstance(pos_offset, Tensor):
-            pos = (ops.arange(s, dtype="int32")
-                   + pos_offset.astype("int32")).unsqueeze(0)
-        else:
-            pos = ops.arange(pos_offset, pos_offset + s,
-                             dtype="int32").unsqueeze(0)
+        pos = positions(pos_offset, input_ids.shape[1])
         x = apply("embedding_lookup", lambda w, i: w[i],
                   [self.embed, input_ids])
         for i, block in enumerate(self.layers):
@@ -405,30 +359,6 @@ class MLAMoEForCausalLM(nn.Layer):
     def generate(self, input_ids, max_new_tokens=32, eos_token_id=None):
         """Greedy decoding with the dense latent cache: the prompt in one
         forward, then a token at a time. -> ids [B, prompt + new]."""
-        from .. import ops
-        from ..core.autograd import no_grad
-        if input_ids.shape[1] + max_new_tokens > self.config.max_seq_len:
-            raise ValueError(
-                f"prompt ({input_ids.shape[1]}) + max_new_tokens "
-                f"({max_new_tokens}) exceeds max_seq_len "
-                f"({self.config.max_seq_len})")
-        was_training = self.training
-        self.eval()
-        try:
-            with no_grad():
-                caches = [{"latent": None} for _ in self.layers]
-                out, cur = input_ids, input_ids.shape[1]
-                logits = self(input_ids, caches=caches)
-                for _ in range(max_new_tokens):
-                    nxt = ops.argmax(logits[:, -1], axis=-1,
-                                     keepdim=True).astype(input_ids.dtype)
-                    out = ops.concat([out, nxt], axis=1)
-                    if eos_token_id is not None and bool(
-                            jnp.all(nxt._data == eos_token_id)):
-                        break
-                    logits = self(nxt, caches=caches, pos_offset=cur)
-                    cur += 1
-                return out
-        finally:
-            if was_training:
-                self.train()
+        return greedy_generate(self, input_ids, max_new_tokens,
+                               eos_token_id,
+                               [{"latent": None} for _ in self.layers])

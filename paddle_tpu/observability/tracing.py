@@ -40,10 +40,13 @@ and ``_serve_loop`` open them; ``cat`` is ``serving``):
     tokens in it), ``row_lens`` / ``kv_lens`` (per launched row: query
     tokens, context length after them; at most ``max_slots`` each),
     ``decode_rows``, ``prefill_rows``, ``prefill_tokens``, and the rows
-    of the cache the round's attention has to read (the sum of
-    ``kv_lens``) under the name of the cache's kind: ``kv_rows`` for keys
-    and values by head, ``latent_rows`` for a latent cache (the
-    benchmark's ``mla_roofline_pct.serve`` reads it).
+    of the cache a layer of each page group has to read (the sum of
+    ``kv_lens``, or of ``min(kv_len, window + row_len - 1)`` for a group
+    with a window) under the name of what the group keeps: ``kv_rows``
+    for keys and values by head, ``latent_rows`` for a latent cache,
+    ``window_rows`` for keys and values behind a window (the benchmark's
+    ``mla_roofline_pct.serve`` and ``windowed_roofline_pct.serve`` check
+    their counts against them).
 ``round.schedule``
     ``scheduler.schedule()``, ``ensure_decode_capacity()``, admission and
     eviction bookkeeping.
@@ -59,6 +62,13 @@ and ``_serve_loop`` open them; ``cat`` is ``serving``):
     metrics hooks.
 ``serve.idle_wait``
     one ``_wake.wait(0.02)`` of the serve loop: no work pending.
+
+``cache.window_release``
+    one zero-length event a round of an engine with a windowed page
+    group, args ``round`` and ``pages``: by group name, the pages that
+    slid out of the window since the last round and went back to the
+    allocator before this round's admissions
+    (``scheduler.release_slid_pages``).
 
 ``moe.route`` (and any other name a layer reports)
     what the layers of the round's program put under ``cache["aux"]``:

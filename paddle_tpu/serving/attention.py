@@ -12,6 +12,12 @@ pools as a dict ``name -> [P, page, *row]``.
 * ``mla_latent`` — one shared latent row a token, read by every query
   head, values the first ``value_width`` entries of the same row:
   ``ops/pallas/mla_ragged_attention.py``.
+* ``kv_windowed`` — keys and values with the KV heads side by side in one
+  row, grouped-query, looking back ``spec.window`` tokens or all the way:
+  ``ops/pallas/windowed_ragged_attention.py``.
+
+Each class also says what a layer of its kind has to read in a round
+(``rows_read``), under the name the ``decode_round`` span carries it.
 """
 from __future__ import annotations
 
@@ -23,17 +29,22 @@ from .ragged_attention import (ab_compare_ragged, pad_total_tokens,
                                ragged_paged_attention,
                                sharded_ragged_attention)
 
-__all__ = ["for_kind", "KVAttention", "LatentAttention"]
+__all__ = ["for_kind", "KVAttention", "LatentAttention",
+           "WindowedAttention"]
 
 
 class KVAttention:
     """Keys and values by head, GQA-grouped; pools ``k`` and ``v``."""
 
     kind = "kv"
-    rows_read = "kv_rows"       # the key of ``decode_round``'s row count
 
     def __init__(self, spec):
         self.spec = spec
+
+    def rows_read(self, row_lens, kv_lens):
+        """-> (the key of ``decode_round``'s row count, the rows a layer
+        reads for these launched rows)."""
+        return "kv_rows", int(np.sum(kv_lens))
 
     def check_mesh(self, degree, axis):
         heads, kv_heads = self.spec.query[0], self.spec.rows["k"][0]
@@ -70,10 +81,12 @@ class LatentAttention:
     form); pool ``latent``."""
 
     kind = "mla_latent"
-    rows_read = "latent_rows"
 
     def __init__(self, spec):
         self.spec = spec
+
+    def rows_read(self, row_lens, kv_lens):
+        return "latent_rows", int(np.sum(kv_lens))
 
     def check_mesh(self, degree, axis):
         raise ValueError("a latent cache is one row for all heads: it "
@@ -108,7 +121,69 @@ class LatentAttention:
             q, p["latent"], rs, rl, kl, bt, **kw)
 
 
-_KINDS = {c.kind: c for c in (KVAttention, LatentAttention)}
+class WindowedAttention:
+    """Keys and values with the KV heads side by side in one row (pools
+    ``k`` and ``v``, ``[P, page, KVH * Dh]``), grouped-query, over the
+    last ``spec.window`` tokens or over all of them."""
+
+    kind = "kv_windowed"
+
+    def __init__(self, spec):
+        self.spec = spec
+        self.window = spec.window
+
+    def rows_read(self, row_lens, kv_lens):
+        """A row of ``n`` tokens ending at context ``kv`` reads its last
+        ``window + n - 1`` rows (what its first token sees, and the
+        tokens after it), or all ``kv``. Host arrays, as the round's
+        assembly holds them."""
+        if self.window is None:
+            return "kv_rows", int(np.sum(kv_lens))
+        return "window_rows", int(np.minimum(
+            kv_lens, row_lens + (self.window - 1)).sum())
+
+    def check_mesh(self, degree, axis):
+        raise ValueError("the windowed kernel reads every KV head of a "
+                         f"page at once: no split over mesh axis {axis}")
+
+    def gate_ragged(self, pools, rows, tokens, page_size, max_pages,
+                    max_seq_len):
+        """Decode rows only, each at a context of 16 pages (or the
+        table's, if shorter), whatever the round's pad and the engine's
+        table: the XLA twin gathers every row's whole block table for
+        each token, rows x table x KVH x Dh values of keys and of values;
+        at a 72-page table that is several GiB beside the weights."""
+        from ..ops.pallas import windowed_ragged_attention as _win
+        del tokens
+        T = pad_total_tokens(rows)
+        pages = min(max_pages, 16)
+        q = jax.random.normal(jax.random.PRNGKey(0),
+                              (T,) + self.spec.query, self.spec.dtype)
+        args = (q, pools["k"], pools["v"], np.arange(rows, dtype=np.int32),
+                np.ones(rows, np.int32),
+                np.full((rows,), min(pages * page_size, max_seq_len),
+                        np.int32),
+                np.zeros((rows, pages), np.int32))
+        return _gate.ab_gate(
+            "windowed_ragged_attention",
+            lambda *a: _win.windowed_ragged_attention_reference(
+                *a, window=self.window),
+            lambda *a: _win.windowed_ragged_attention(
+                *a, window=self.window),
+            tuple(jax.numpy.asarray(a) for a in args),
+            repeats=20, sig=_gate.shape_sig(q))
+
+    def impls(self, backend, mesh=None, mesh_axis="model"):
+        from ..ops.pallas import windowed_ragged_attention as _win
+        fn = _win.windowed_ragged_attention if backend == "pallas" \
+            else _win.windowed_ragged_attention_reference
+        window = self.window
+        return lambda q, p, rs, rl, kl, bt, **kw: fn(
+            q, p["k"], p["v"], rs, rl, kl, bt, window=window, **kw)
+
+
+_KINDS = {c.kind: c for c in (KVAttention, LatentAttention,
+                              WindowedAttention)}
 
 
 def for_kind(spec):

@@ -139,13 +139,14 @@ class ServingEngine:
         # are allocated from that declaration (GQA pools carry only the KV
         # heads, a latent cache one row for all heads) and its kind picks
         # the attention that reads them
+        # Layers that keep the same, as far back, form a page group with
+        # its own pages and its own block table a request; ``num_pages``
+        # is one count for every group or a dict by group name
         specs = list(model.cache_spec())
-        kinds = sorted({s.kind for s in specs})
-        if len(kinds) != 1:
-            raise ValueError("one engine serves layers of one kind of "
-                             f"cached state; the model declares {kinds}")
-        self._attention = _attention.for_kind(specs[0])
-        self.kv = PagedKVCache(specs, int(num_pages), self.page_size)
+        self.kv = PagedKVCache(specs, num_pages, self.page_size)
+        self._attentions = [_attention.for_kind(specs[g.layers[0]])
+                            for g in self.kv.groups]
+        self._attention = self._attentions[0]
         self.num_layers = len(specs)
         # models with keys and values by head say how many KV heads
         self.num_kv_heads = specs[0].rows["k"][0] \
@@ -156,6 +157,8 @@ class ServingEngine:
         # With a fleet PageShareClient attached the trie becomes fleet-
         # wide: a local miss consults the store-published index and
         # imports the hot pages (system prompts prefill once per FLEET)
+        if prefix_cache:
+            self.kv.require_one_unwindowed_group("the prefix cache")
         if not prefix_cache:
             self.prefix = None
         elif page_share is not None:
@@ -164,14 +167,11 @@ class ServingEngine:
                                             page_share)
         else:
             self.prefix = PrefixCache(self.kv.allocator, self.page_size)
-        self.scheduler = ContinuousBatchingScheduler(
-            self.kv.allocator, self.max_slots, self.page_size,
-            cfg.max_seq_len, max_queue=max_queue,
-            prefix_cache=self.prefix)
         self.metrics = ServingMetrics(registry=registry,
                                       prefix_enabled=self.prefix
                                       is not None, engine=engine_id)
-        self.metrics.on_cache_spec(kinds[0], sum(self.kv.bytes_per_token()))
+        self.metrics.on_cache_spec(specs[0].kind,
+                                   sum(self.kv.bytes_per_token()))
         # chunked prefill: split prompts into prefill_chunk-token chunks
         # and interleave at most prefill_token_budget chunk-tokens per
         # scheduler round beside the decode rows — a long prompt arriving
@@ -187,6 +187,10 @@ class ServingEngine:
                 "whole and the budget would be silently ignored)")
         self._prefill_budget = int(prefill_token_budget) \
             if prefill_token_budget else (self.prefill_chunk or 0)
+        self.scheduler = ContinuousBatchingScheduler(
+            self.kv.groups, self.max_slots, self.page_size,
+            cfg.max_seq_len, max_queue=max_queue,
+            prefix_cache=self.prefix, prefill_chunk=self.prefill_chunk)
         self._prefilling: list = []     # FIFO of mid-prefill requests
         # the round's token pads: the power-of-two schedule, or an
         # explicit ladder (every round pads up to its next entry)
@@ -204,9 +208,11 @@ class ServingEngine:
         if mesh is not None and int(mesh.shape.get(mesh_axis, 1)) > 1:
             self._attention.check_mesh(int(mesh.shape[mesh_axis]),
                                        mesh_axis)
-        # what the round's program hands each layer beside its pools
-        self._attn_impl = self._attention.impls(
-            self.attn_backend, mesh=mesh, mesh_axis=mesh_axis)
+        # what the round's program hands each layer beside its pools:
+        # the attention of the layer's page group
+        self._attn_impls = [a.impls(self.attn_backend, mesh=mesh,
+                                    mesh_axis=mesh_axis)
+                            for a in self._attentions]
         self._params = list(model.parameters())
         self._param_arrays = [p._data for p in self._params]
         self._jit = bool(jit)
@@ -268,11 +274,17 @@ class ServingEngine:
         self.metrics.on_compile(len(self._programs))
 
     # -------------------------------------------------------- ragged round
-    def _layer_caches(self, pools, **shared):
+    def _layer_caches(self, pools, bt, **shared):
         """One cache dict a layer: its own pools (as Tensors, by the row
-        names it declared) beside what the whole round shares."""
-        return [dict(shared, pools={n: Tensor(a) for n, a in p.items()})
-                for p in pools]
+        names it declared), its page group's block table (``bt`` is the
+        one table of a one-group model, else one a group, stacked) and
+        attention, beside what the whole round shares."""
+        tables = [Tensor(bt)] if len(self.kv.groups) == 1 \
+            else [Tensor(bt[g]) for g in range(len(self.kv.groups))]
+        return [dict(shared, block_tables=tables[g],
+                     attn_impl=self._attn_impls[g],
+                     pools={n: Tensor(a) for n, a in p.items()})
+                for p, g in zip(pools, self.kv.group_of)]
 
     @staticmethod
     def _pools_out(caches):
@@ -288,7 +300,6 @@ class ServingEngine:
         constants), pools are donated on TPU; jax.jit specializes per
         padded total_tokens ONLY."""
         model, params = self.model, self._params
-        attn_impl = self._attn_impl
         emit_logits = self.emit_logits
         from ..ops.pallas.ragged_attention import ragged_row_index
 
@@ -300,10 +311,9 @@ class ServingEngine:
                                                  kv_lens, T)
                 positions = jnp.where(valid, pos, 0).astype(jnp.int32)
                 caches = self._layer_caches(
-                    pools, ragged=True, block_tables=Tensor(bt),
+                    pools, bt, ragged=True,
                     row_starts=Tensor(row_starts),
-                    row_lens=Tensor(row_lens), kv_lens=Tensor(kv_lens),
-                    attn_impl=attn_impl)
+                    row_lens=Tensor(row_lens), kv_lens=Tensor(kv_lens))
                 logits = model(Tensor(tokens[None, :]), caches=caches,
                                pos_offset=Tensor(positions[None, :]))
                 # each row's last valid token carries the round's output
@@ -376,9 +386,16 @@ class ServingEngine:
                     self._param_arrays, jnp.zeros(p, jnp.int32),
                     jnp.full(R, p, jnp.int32), jnp.zeros(R, jnp.int32),
                     jnp.zeros(R, jnp.int32),
-                    jnp.zeros((R, self.max_pages), jnp.int32),
+                    jnp.zeros(self._bt_shape(), jnp.int32),
                     self.kv.pools)
         return pads
+
+    def _bt_shape(self):
+        """The round's block tables: ``[rows, max_pages]`` of a one-group
+        model, one such a group stacked otherwise."""
+        G = len(self.kv.groups)
+        return (self.max_slots, self.max_pages) if G == 1 \
+            else (G, self.max_slots, self.max_pages)
 
     def _step_ragged(self):
         """One scheduler round: admit, grow/evict, then assemble decode
@@ -395,12 +412,31 @@ class ServingEngine:
         if tr is not None:
             rnd = _trc.phase(tr, "decode_round", round=self._steps).open()
             ph = _trc.phase(tr, "round.schedule", round=self._steps).open()
+        # pages that slid out of a windowed group's window since the last
+        # round go back first: this round's admissions may take them
+        freed = self.scheduler.release_slid_pages()
         admitted = self.scheduler.schedule()
         for req in admitted:
             self.metrics.on_admit(req)
             req.state = "prefilling"
             self._prefilling.append(req)
-        _, evicted = self.scheduler.ensure_decode_capacity()
+        # prefill rows: FIFO, at most budget // chunk rows per round each
+        # contributing one chunk (ITL stays bounded by the budget);
+        # unchunked mode takes every pending row's whole remaining tail
+        if self.prefill_chunk is not None:
+            n_rows = max(1, self._prefill_budget // self.prefill_chunk)
+            prefill_rows = self._prefilling[:n_rows]
+        else:
+            prefill_rows = list(self._prefilling)
+        chunks, prompts = [], {}
+        for req in prefill_rows:
+            p = req.effective_prompt()
+            prompts[req.request_id] = p
+            take = len(p) - req.num_cached
+            if self.prefill_chunk is not None:
+                take = min(take, self.prefill_chunk)
+            chunks.append((req, take))
+        _, evicted = self.scheduler.ensure_decode_capacity(chunks)
         for req in evicted:
             self.metrics.on_evict(req)
         self._prefilling = [r for r in self._prefilling
@@ -410,55 +446,52 @@ class ServingEngine:
         decode_rows = sorted(
             (r for r in self.scheduler.active.values()
              if r.state == "active"), key=lambda r: r.slot)
-        # prefill rows: FIFO, at most budget // chunk rows per round each
-        # contributing one chunk (ITL stays bounded by the budget);
-        # unchunked mode takes every pending row's whole remaining tail
-        if self.prefill_chunk is not None:
-            n_rows = max(1, self._prefill_budget // self.prefill_chunk)
-            prefill_rows = self._prefilling[:n_rows]
-        else:
-            prefill_rows = list(self._prefilling)
         plan = [(req, 1, req.generated[-1:]) for req in decode_rows]
-        prompts = {}
-        for req in prefill_rows:
-            p = req.effective_prompt()
-            prompts[req.request_id] = p
-            take = len(p) - req.num_cached
-            if self.prefill_chunk is not None:
-                take = min(take, self.prefill_chunk)
-            plan.append((req, take,
-                         p[req.num_cached:req.num_cached + take]))
+        for req, take in chunks:
+            if req.state == "prefilling":
+                p = prompts[req.request_id]
+                plan.append((req, take,
+                             p[req.num_cached:req.num_cached + take]))
         if not plan:
             if rnd is not None:
                 ph.close()
                 rnd.close(record=False)
             return 0
-        R, maxp = self.max_slots, self.max_pages
+        R = self.max_slots
         total = sum(take for _, take, _ in plan)
         T = self._pad(total)
         tokens = np.zeros(T, np.int32)
         row_starts = np.full(R, T, np.int32)   # unused rows: sentinel T
         row_lens = np.zeros(R, np.int32)
         kv_lens = np.zeros(R, np.int32)
-        bt = np.zeros((R, maxp), np.int32)
+        bt = np.zeros(self._bt_shape(), np.int32)
+        tables = bt[None] if bt.ndim == 2 else bt       # one a page group
         cursor = 0
         for i, (req, take, seg) in enumerate(plan):
             tokens[cursor:cursor + take] = seg
             row_starts[i] = cursor
             row_lens[i] = take
             kv_lens[i] = req.num_cached + take
-            bt[i, :len(req.pages)] = req.pages
+            for table, pages in zip(tables, req.group_pages):
+                table[i, :len(pages)] = pages
             cursor += take
         if T not in self._ragged_shapes:
             self._ragged_shapes.add(T)
             self._note_program(("ragged", T))
         if rnd is not None:
             n = len(plan)
-            # rows of the cache this round's attention has to read, under
-            # the name of their kind (``kv_rows`` / ``latent_rows``)
+            # rows of the cache a layer of each page group has to read
+            # this round, under the name of what it keeps (``kv_rows`` /
+            # ``latent_rows`` / ``window_rows``)
+            rows_read = {}
+            for a in self._attentions:
+                name, rows = a.rows_read(row_lens[:n], kv_lens[:n])
+                rows_read[name] = rows_read.get(name, 0) + rows
             rnd.set(pad=T, tokens=total, row_lens=row_lens[:n].tolist(),
-                    kv_lens=kv_lens[:n].tolist(),
-                    **{self._attention.rows_read: int(kv_lens[:n].sum())})
+                    kv_lens=kv_lens[:n].tolist(), **rows_read)
+            if freed:
+                tr.add("cache.window_release", rnd.t0, 0.0, cat="serving",
+                       args={"round": self._steps, "pages": freed})
             ph = ph.then("round.launch")
         nxt, row_logits, self.kv.pools, extras = self._ragged_fn(
             self._param_arrays, jnp.asarray(tokens),
@@ -618,6 +651,9 @@ class ServingEngine:
             emitted = self._step_ragged()
             occ = self.kv.occupancy_pct()
             self._peak_occupancy = max(self._peak_occupancy, occ)
+            for group, unreleased in zip(
+                    self.kv.groups, self.scheduler.unreleased_pages()):
+                group.note(unreleased)
             alloc = self.kv.allocator
             share = getattr(self.prefix, "share", None)
             self.metrics.sample_state(
@@ -681,6 +717,7 @@ class ServingEngine:
         Read-only on the pools (shared prefix pages included), serialized
         against rounds — the page migration payload of the disaggregated
         fleet."""
+        self.kv.require_one_unwindowed_group("page migration")
         with self._step_lock:
             length = int(req.num_cached)
             # tpu-lint: ok[HS002] page migration IS the designed host roundtrip: one gather per pool moves this request's state off-device
@@ -708,11 +745,11 @@ class ServingEngine:
         :class:`~.kv_cache.OutOfPages` / :class:`~.scheduler.OutOfSlots`
         when this pool/batch cannot take it (caller falls back to
         :meth:`readmit_request`)."""
-        from .kv_cache import pages_for as _pages_for
+        self.kv.require_one_unwindowed_group("page migration")
         with self._step_lock:
             self._check_accepting()
             pages = self.kv.allocator.alloc(
-                max(1, _pages_for(length, self.page_size)))
+                max(1, pages_for(length, self.page_size)))
             try:
                 for layer, rows in enumerate(layers):
                     self.kv.write_rows(layer, rows, pages, length)
@@ -990,7 +1027,7 @@ class ServingEngine:
 
         return self._ragged_fn.lower(
             [_aval(a) for a in self._param_arrays], i32(T), i32(R), i32(R),
-            i32(R), i32(R, self.max_pages),
+            i32(R), i32(*self._bt_shape()),
             jax.tree_util.tree_map(_aval, self.kv.pools)
             ).compile().as_text()
 
@@ -1013,6 +1050,10 @@ class ServingEngine:
             "cache_bytes_per_token_layer": self.kv.bytes_per_token(),
             "cache_pool_bytes_per_token_layer":
                 self.kv.bytes_per_token(padded=True),
+            # by page group: its pages, those held now and at the fullest
+            # (beside what the same requests' tables spanned then) and
+            # those a windowed group gave back as they slid out
+            "page_groups": {g.name: g.stats() for g in self.kv.groups},
             "prefill_chunk": self.prefill_chunk,
             "prefill_chunk_tokens": self._chunk_tokens,
             "distinct_programs": len(self._programs),

@@ -56,6 +56,7 @@ def migrate_request(src, dst, req):
     :class:`MigrationFailed` when the target cannot take it at all. The
     request object itself moves — callers keep their handle.
     """
+    src.kv.require_one_unwindowed_group("page migration")
     ctx = getattr(req, "trace", None)
     t0 = time.time() if ctx is not None else 0.0
     with src._step_lock:

@@ -219,6 +219,7 @@ class SharedPrefixCache(PrefixCache):
     """
 
     def __init__(self, kv, page_size, share: PageShareClient):
+        kv.require_one_unwindowed_group("page sharing across engines")
         super().__init__(kv.allocator, page_size)
         self.kv = kv
         self.share = share

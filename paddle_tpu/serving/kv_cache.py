@@ -12,8 +12,14 @@ free-list allocator. This replaces the dense ``[B, T, H, Dh]`` buffers of
 worst case and the continuous-batching scheduler can admit until the pool
 — not the batch shape — is full.
 
-Physical page 0 is reserved as the **scrap page**: padded block-table
-entries and a round's pad tokens point at it, so masked lanes of the
+A model's layers are grouped by what they keep for a token and how far
+back (:class:`PageGroup`): each group has its own allocator, page count
+and block table a request, so that layers behind a sliding window give
+their pages back while full layers keep every token.
+
+Physical page 0 of every group is reserved as the **scrap page**: padded
+block-table entries, the entries of pages a windowed group gave back and a
+round's pad tokens point at it, so masked lanes of the
 round have a legal write/read target without branching. All pool updates
 are functional (``.at[].set``) so the round can be one jitted XLA program
 with donated pool buffers.
@@ -27,8 +33,8 @@ import numpy as np
 
 from ..core.dispatch import apply
 
-__all__ = ["BlockAllocator", "PagedKVCache", "LayerState", "kv_state",
-           "pages_for", "OutOfPages", "pool_write_ragged"]
+__all__ = ["BlockAllocator", "PagedKVCache", "PageGroup", "LayerState",
+           "kv_state", "pages_for", "OutOfPages", "pool_write_ragged"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,9 +46,12 @@ class LayerState:
     entry of ``rows`` and hands each layer its own pools back.
 
     kind   which attention reads the rows: ``"kv"`` (keys and values by
-           head) or ``"mla_latent"`` (one shared latent row a token)
+           head), ``"mla_latent"`` (one shared latent row a token) or
+           ``"kv_windowed"`` (keys and values with the heads side by side
+           in one row, read by ``windowed_ragged_attention``)
     rows   name -> shape of a token's row, e.g. ``{"k": (KVH, Dh), "v":
-           (KVH, Dh)}`` or ``{"latent": (576,)}``
+           (KVH, Dh)}``, ``{"latent": (576,)}`` or ``{"k": (KVH * Dh,),
+           "v": (KVH * Dh,)}``
     dtype  the pools' dtype
     query  shape of a token's query as the attention takes it (the
            start-up gate times the backends at this shape)
@@ -50,12 +59,25 @@ class LayerState:
            this. A TPU stores a 576-wide row in 640 lanes whatever is
            declared; a kernel that copies whole pages by DMA needs the
            array to say so. The padding holds zeros and is never a value.
+    window  tokens the layer may look back, the token itself included
+           (token i sees token j where ``0 <= i - j < window``); ``None``
+           = every earlier token. Layers of one ``(kind, window)`` form a
+           **page group** (:class:`PageGroup`): a group with a window
+           gives a page back once every token in it has slid out.
     """
     kind: str
     rows: dict
     dtype: object
     query: tuple
     row_align: int = 1
+    window: int = None
+
+    @property
+    def group(self):
+        """The name of the layer's page group: its kind, and its window
+        where it has one (``kv_windowed``, ``kv_windowed.w4096``)."""
+        return self.kind if self.window is None \
+            else f"{self.kind}.w{int(self.window)}"
 
     def pool_row(self, name):
         """The row's shape as the pool holds it (minor dimension rounded
@@ -227,8 +249,66 @@ class BlockAllocator:
                 self._free.append(p)
 
 
+class PageGroup:
+    """The layers of a model that keep the same thing for a token, as far
+    back: one ``(kind, window)`` of their :class:`LayerState`. A group has
+    its own :class:`BlockAllocator` over its own page count, and a request
+    one block table in it (``GenerationRequest.group_pages``), which the
+    round hands to the group's layers.
+
+    A group with a ``window`` holds of a request only the pages some later
+    token can still see: the scheduler frees, between rounds, every page
+    that lies wholly before ``num_cached - window + 1`` (the first position
+    the next round's first query token sees) and points its entry of the
+    request's table at the scrap page.
+    """
+
+    def __init__(self, allocator, name="kv", window=None, layers=()):
+        self.allocator = allocator
+        self.name = name
+        self.window = None if window is None else int(window)
+        self.layers = list(layers)
+        self.released = 0        # pages given back as they slid out
+        self.peak_held = 0       # most pages with a live reader, and what
+        self.peak_unreleased = 0  # their requests' tables spanned then
+
+    @property
+    def num_pages(self):
+        return self.allocator.num_pages
+
+    def first_live_page(self, num_cached, page_size):
+        """The first page of a request's table that a token at position
+        ``num_cached`` or later can still see."""
+        if self.window is None:
+            return 0
+        return max(0, int(num_cached) - self.window + 1) // int(page_size)
+
+    def admit_tokens(self, tokens, chunk):
+        """Tokens of a ``tokens``-long prompt that have to fit in the
+        group before its first round: all of them, or a window and the
+        round's chunk."""
+        if self.window is None or not chunk:
+            return int(tokens)
+        return min(int(tokens), self.window + int(chunk))
+
+    def note(self, unreleased):
+        """After a round: remember the fullest moment. ``unreleased`` is
+        what the live requests' tables span, freed entries included."""
+        held = self.allocator.used_pages
+        if held >= self.peak_held:
+            self.peak_held, self.peak_unreleased = held, int(unreleased)
+
+    def stats(self):
+        return {"pages": self.num_pages, "window": self.window,
+                "layers": len(self.layers),
+                "held": self.allocator.used_pages,
+                "peak_held": self.peak_held,
+                "peak_unreleased": self.peak_unreleased,
+                "released": self.released}
+
+
 class PagedKVCache:
-    """Per-layer page pools + the allocator that parcels them out.
+    """Per-layer page pools + the allocators that parcel them out.
 
     ``pools[l]`` maps each row name of layer ``l``'s :class:`LayerState`
     to a jnp array ``[num_pages, page_size, *row_shape]`` — keys and
@@ -236,20 +316,68 @@ class PagedKVCache:
     heads, an ``H/KVH`` memory cut), one ``[.., 576]`` latent pool for an
     MLA layer. Round writes happen *inside* the model's attention through
     ``pool_write_ragged`` below (a functional scatter); this class
-    owns prefill writes, the allocator, and test/debug gathers.
+    owns prefill writes, the allocators, and test/debug gathers.
+
+    Layers are grouped by what they keep (:class:`PageGroup`, in order of
+    first appearance): ``num_pages`` is one count for every group, or a
+    dict by group name. A model whose layers all keep the same has one
+    group, ``allocator`` and ``num_pages`` are that group's.
     """
 
     def __init__(self, specs, num_pages, page_size, reserved=1):
         self.specs = list(specs)
         self.num_layers = len(self.specs)
-        self.num_pages = int(num_pages)
         self.page_size = int(page_size)
+        by_name = {}
+        for l, spec in enumerate(self.specs):
+            by_name.setdefault(spec.group, []).append(l)
+        if isinstance(num_pages, dict):
+            if set(num_pages) != set(by_name):
+                raise ValueError(
+                    f"num_pages names the page groups {sorted(num_pages)}; "
+                    f"the model's layers form {sorted(by_name)}")
+            counts = num_pages
+        else:
+            counts = dict.fromkeys(by_name, num_pages)
+        self.groups = [
+            PageGroup(BlockAllocator(int(counts[name]), reserved=reserved),
+                      name, self.specs[layers[0]].window, layers)
+            for name, layers in by_name.items()]
+        self.group_of = [0] * self.num_layers
+        for g, group in enumerate(self.groups):
+            for l in group.layers:
+                self.group_of[l] = g
         self.pools = [
-            {name: jnp.zeros((self.num_pages, self.page_size)
-                             + spec.pool_row(name), spec.dtype)
+            {name: jnp.zeros(
+                (self.groups[self.group_of[l]].num_pages, self.page_size)
+                + spec.pool_row(name), spec.dtype)
              for name in spec.rows}
-            for spec in self.specs]
-        self.allocator = BlockAllocator(num_pages, reserved=reserved)
+            for l, spec in enumerate(self.specs)]
+
+    @property
+    def allocator(self):
+        """The first group's allocator: THE allocator of a model with one
+        page group."""
+        return self.groups[0].allocator
+
+    @property
+    def num_pages(self):
+        return self.groups[0].num_pages
+
+    def require_one_unwindowed_group(self, what):
+        """``what`` (the prefix cache, page sharing, migration) identifies
+        a request's cached state with ONE list of pages that all hold
+        their tokens for good; refuse a model that keeps it otherwise."""
+        windowed = [g.name for g in self.groups if g.window is not None]
+        if windowed:
+            raise ValueError(
+                f"{what} cannot serve a model with a windowed page group "
+                f"({windowed}): a page that slid out of a window was freed "
+                "and cannot be a hit, be shared or be moved")
+        if len(self.groups) > 1:
+            raise ValueError(
+                f"{what} handles one page group a request; the model's "
+                f"layers form {[g.name for g in self.groups]}")
 
     @property
     def dtype(self):
@@ -274,7 +402,8 @@ class PagedKVCache:
                    for p in self.pools for a in p.values())
 
     def occupancy_pct(self):
-        return self.allocator.occupancy_pct()
+        """Of the fullest group."""
+        return max(g.allocator.occupancy_pct() for g in self.groups)
 
     def write_rows(self, layer, rows, pages, length):
         """Write one request's prefill rows (name -> ``[S, *row_shape]``

@@ -31,7 +31,7 @@ import time
 from collections import deque
 
 from ..observability import tracing as _trc
-from .kv_cache import OutOfPages, pages_for
+from .kv_cache import OutOfPages, PageGroup, pages_for
 
 __all__ = ["GenerationRequest", "ContinuousBatchingScheduler",
            "QueueFull", "EngineClosed", "OutOfSlots"]
@@ -109,7 +109,12 @@ class GenerationRequest:
         self.state = "waiting"   # waiting|prefilling|active|finished|failed
         self.error = None
         self.slot = None
-        self.pages: list[int] = []
+        # one block table a page group of the model (kv_cache.PageGroup):
+        # entry i is the physical page of tokens [i * page, (i + 1) * page),
+        # or the scrap page 0 once a windowed group has given it back;
+        # ``released[g]`` entries at the head of table g were given back
+        self.group_pages: list[list[int]] = [[]]
+        self.released: list[int] = [0]
         self.num_cached = 0          # tokens currently in the KV pool
         self.prefix_hit_tokens = 0   # prompt head served from the cache
         self.evictions = 0
@@ -130,6 +135,16 @@ class GenerationRequest:
         self._rng = None
 
     # ---- engine-side helpers -------------------------------------------
+    @property
+    def pages(self):
+        """The block table in the first page group: THE table of a model
+        with one group."""
+        return self.group_pages[0]
+
+    @pages.setter
+    def pages(self, pages):
+        self.group_pages[0] = pages
+
     def effective_prompt(self):
         """Prompt for (re-)prefill: original prompt plus everything already
         generated (an evicted request recomputes its own context)."""
@@ -230,11 +245,24 @@ class GenerationRequest:
 
 
 class ContinuousBatchingScheduler:
-    """Owns the waiting queue, the slot map, and page accounting."""
+    """Owns the waiting queue, the slot map, and page accounting.
+
+    ``allocator`` is one :class:`~.kv_cache.BlockAllocator`, or the
+    :class:`~.kv_cache.PageGroup` list of a model whose layers keep
+    different things (``PagedKVCache.groups``): admission, growth,
+    eviction and finish then act on every group of a request, all or
+    nothing. ``prefill_chunk`` is the most tokens a prefill row advances
+    in a round (None: the whole prompt): a windowed group admits a prompt
+    on a window and a chunk of pages and is given the rest as it slides.
+    """
 
     def __init__(self, allocator, max_slots, page_size, max_seq_len,
-                 max_queue=256, prefix_cache=None):
-        self.allocator = allocator
+                 max_queue=256, prefix_cache=None, prefill_chunk=None):
+        self.groups = list(allocator) \
+            if isinstance(allocator, (list, tuple)) \
+            else [PageGroup(allocator)]
+        self.allocator = self.groups[0].allocator
+        self.prefill_chunk = prefill_chunk
         self.prefix_cache = prefix_cache
         self.max_slots = int(max_slots)
         self.page_size = int(page_size)
@@ -257,10 +285,12 @@ class ContinuousBatchingScheduler:
                 f"prompt ({len(req.prompt_ids)}) + max_new_tokens "
                 f"({req.max_new_tokens}) exceeds max_seq_len "
                 f"({self.max_seq_len})")
-        if pages_for(total, self.page_size) > self.allocator.capacity:
-            raise ValueError(
-                f"request needs {pages_for(total, self.page_size)} pages; "
-                f"pool has {self.allocator.capacity} — it could never run")
+        for group in self.groups:
+            need = self._most_pages(group, total)
+            if need > group.allocator.capacity:
+                raise ValueError(
+                    f"request needs {need} pages; pool has "
+                    f"{group.allocator.capacity} — it could never run")
         with self._space:
             if self._closed:
                 raise self._closed_error()
@@ -317,9 +347,16 @@ class ContinuousBatchingScheduler:
                 # by reference (no prefill compute, no page writes) —
                 # only the tail needs private pages
                 shared, n_shared = self.prefix_cache.lookup(prompt)
-            need = pages_for(len(prompt) + 1, self.page_size) \
-                - len(shared)
-            if not self.allocator.can_alloc(need):
+            # all or nothing: every group must hold what the prompt
+            # needs there before its first round (a windowed group: a
+            # window and a chunk of it; shared pages are the one group's
+            # of a model the prefix cache serves)
+            need = [pages_for(g.admit_tokens(len(prompt) + 1,
+                                             self.prefill_chunk),
+                              self.page_size) for g in self.groups]
+            need[0] -= len(shared)
+            if not all(g.allocator.can_alloc(n)
+                       for g, n in zip(self.groups, need)):
                 if shared:    # un-ref the speculative hit
                     self.allocator.free(shared)
                 break
@@ -333,7 +370,10 @@ class ContinuousBatchingScheduler:
                     continue
                 self.waiting.popleft()
                 self._space.notify_all()
-            req.pages = shared + self.allocator.alloc(need)
+            req.group_pages = [g.allocator.alloc(n)
+                               for g, n in zip(self.groups, need)]
+            req.released = [0] * len(self.groups)
+            req.pages = shared + req.pages
             req.num_cached = n_shared
             req.prefix_hit_tokens = n_shared
             if self.prefix_cache is not None and req.evictions == 0:
@@ -363,32 +403,79 @@ class ContinuousBatchingScheduler:
             _trc.req_event(req.trace, "prefix_hit", now, 0.0,
                            args={"tokens": req.prefix_hit_tokens})
 
-    def ensure_decode_capacity(self):
-        """Before a decode step: every active request writing token
-        ``num_cached`` needs page ``num_cached // page_size``. Grow block
+    def _most_pages(self, group, total):
+        """The most pages a request of ``total`` tokens ever holds in
+        ``group``: all of them, or a window and a chunk and one for the
+        edge."""
+        whole = pages_for(total, self.page_size)
+        if group.window is None:
+            return whole
+        return min(whole, pages_for(
+            group.admit_tokens(total, self.prefill_chunk),
+            self.page_size) + 1)
+
+    def release_slid_pages(self):
+        """Between rounds: give back every page of a windowed group that
+        no later token of its request can see (it lies wholly before
+        ``num_cached - window + 1``), and point its table entry at the
+        scrap page. -> {group name: pages freed} for the windowed groups."""
+        freed = {}
+        for g, group in enumerate(self.groups):
+            if group.window is None:
+                continue
+            n = 0
+            for req in self.active.values():
+                pages, done = req.group_pages[g], req.released[g]
+                first = min(group.first_live_page(req.num_cached,
+                                                  self.page_size),
+                            len(pages))
+                if first <= done:
+                    continue
+                group.allocator.free(pages[done:first])
+                pages[done:first] = [0] * (first - done)
+                req.released[g] = first
+                n += first - done
+            group.released += n
+            freed[group.name] = n
+        return freed
+
+    def unreleased_pages(self):
+        """By group: what the live requests' tables span, entries given
+        back included (what they would hold with nothing released)."""
+        return [sum(len(r.group_pages[g]) for r in self.active.values())
+                for g in range(len(self.groups))]
+
+    def ensure_decode_capacity(self, prefill=()):
+        """Before a round: every active request writing token
+        ``num_cached`` needs page ``num_cached // page_size`` in every
+        group, and each of ``prefill`` (the ``(request, tokens)`` of the
+        prefill rows the round will carry) the pages of its chunk (a group
+        without a window got its whole prompt's at admission). Grow block
         tables, evicting the most-recently-admitted active request when
-        the pool is dry. -> (grown, evicted) request lists."""
+        a pool is dry. -> (grown, evicted) request lists."""
         grown, evicted = [], []
+        chunk_end = {id(req): req.num_cached + take - 1
+                     for req, take in prefill}
         # oldest first: under pressure the senior requests grab pages
         # before the juniors (who are also the eviction victims)
         for req in sorted(self.active.values(),
                           key=lambda r: r.t_admit or 0.0):
-            if req.state != "active":
+            last = req.num_cached if req.state == "active" \
+                else chunk_end.get(id(req))
+            if last is None:
                 continue
-            while req.num_cached // self.page_size >= len(req.pages):
-                try:
-                    req.pages += self.allocator.alloc(1)
-                    grown.append(req)
-                except OutOfPages:
-                    victim = self._pick_victim(exclude=req)
-                    if victim is None:
-                        # only this request is left: nothing to reclaim —
+            for g, group in enumerate(self.groups):
+                while req.slot is not None and \
+                        last // self.page_size >= len(req.group_pages[g]):
+                    try:
+                        req.group_pages[g] += group.allocator.alloc(1)
+                        grown.append(req)
+                    except OutOfPages:
+                        victim = self._pick_victim(exclude=req) or req
+                        # only this request left: nothing to reclaim —
                         # evict IT (it re-prefills once pages free up)
-                        self._evict(req)
-                        evicted.append(req)
-                        break
-                    self._evict(victim)
-                    evicted.append(victim)
+                        self._evict(victim)
+                        evicted.append(victim)
         return grown, evicted
 
     def _pick_victim(self, exclude=None):
@@ -477,9 +564,11 @@ class ContinuousBatchingScheduler:
         return True
 
     def _release(self, req):
-        if req.pages:
-            self.allocator.free(req.pages)
-            req.pages = []
+        for group, pages in zip(self.groups, req.group_pages):
+            # entry 0 is the scrap page: a page already given back
+            group.allocator.free([p for p in pages if p])
+        req.group_pages = [[] for _ in self.groups]
+        req.released = [0] * len(self.groups)
         if req.slot is not None:
             del self.active[req.slot]
             self._free_slots.append(req.slot)

@@ -549,3 +549,79 @@ def test_mla_moe_round_program_at_published_widths(one_chip, monkeypatch):
                       ).compile().as_text()
     assert len(_named(text, "mla_ragged_attention")) == 2
     assert len(_named(text, "moe_grouped_matmul")) == 2
+
+
+# ------------------------------------------------------------------------
+# The gated window / full attention decoder at Trinity-Large's published
+# widths: hidden 3072, 48 query heads over 8 KV heads of 128, a window of
+# 4,096 tokens, 3072-wide experts, 32 of 256 held; pages of 256 tokens,
+# the full group's 2,305 and the window group's 641.
+@pytest.mark.parametrize("tokens,pages,window", [
+    (32, 2305, None), (544, 2305, None), (32, 641, 4096), (544, 641, 4096)])
+def test_windowed_ragged_attention_at_published_widths(one_chip, tokens,
+                                                       pages, window):
+    """A decode round (items of 8 tokens, 16 query rows a KV head at work)
+    and the largest mixed round (items of 32 tokens), with a window and
+    without."""
+    from paddle_tpu.ops.pallas.windowed_ragged_attention import \
+        windowed_ragged_attention
+    meta = ((32,), jnp.int32)
+    pool = ((pages, 256, 8 * 128), BF16)
+    text = _compile(
+        lambda q, k, v, rs, rl, kl, bt: windowed_ragged_attention(
+            q, k, v, rs, rl, kl, bt, window=window),
+        one_chip, ((tokens, 48, 128), BF16), pool, pool, meta, meta, meta,
+        ((32, 18432 // 256), jnp.int32))
+    assert _named(text, "windowed_ragged_attention") == \
+        ["windowed_ragged_attention"]
+
+
+def test_afmoe_round_program_at_published_widths(one_chip, monkeypatch):
+    """A dense sliding layer, a sliding and a full expert layer of the
+    decoder behind the serving engine's ragged round, every width as
+    published and one block table a page group: the program holds the
+    windowed kernel once a layer and the grouped product twice an expert
+    layer, under their names, and fits the chip with its pools."""
+    import json
+    import os
+    from paddle_tpu.models import AfmoeForCausalLM
+    from paddle_tpu.nn import initializer as init
+    from paddle_tpu.ops.pallas import _common as gate
+    from paddle_tpu.serving import ServingEngine
+    from benchmark.models import afmoe as family
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "trinity-large-serve.json")) as f:
+        cfg = json.load(f)
+    cfg.update(num_layers=3, layers_run=[0, 10, 11],
+               layer_types_run=["sliding_attention"] * 2
+               + ["full_attention"])
+    init.set_global_initializer(init.Constant(0.01), init.Constant(0.0))
+    try:
+        model = AfmoeForCausalLM(family.model_config(
+            cfg, moe_backend="pallas"))
+    finally:
+        init.set_global_initializer(None, None)
+    eng = ServingEngine(model, page_size=256, max_slots=32,
+                        num_pages={"kv_windowed.w4096": 8, "kv_windowed": 4},
+                        prefill_chunk=512, attn_backend="pallas",
+                        prefix_cache=False, token_pads=[32, 544])
+    assert [(g.name, g.layers) for g in eng.kv.groups] == [
+        ("kv_windowed.w4096", [0, 1]), ("kv_windowed", [2])]
+    monkeypatch.setattr(gate, "on_tpu", lambda: True)
+    eng._jit = False
+    step = jax.jit(eng._build_ragged_step(), donate_argnums=(6,))
+
+    def aval(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    assert eng._bt_shape() == (2, 32, 72)
+    text = step.lower([aval(a) for a in eng._param_arrays], i32(544),
+                      i32(32), i32(32), i32(32), i32(*eng._bt_shape()),
+                      jax.tree_util.tree_map(aval, eng.kv.pools)
+                      ).compile().as_text()
+    assert len(_named(text, "windowed_ragged_attention")) == 3
+    assert len(_named(text, "moe_grouped_matmul")) == 4

@@ -53,9 +53,15 @@ and ``_serve_loop`` open them; ``cat`` is ``serving``):
 ``round.assemble``
     the plan (decode rows, prefill chunks) and the numpy metadata.
 ``round.launch``
-    the six host-to-device uploads and the call of the round's program.
+    the upload of the round's plan (ONE int32 message, one
+    ``jax.device_put``) and the call of the round's program. Args:
+    ``uploads``, the host-to-device transfers the round made (1).
 ``round.fetch``
-    the token (and logit) fetch: the host blocked on the device.
+    the round's one blocking fetch: the host blocked on the device for
+    the tokens (their copy was asked for at the launch), the emitted
+    logits beside them and, when asked, the logit rows and the layers'
+    reports in the same ``jax.device_get``. Args: ``fetches``, the
+    blocking fetches the round made (1).
 ``round.emit``
     sampling, ``complete_step`` (``on_token`` / ``on_done`` callbacks run
     here, a closed loop's resubmits among them), prefill bookkeeping,
@@ -481,11 +487,12 @@ class phase:
     ``p = p.then("round.launch")`` ... ``p.close()`` where the phases of
     one round follow one another."""
 
-    __slots__ = ("buf", "name", "cat", "args", "t0", "_ann")
+    __slots__ = ("buf", "name", "cat", "args", "t0", "_ann", "_opened")
 
     def __init__(self, buf, name, cat="serving", **args):
         self.buf, self.name, self.cat, self.args = buf, name, cat, args
         self._ann = None
+        self._opened = args
 
     def open(self):
         ann = _ANNOTATION or _annotation()
@@ -496,8 +503,9 @@ class phase:
         return self
 
     def set(self, **args):
-        """Arguments known only once the phase is under way."""
-        self.args.update(args)
+        """Arguments known only once the phase is under way: this
+        phase's own, not carried to the next by :meth:`then`."""
+        self.args = {**self.args, **args}
         if self._ann is not None:
             self._ann.set_metadata(**_stats(args))
 
@@ -513,9 +521,10 @@ class phase:
                          args=self.args)
 
     def then(self, name):
-        """Close this phase and open the next one of the same round."""
+        """Close this phase and open the next one of the same round
+        (with what this one was opened with: its ``round``)."""
         self.close()
-        return phase(self.buf, name, self.cat, **self.args).open()
+        return phase(self.buf, name, self.cat, **self._opened).open()
 
     __enter__ = open
 

@@ -16,9 +16,12 @@ that reads them (``serving/attention.py``).
   Only ``total_tokens`` is padded (power-of-two schedule, or the
   ``token_pads`` ladder), so the one callable has a handful of
   shape-specializations, counted by ``serving_compiles_total`` /
-  ``serving_distinct_programs``. Params, block tables and pools are
+  ``serving_distinct_programs``. Params, the plan and pools are
   arguments, pools are donated on TPU; greedy argmax runs on device
-  (host-side temperature/top-k sampling per request when asked).
+  (host-side temperature/top-k sampling per request when asked). A
+  round crosses the host-device boundary once each way: the plan goes
+  up as ONE int32 message and is taken apart inside the program, the
+  tokens come back in one blocking fetch.
 * **chunked prefill** (ISSUE 9) — ``prefill_chunk=C`` splits prompts
   into C-token chunks advanced at most ``prefill_token_budget`` tokens
   per scheduler round beside the decode rows, so a long prompt arriving
@@ -47,6 +50,7 @@ ServingMetrics`.
 from __future__ import annotations
 
 import contextlib
+import math
 import os
 import sys
 import threading
@@ -84,6 +88,29 @@ def _swap_params(params, arrays):
     finally:
         for p, o in zip(params, olds):
             p._data = o
+
+
+def _plan_parts(message, rows, bt_shape):
+    """The round's plan inside its ONE int32 message, in this order:
+    ``tokens[T]``, ``row_starts[R]``, ``row_lens[R]``, ``kv_lens[R]`` and
+    the block tables (``bt_shape``); ``T`` is what is left of the length.
+    Basic slices either way: of the host's numpy buffer they are the views
+    the round writes its plan through, of the traced array the static
+    slices the program takes it apart with, so the two sides cannot
+    disagree on the layout."""
+    n_bt = math.prod(bt_shape)
+    cuts = [0, message.shape[0] - _message_len(0, rows, bt_shape)]
+    for n in (rows, rows, rows, n_bt):
+        cuts.append(cuts[-1] + n)
+    tokens, row_starts, row_lens, kv_lens, bt = (
+        message[a:b] for a, b in zip(cuts, cuts[1:]))
+    return tokens, row_starts, row_lens, kv_lens, bt.reshape(bt_shape)
+
+
+def _message_len(T, rows, bt_shape):
+    """Length of the message :func:`_plan_parts` lays out, at token pad
+    ``T``."""
+    return T + 3 * rows + math.prod(bt_shape)
 
 
 def _select_token(logits_row, req):
@@ -220,10 +247,15 @@ class ServingEngine:
         # total_tokens only. The pads it has served live in
         # _ragged_shapes; every installed program lands in _programs,
         # feeding serving_compiles_total / serving_distinct_programs
-        self._ragged_fn = self._build_ragged_step()
+        self._ragged_fn = self._build_round()
         self._ragged_shapes: set = set()
         self._programs: set = set()
+        # one host buffer a token pad holds the round's plan (_plan)
+        self._plans: dict = {}
         self._steps = 0
+        # host-to-device transfers and blocking fetches the rounds made
+        self._uploads = 0
+        self._fetches = 0
         self._decode_tokens = 0
         self._chunk_tokens = 0
         self.capture_logits = None   # tests: a list collects per-step
@@ -290,15 +322,14 @@ class ServingEngine:
     def _pools_out(caches):
         return [{n: t._data for n, t in c["pools"].items()} for c in caches]
 
-    def _build_ragged_step(self):
-        """ONE program for the whole scheduler round: embed the flat
-        token stream at per-token positions, scatter every row's K/V into
-        its pages, run ragged paged attention, and hand back one
-        next-token + logit row per batch row (the row's LAST valid
-        token's logits — a decode row's next token, a completing prefill
-        row's first token). Params are real arguments (no giant closure
-        constants), pools are donated on TPU; jax.jit specializes per
-        padded total_tokens ONLY."""
+    def _ragged_body(self):
+        """The whole scheduler round as one plain function of seven
+        arguments: embed the flat token stream at per-token positions,
+        scatter every row's K/V into its pages, run ragged paged
+        attention, and hand back one next-token + logit row per batch row
+        (the row's LAST valid token's logits — a decode row's next token,
+        a completing prefill row's first token). Params are real
+        arguments (no giant closure constants)."""
         model, params = self.model, self._params
         emit_logits = self.emit_logits
         from ..ops.pallas.ragged_attention import ragged_row_index
@@ -333,13 +364,67 @@ class ServingEngine:
                     extras["top"] = jnp.max(row_logits, axis=-1)
                 return nxt, row_logits, self._pools_out(caches), extras
 
+        return rstep
+
+    def _build_ragged_step(self):
+        """The round's body as a program of its own, with the seven
+        arguments ``(arrays, tokens, row_starts, row_lens, kv_lens, bt,
+        pools)``: what the compile tools and tests lower. The engine
+        calls :meth:`_build_round`'s entry, which holds the same body."""
+        return self._jitted(self._ragged_body(), donate=6)
+
+    def _jitted(self, fn, donate):
+        """``fn`` as this engine runs it: jitted unless the engine was
+        built with ``jit=False``, the pools (argument ``donate``) donated
+        where that works."""
         if not self._jit:
-            return rstep
+            return fn
         # donation saves the pool double-buffer on TPU; CPU/older
         # backends warn and ignore it, so only ask where it works
         if _ragged.on_tpu():
-            return jax.jit(rstep, donate_argnums=(6,))
-        return jax.jit(rstep)
+            return jax.jit(fn, donate_argnums=(donate,))
+        return jax.jit(fn)
+
+    def _build_round(self):
+        """The entry a round calls, ``(arrays, message, pools)``: a thin
+        wrapper around :meth:`_ragged_body` in ONE program, jitted once
+        and specialized per padded total_tokens ONLY. (Around the plain
+        body, not a jitted one: a jit inside a jit tripled the time a
+        pad's lowering takes at 24 layers.) The plan arrives as one int32
+        message (one upload) and is taken apart by static slices
+        (:func:`_plan_parts`); what the host reads every round leaves as
+        one int32 array (one fetch): the next tokens and, in an
+        ``emit_logits`` engine, their logits bit-cast beside them (the
+        host views them back as float32). -> ``(out, row_logits, pools,
+        aux)``."""
+        step = self._ragged_body()
+        R, bt_shape = self.max_slots, self._bt_shape()
+
+        def round_step(arrays, message, pools):
+            out, row_logits, pools, extras = step(
+                arrays, *_plan_parts(message, R, bt_shape), pools)
+            if "top" in extras:
+                out = jnp.concatenate([out, jax.lax.bitcast_convert_type(
+                    extras["top"].astype(jnp.float32), jnp.int32)])
+            return out, row_logits, pools, extras["aux"]
+
+        return self._jitted(round_step, donate=2)
+
+    def _plan(self, T):
+        """The host's buffer for the plan of a round at token pad ``T``,
+        reset to a round of no rows (every token padding, unused rows at
+        the sentinel ``T``): one a pad, kept, so a round allocates nothing.
+        -> ``(message, its five parts as views)``."""
+        plan = self._plans.get(T)
+        if plan is None:
+            R, bt_shape = self.max_slots, self._bt_shape()
+            message = np.empty(_message_len(T, R, bt_shape), np.int32)
+            plan = self._plans[T] = (
+                message, _plan_parts(message, R, bt_shape))
+        message, parts = plan
+        message[:] = 0
+        parts[1][:] = T
+        return plan
 
     def warm_ragged(self, max_tokens=None):
         """Pre-compile the ragged program at every token pad up to
@@ -375,7 +460,6 @@ class ServingEngine:
             if p >= max_tokens:
                 break
             t = p + 1
-        R = self.max_slots
         with self._step_lock:
             for p in pads:
                 if p in self._ragged_shapes:
@@ -383,10 +467,7 @@ class ServingEngine:
                 self._ragged_shapes.add(p)
                 self._note_program(("ragged", p))
                 _, _, self.kv.pools, _ = self._ragged_fn(
-                    self._param_arrays, jnp.zeros(p, jnp.int32),
-                    jnp.full(R, p, jnp.int32), jnp.zeros(R, jnp.int32),
-                    jnp.zeros(R, jnp.int32),
-                    jnp.zeros(self._bt_shape(), jnp.int32),
+                    self._param_arrays, jax.device_put(self._plan(p)[0]),
                     self.kv.pools)
         return pads
 
@@ -457,14 +538,9 @@ class ServingEngine:
                 ph.close()
                 rnd.close(record=False)
             return 0
-        R = self.max_slots
         total = sum(take for _, take, _ in plan)
         T = self._pad(total)
-        tokens = np.zeros(T, np.int32)
-        row_starts = np.full(R, T, np.int32)   # unused rows: sentinel T
-        row_lens = np.zeros(R, np.int32)
-        kv_lens = np.zeros(R, np.int32)
-        bt = np.zeros(self._bt_shape(), np.int32)
+        message, (tokens, row_starts, row_lens, kv_lens, bt) = self._plan(T)
         tables = bt[None] if bt.ndim == 2 else bt       # one a page group
         cursor = 0
         for i, (req, take, seg) in enumerate(plan):
@@ -493,34 +569,41 @@ class ServingEngine:
                 tr.add("cache.window_release", rnd.t0, 0.0, cat="serving",
                        args={"round": self._steps, "pages": freed})
             ph = ph.then("round.launch")
-        nxt, row_logits, self.kv.pools, extras = self._ragged_fn(
-            self._param_arrays, jnp.asarray(tokens),
-            jnp.asarray(row_starts), jnp.asarray(row_lens),
-            jnp.asarray(kv_lens), jnp.asarray(bt), self.kv.pools)
+        # the round crosses to the device once: the plan as ONE message
+        out, row_logits, self.kv.pools, aux = self._ragged_fn(
+            self._param_arrays, jax.device_put(message), self.kv.pools)
+        self._uploads += 1
+        # ... and back once: the tokens' copy is asked for now, behind the
+        # program, and the round blocks on it in round.fetch
+        out.copy_to_host_async()
         completing = [req for req, take, _ in plan[len(decode_rows):]
                       if req.num_cached + take
                       >= len(prompts[req.request_id])]
         any_sampling = any(r.temperature > 0.0
                            for r in decode_rows + completing)
+        # what a round reads only sometimes rides the same fetch: the
+        # logit rows when a request samples (or a test captures them),
+        # what the layers reported when tracing is on (never fetched off)
+        fetch = [out,
+                 row_logits if any_sampling
+                 or self.capture_logits is not None else None,
+                 aux if rnd is not None else None]
         if ph is not None:
+            ph.set(uploads=1)
             ph = ph.then("round.fetch")
-        # tpu-lint: ok[HS002] designed sync: ONE batched token fetch per ragged round feeds host-side scheduling/sampling
-        nxt = np.asarray(nxt)
-        top = extras.get("top")
-        if top is not None:
-            # tpu-lint: ok[HS002] designed sync: the emitted tokens' logits ride the round's token fetch (emit_logits engines only)
-            top = np.asarray(top)
-        # tpu-lint: ok[HS002] designed sync: the logit rows ride the same per-round host sampling fetch
-        logits_np = np.asarray(row_logits) \
-            if (any_sampling or self.capture_logits is not None) else None
+        # tpu-lint: ok[HS001] designed sync: ONE batched fetch per ragged round (tokens, their logits, and the logit rows / the layers' reports when asked) feeds host-side scheduling/sampling
+        out, logits_np, aux = jax.device_get(fetch)
+        self._fetches += 1
+        R = self.max_slots
+        nxt = out[:R]
+        top = out[R:].view(np.float32) if self.emit_logits else None
         if rnd is not None:
+            ph.set(fetches=1)
             # what the layers reported of this round (one event a name,
-            # one entry a reporting layer); untraced, it is never fetched
-            # tpu-lint: ok[HS001] traced runs only: a few integers a layer ride the round's fetch
-            for name, arr in jax.device_get(extras["aux"]).items():
+            # one entry a reporting layer)
+            for name, arr in aux.items():
                 tr.add(name, rnd.t0, 0.0, cat="serving",
                        args={"round": self._steps, "layers": arr.tolist()})
-        if ph is not None:
             ph = ph.then("round.emit")
         if self.capture_logits is not None and decode_rows:
             cap = np.zeros((self.max_slots,) + logits_np.shape[1:],
@@ -1020,14 +1103,11 @@ class ServingEngine:
                     "step() before compiled_text()")
             total_tokens = min(self._ragged_shapes)
         from ..jit.api import _aval
-        T, R = int(total_tokens), self.max_slots
-
-        def i32(*shape):
-            return jax.ShapeDtypeStruct(shape, jnp.int32)
-
         return self._ragged_fn.lower(
-            [_aval(a) for a in self._param_arrays], i32(T), i32(R), i32(R),
-            i32(R), i32(*self._bt_shape()),
+            [_aval(a) for a in self._param_arrays],
+            jax.ShapeDtypeStruct((_message_len(
+                int(total_tokens), self.max_slots, self._bt_shape()),),
+                jnp.int32),
             jax.tree_util.tree_map(_aval, self.kv.pools)
             ).compile().as_text()
 
@@ -1058,6 +1138,11 @@ class ServingEngine:
             "prefill_chunk_tokens": self._chunk_tokens,
             "distinct_programs": len(self._programs),
             "ragged_token_pads": sorted(self._ragged_shapes),
+            # what the rounds ("steps", those that launched) moved across
+            # the host-device boundary: one upload and one blocking fetch
+            # each, so either over the rounds reads 1.0
+            "round_uploads": self._uploads,
+            "round_fetches": self._fetches,
         }
         if self.prefix is not None:
             out.update({

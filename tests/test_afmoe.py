@@ -18,6 +18,7 @@ from paddle_tpu.models import (AfmoeForCausalLM, GPTForCausalLM,
                                mla_moe_tiny)
 from paddle_tpu.ops.pallas import windowed_ragged_attention as win
 from paddle_tpu.serving import ServingEngine
+from paddle_tpu.serving.engine import _plan_parts
 from paddle_tpu.serving.kv_cache import (LayerState, OutOfPages,
                                          PagedKVCache, pages_for)
 from paddle_tpu.serving.scheduler import GenerationRequest
@@ -428,9 +429,10 @@ def test_one_group_models_get_todays_pools_tables_and_program():
         tables = []
         fn = eng._ragged_fn
 
-        def spy(arrays, tokens, rs, rl, kl, bt, pools):
-            tables.append(np.asarray(bt))
-            return fn(arrays, tokens, rs, rl, kl, bt, pools)
+        def spy(arrays, message, pools):
+            tables.append(_plan_parts(np.asarray(message), eng.max_slots,
+                                      eng._bt_shape())[4])
+            return fn(arrays, message, pools)
 
         eng._ragged_fn = spy
         a = GenerationRequest(IDS[:10].tolist(), max_new_tokens=3)

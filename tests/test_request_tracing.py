@@ -327,7 +327,13 @@ def test_tracing_on_round_phases(tiny_model):
         inside = [c for c in events if c["name"].startswith("round.")
                   and c["args"]["round"] == e["args"]["round"]]
         assert [c["name"] for c in inside] == ROUND_PHASES
-        assert all(set(c["args"]) == {"round"} for c in inside)
+        # the launch says how many transfers went up, the fetch how many
+        # blocking fetches came back (one each); the other phases carry
+        # the round alone
+        own = {"round.launch": {"uploads": 1}, "round.fetch": {"fetches": 1}}
+        for c in inside:
+            assert c["args"] == dict(own.get(c["name"], {}),
+                                     round=e["args"]["round"])
         assert e["ts"] - eps <= inside[0]["ts"]
         for a, b in zip(inside, inside[1:]):
             assert a["ts"] + a["dur"] <= b["ts"] + eps

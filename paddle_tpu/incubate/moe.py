@@ -19,7 +19,8 @@ Two layers, for two regimes:
   mesh axis.
 * :class:`DroplessMoELayer` — hundreds of fine-grained experts, a sigmoid
   router with a selection bias, renormalised top-k weights, a shared
-  expert, no capacity and no dropped token (DeepSeek-V3 / Kimi-K2 style).
+  expert, no capacity and no dropped token (DeepSeek-V3 / Kimi-K2 style;
+  ``n_group`` / ``topk_group`` limit a token's experts to its best groups).
   Tokens bound for the experts this layer HOLDS (``experts_held``, a range
   of the whole set) are sorted by expert and run through one grouped
   matrix product (``ops/pallas/moe_grouped_matmul.py``); what absent
@@ -165,16 +166,26 @@ def _router_logits(u, weight):
 
 
 def sigmoid_topk_route(logits, bias, top_k, norm_topk_prob=True,
-                       scaling=1.0):
-    """DeepSeek-V3's ``noaux_tc`` router without a group limit
-    (``n_group == topk_group == 1``), on raw arrays. ``logits`` [T, E]
+                       scaling=1.0, n_group=1, topk_group=1):
+    """DeepSeek-V3's ``noaux_tc`` router, on raw arrays. ``logits`` [T, E]
     float32. The experts are the top-k of ``sigmoid(logits) + bias``; the
     bias steers the SELECTION only: the weights come from the unbiased
-    scores, renormalised over the chosen k and scaled.
+    scores, renormalised over the chosen k and scaled. With a group limit
+    (``n_group`` > 1) the experts lie in ``n_group`` groups of equal
+    size, a group scores the sum of its two largest ``sigmoid + bias``,
+    and only the experts of the ``topk_group`` best groups can be chosen.
     -> ``(expert ids [T, k] int32, weights [T, k] float32)``."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32)[None, :],
-                           top_k)
+    pick = scores + bias.astype(jnp.float32)[None, :]
+    if n_group > 1:
+        T, E = pick.shape
+        best2, _ = jax.lax.top_k(pick.reshape(T, n_group, E // n_group), 2)
+        _, kept = jax.lax.top_k(best2.sum(-1), topk_group)
+        keep = (kept[:, :, None] == jnp.arange(n_group)[None, None, :]) \
+            .any(1)                                           # [T, groups]
+        pick = jnp.where(jnp.repeat(keep, E // n_group, axis=1), pick,
+                         -jnp.inf)
+    _, idx = jax.lax.top_k(pick, top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk_prob:
         w = w / (w.sum(-1, keepdims=True) + 1e-20)
@@ -252,9 +263,13 @@ class DroplessMoELayer(nn.Layer):
     def __init__(self, d_model, d_hidden, num_experts, top_k,
                  experts_held=None, n_shared_experts=1,
                  routed_scaling_factor=1.0, norm_topk_prob=True,
-                 backend=None, dtype=None):
+                 backend=None, dtype=None, n_group=1, topk_group=1):
         super().__init__()
         from ..nn import initializer as I
+        if num_experts % n_group or not 1 <= topk_group <= n_group:
+            raise ValueError(f"{num_experts} experts in {n_group} groups, "
+                             f"{topk_group} kept: no group limit")
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
         lo, hi = experts_held if experts_held is not None \
             else (0, num_experts)
         if not 0 <= lo < hi <= num_experts:
@@ -301,7 +316,8 @@ class DroplessMoELayer(nn.Layer):
         """Raw arrays: ``u`` [T, d] -> (ids [T, k], weights [T, k])."""
         return sigmoid_topk_route(
             _router_logits(u, self.gate_weight._data), self.gate_bias._data,
-            self.top_k, self.norm_topk_prob, self.scaling)
+            self.top_k, self.norm_topk_prob, self.scaling, self.n_group,
+            self.topk_group)
 
     def forward(self, x, return_load=False, token_mask=None):
         """``token_mask`` (bool, one a token): tokens that are padding;
@@ -319,7 +335,7 @@ class DroplessMoELayer(nn.Layer):
             u = xa.reshape(n_tokens, d)
             idx, wts = sigmoid_topk_route(
                 _router_logits(u, wg), bg, self.top_k, self.norm_topk_prob,
-                self.scaling)
+                self.scaling, self.n_group, self.topk_group)
             if masked:
                 idx = jnp.where(rest[0].reshape(n_tokens, 1), idx, -1)
             plan = dispatch_plan(idx, self.lo, self.n_held, tile_m)

@@ -21,3 +21,6 @@ from .mla_moe import (  # noqa: F401
 from .afmoe import (  # noqa: F401
     AfmoeConfig, AfmoeForCausalLM, afmoe_tiny,
 )
+from .kda_mla_moe import (  # noqa: F401
+    KDAMLAMoEConfig, KDAMLAMoEForCausalLM, kda_mla_moe_tiny,
+)

@@ -6,7 +6,9 @@ preset of one model. The block, with ``RMS`` an RMSNorm:
 
     h = x + MLA(RMS(x));   y = h + FFN(RMS(h))
 
-* **MLA** — queries through a low-rank bottleneck (``q_lora_rank``), keys
+* **MLA** — queries through a low-rank bottleneck (``q_lora_rank``; or
+  straight from the hidden state where it is ``None``), optionally each
+  head's output times a sigmoid gate (``attn_head_gate``), keys
   and values through ONE shared latent (``kv_lora_rank``) plus a decoupled
   rotary key common to all heads. What a token leaves behind is the row
   ``[c | k_rope]`` (``kv_lora_rank + qk_rope_head_dim`` values), after the
@@ -52,12 +54,13 @@ class MLAMoEConfig:
                  routed_scaling_factor=1.0, norm_topk_prob=True,
                  experts_held=None, rms_norm_eps=1e-5, rope_theta=10000.0,
                  rope_scaling=None, max_seq_len=2048, dtype="float32",
-                 cache_row_align=1, moe_backend=None):
+                 cache_row_align=1, moe_backend=None, attn_head_gate=False):
         self.vocab_size = int(vocab_size)
         self.hidden_size = int(hidden_size)
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
-        self.q_lora_rank = int(q_lora_rank)
+        # None: queries straight from the hidden state, no bottleneck
+        self.q_lora_rank = int(q_lora_rank) if q_lora_rank else None
         self.kv_lora_rank = int(kv_lora_rank)
         self.qk_nope_head_dim = int(qk_nope_head_dim)
         self.qk_rope_head_dim = int(qk_rope_head_dim)
@@ -83,6 +86,8 @@ class MLAMoEConfig:
         # on a TPU, where the page DMA wants whole lane tiles)
         self.cache_row_align = int(cache_row_align)
         self.moe_backend = moe_backend
+        # each head's output times sigmoid(x W_gate), one gate a head
+        self.attn_head_gate = bool(attn_head_gate)
 
     @property
     def latent_width(self):
@@ -168,15 +173,22 @@ class MLAttention(nn.Layer):
             return self.create_parameter(
                 [n], dtype="float32", default_initializer=I.Constant(1.0))
 
-        self.q_a_proj = param([d, cfg.q_lora_rank], d)
-        self.q_a_norm = ones(cfg.q_lora_rank)
-        self.q_b_proj = param([cfg.q_lora_rank, H * (dn + dr)],
-                              cfg.q_lora_rank)
+        if cfg.q_lora_rank:
+            self.q_a_proj = param([d, cfg.q_lora_rank], d)
+            self.q_a_norm = ones(cfg.q_lora_rank)
+            self.q_b_proj = param([cfg.q_lora_rank, H * (dn + dr)],
+                                  cfg.q_lora_rank)
+            self._q_weights = [self.q_a_proj, self.q_a_norm, self.q_b_proj]
+        else:
+            self.q_proj = param([d, H * (dn + dr)], d)
+            self._q_weights = [self.q_proj]
         self.kv_a_proj = param([d, cfg.latent_width], d)
         self.kv_a_norm = ones(cfg.kv_lora_rank)
         # per head [k_nope | v] from the latent
         self.kv_b_proj = param([cfg.kv_lora_rank, H * (dn + dv)],
                                cfg.kv_lora_rank)
+        self.gate_proj = param([d, H], d) \
+            if getattr(cfg, "attn_head_gate", False) else None
         self.o_proj = param([H * dv, d], H * dv)
         sc = cfg.rope_scaling
         self._inv_freq = yarn_inv_freq(dr, cfg.rope_theta, sc)
@@ -194,10 +206,14 @@ class MLAttention(nn.Layer):
         H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
         eps, inv, ms = cfg.rms_norm_eps, self._inv_freq, self._rope_mscale
 
-        def fwd(xa, pa, wqa, nqa, wqb, wkva, nkva):
+        def fwd(xa, pa, *w):
+            *wq, wkva, nkva = w
             B, S, _ = xa.shape
-            cq = _rms(jnp.matmul(xa, wqa), nqa, eps)
-            q = jnp.matmul(cq, wqb).reshape(B, S, H, dn + dr)
+            if len(wq) == 3:        # through the bottleneck: a, norm, b
+                cq = _rms(jnp.matmul(xa, wq[0]), wq[1], eps)
+                q = jnp.matmul(cq, wq[2]).reshape(B, S, H, dn + dr)
+            else:
+                q = jnp.matmul(xa, wq[0]).reshape(B, S, H, dn + dr)
             kv = jnp.matmul(xa, wkva)
             c = _rms(kv[..., :cfg.kv_lora_rank], nkva, eps)
             k_rope = _rope(kv[..., cfg.kv_lora_rank:], pa, inv, ms)
@@ -205,8 +221,8 @@ class MLAttention(nn.Layer):
                     jnp.concatenate([c, k_rope], axis=-1))
 
         return apply("mla_project", fwd,
-                     [x, pos, self.q_a_proj, self.q_a_norm, self.q_b_proj,
-                      self.kv_a_proj, self.kv_a_norm], nout=3)
+                     [x, pos, *self._q_weights, self.kv_a_proj,
+                      self.kv_a_norm], nout=3)
 
     def _expanded(self, q_nope, q_rope, latent):
         """Plain attention with keys and values expanded from the latent
@@ -276,6 +292,15 @@ class MLAttention(nn.Layer):
                     latent = ops.concat([cache["latent"], latent], axis=1)
                 cache["latent"] = latent
             out = self._expanded(q_nope, q_rope, latent)
+        if self.gate_proj is not None:
+            H = self.cfg.num_heads
+
+            def gated(o, xa, wg):
+                g = jax.nn.sigmoid(jnp.matmul(xa, wg).astype(jnp.float32))
+                o = o.reshape(o.shape[:-1] + (H, -1)) * g[..., None]
+                return o.reshape(xa.shape[:-1] + (-1,)).astype(xa.dtype)
+
+            out = apply("mla_head_gate", gated, [out, x, self.gate_proj])
         return out.matmul(self.o_proj)
 
 

@@ -46,7 +46,11 @@ and ``_serve_loop`` open them; ``cat`` is ``serving``):
     for keys and values by head, ``latent_rows`` for a latent cache,
     ``window_rows`` for keys and values behind a window (the benchmark's
     ``mla_roofline_pct.serve`` and ``windowed_roofline_pct.serve`` check
-    their counts against them).
+    their counts against them). Where layers keep a state a request and
+    no rows a token, ``state_rows``: the launched rows times those layers,
+    the states the round has to read and write (``kda_roofline_pct.serve``
+    checks it, ``state_read_pct.serve`` weighs it against
+    ``latent_rows``).
 ``round.schedule``
     ``scheduler.schedule()``, ``ensure_decode_capacity()``, admission and
     eviction bookkeeping.

@@ -16,6 +16,10 @@ pools as a dict ``name -> [P, page, *row]``.
   row, grouped-query, looking back ``spec.window`` tokens or all the way:
   ``ops/pallas/windowed_ragged_attention.py``.
 
+* ``kda_state`` — no rows a token but one delta-rule state a REQUEST,
+  held by slot and read and written once a launched row:
+  ``ops/pallas/kda_ragged.py``.
+
 Each class also says what a layer of its kind has to read in a round
 (``rows_read``), under the name the ``decode_round`` span carries it.
 """
@@ -30,7 +34,7 @@ from .ragged_attention import (ab_compare_ragged, pad_total_tokens,
                                sharded_ragged_attention)
 
 __all__ = ["for_kind", "KVAttention", "LatentAttention",
-           "WindowedAttention"]
+           "WindowedAttention", "StateAttention"]
 
 
 class KVAttention:
@@ -182,8 +186,57 @@ class WindowedAttention:
             q, p["k"], p["v"], rs, rl, kl, bt, window=window, **kw)
 
 
+class StateAttention:
+    """A delta-rule state a request (pool ``state``
+    ``[slots + 1, H, Dk, Dv]`` float32, held by slot) beside the tail of
+    its short convolution (pool ``conv``, which the layer itself reads and
+    writes). The function it hands the layer is the recurrence alone:
+    ``(q, k, v, alpha, beta, state, row_slots, row_starts, row_lens,
+    kv_lens) -> (o, state)``."""
+
+    kind = "kda_state"
+
+    def __init__(self, spec):
+        self.spec = spec
+
+    def rows_read(self, row_lens, kv_lens):
+        """One state a launched row, whatever its context."""
+        return "state_rows", int(np.count_nonzero(row_lens))
+
+    def check_mesh(self, degree, axis):
+        raise ValueError("the state kernel takes whole head blocks of a "
+                         f"slot: no split over mesh axis {axis}")
+
+    def gate_ragged(self, pools, rows, tokens, page_size, max_pages,
+                    max_seq_len):
+        """Decode rows only (one token a slot), as the paged kinds gate:
+        the twin runs the stream a token at a time."""
+        from ..ops.pallas import kda_ragged as _kda
+        del tokens, page_size, max_pages, max_seq_len
+        T = pad_total_tokens(rows)
+        H, D = self.spec.query
+        key = jax.random.split(jax.random.PRNGKey(0), 5)
+        q, k, v = (jax.random.normal(key[i], (T, H, D), "float32") * D ** -0.5
+                   for i in range(3))
+        alpha = jax.nn.sigmoid(jax.random.normal(key[3], (T, H, D)))
+        beta = jax.nn.sigmoid(jax.random.normal(key[4], (T, H)))
+        args = (q, k, v, alpha, beta, pools["state"],
+                np.arange(1, rows + 1, dtype=np.int32),
+                np.arange(rows, dtype=np.int32), np.ones(rows, np.int32),
+                np.full((rows,), 2, np.int32))
+        return _gate.ab_gate(
+            "kda_ragged", _kda.kda_ragged_reference, _kda.kda_ragged,
+            tuple(jax.numpy.asarray(a) for a in args),
+            repeats=20, sig=_gate.shape_sig(q))
+
+    def impls(self, backend, mesh=None, mesh_axis="model"):
+        from ..ops.pallas import kda_ragged as _kda
+        return _kda.kda_ragged if backend == "pallas" \
+            else _kda.kda_ragged_reference
+
+
 _KINDS = {c.kind: c for c in (KVAttention, LatentAttention,
-                              WindowedAttention)}
+                              WindowedAttention, StateAttention)}
 
 
 def for_kind(spec):

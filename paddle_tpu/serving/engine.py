@@ -90,27 +90,27 @@ def _swap_params(params, arrays):
             p._data = o
 
 
-def _plan_parts(message, rows, bt_shape):
+def _plan_parts(message, rows, bt_shape, slots=False):
     """The round's plan inside its ONE int32 message, in this order:
-    ``tokens[T]``, ``row_starts[R]``, ``row_lens[R]``, ``kv_lens[R]`` and
-    the block tables (``bt_shape``); ``T`` is what is left of the length.
-    Basic slices either way: of the host's numpy buffer they are the views
-    the round writes its plan through, of the traced array the static
-    slices the program takes it apart with, so the two sides cannot
-    disagree on the layout."""
+    ``tokens[T]``, ``row_starts[R]``, ``row_lens[R]``, ``kv_lens[R]``,
+    where the model keeps a state a request (``slots``) ``row_slots[R]``,
+    and the block tables (``bt_shape``); ``T`` is what is left of the
+    length. Basic slices either way: of the host's numpy buffer they are
+    the views the round writes its plan through, of the traced array the
+    static slices the program takes it apart with, so the two sides cannot
+    disagree on the layout. -> the parts in that order, the tables last."""
     n_bt = math.prod(bt_shape)
-    cuts = [0, message.shape[0] - _message_len(0, rows, bt_shape)]
-    for n in (rows, rows, rows, n_bt):
+    cuts = [0, message.shape[0] - _message_len(0, rows, bt_shape, slots)]
+    for n in (rows,) * (3 + bool(slots)) + (n_bt,):
         cuts.append(cuts[-1] + n)
-    tokens, row_starts, row_lens, kv_lens, bt = (
-        message[a:b] for a, b in zip(cuts, cuts[1:]))
-    return tokens, row_starts, row_lens, kv_lens, bt.reshape(bt_shape)
+    *parts, bt = (message[a:b] for a, b in zip(cuts, cuts[1:]))
+    return (*parts, bt.reshape(bt_shape))
 
 
-def _message_len(T, rows, bt_shape):
+def _message_len(T, rows, bt_shape, slots=False):
     """Length of the message :func:`_plan_parts` lays out, at token pad
     ``T``."""
-    return T + 3 * rows + math.prod(bt_shape)
+    return T + (3 + bool(slots)) * rows + math.prod(bt_shape)
 
 
 def _select_token(logits_row, req):
@@ -170,10 +170,16 @@ class ServingEngine:
         # its own pages and its own block table a request; ``num_pages``
         # is one count for every group or a dict by group name
         specs = list(model.cache_spec())
-        self.kv = PagedKVCache(specs, num_pages, self.page_size)
+        # A layer may keep one state a request instead (held by the
+        # request's slot, no pages): the round's message then says which
+        # slot each row is
+        self.kv = PagedKVCache(specs, num_pages, self.page_size,
+                               max_slots=self.max_slots)
         self._attentions = [_attention.for_kind(specs[g.layers[0]])
                             for g in self.kv.groups]
         self._attention = self._attentions[0]
+        self._state = _attention.for_kind(specs[self.kv.state_layers[0]]) \
+            if self.kv.state_layers else None
         self.num_layers = len(specs)
         # models with keys and values by head say how many KV heads
         self.num_kv_heads = specs[0].rows["k"][0] \
@@ -197,7 +203,7 @@ class ServingEngine:
         self.metrics = ServingMetrics(registry=registry,
                                       prefix_enabled=self.prefix
                                       is not None, engine=engine_id)
-        self.metrics.on_cache_spec(specs[0].kind,
+        self.metrics.on_cache_spec(self._attention.kind,
                                    sum(self.kv.bytes_per_token()))
         # chunked prefill: split prompts into prefill_chunk-token chunks
         # and interleave at most prefill_token_budget chunk-tokens per
@@ -226,12 +232,19 @@ class ServingEngine:
         self.emit_logits = bool(emit_logits)
         # ---- attention backend (A/B gated; standing kernel rule)
         requested = _ragged.resolve_backend(attn_backend)
-        self.attn_ab = None
+        self.attn_ab = self.state_ab = None
         if requested == "auto":
-            self.attn_ab = self._run_ab_gate_ragged()
+            self.attn_ab = self._run_ab_gate_ragged(self._attention)
             self.attn_backend = self.attn_ab["backend"]
         else:
             self.attn_backend = requested
+        # the state layers' recurrence is gated on its own
+        self.state_backend = None
+        if self._state is not None:
+            if requested == "auto":
+                self.state_ab = self._run_ab_gate_ragged(self._state)
+            self.state_backend = self.state_ab["backend"] \
+                if self.state_ab else requested
         if mesh is not None and int(mesh.shape.get(mesh_axis, 1)) > 1:
             self._attention.check_mesh(int(mesh.shape[mesh_axis]),
                                        mesh_axis)
@@ -240,6 +253,8 @@ class ServingEngine:
         self._attn_impls = [a.impls(self.attn_backend, mesh=mesh,
                                     mesh_axis=mesh_axis)
                             for a in self._attentions]
+        self._state_impl = self._state.impls(self.state_backend) \
+            if self._state is not None else None
         self._params = list(model.parameters())
         self._param_arrays = [p._data for p in self._params]
         self._jit = bool(jit)
@@ -276,12 +291,15 @@ class ServingEngine:
         self._step_lock = threading.RLock()
 
     # ------------------------------------------------------------ A/B gate
-    def _run_ab_gate_ragged(self):
+    def _run_ab_gate_ragged(self, attention):
         """Measure XLA vs Pallas at this engine's ragged launch shape
-        (a full round: every slot a decode row, padded to the schedule);
-        'auto' resolves to the winner (Pallas never wins off-TPU)."""
-        return self._attention.gate_ragged(
-            self.kv.pools[0], self.max_slots,
+        (a full round: every slot a decode row, padded to the schedule)
+        on the pools of the first layer ``attention`` reads; 'auto'
+        resolves to the winner (Pallas never wins off-TPU)."""
+        layer = next(l for l, spec in enumerate(self.kv.specs)
+                     if spec.kind == attention.kind)
+        return attention.gate_ragged(
+            self.kv.pools[layer], self.max_slots,
             self._pad(self.max_slots + self._prefill_budget),
             self.page_size, self.max_pages, self.cfg.max_seq_len)
 
@@ -306,15 +324,20 @@ class ServingEngine:
         self.metrics.on_compile(len(self._programs))
 
     # -------------------------------------------------------- ragged round
-    def _layer_caches(self, pools, bt, **shared):
+    def _layer_caches(self, pools, bt, row_slots=None, **shared):
         """One cache dict a layer: its own pools (as Tensors, by the row
         names it declared), its page group's block table (``bt`` is the
         one table of a one-group model, else one a group, stacked) and
-        attention, beside what the whole round shares."""
+        attention, or for a layer that keeps a state a request the rows'
+        slots and the recurrence, beside what the whole round shares."""
         tables = [Tensor(bt)] if len(self.kv.groups) == 1 \
             else [Tensor(bt[g]) for g in range(len(self.kv.groups))]
-        return [dict(shared, block_tables=tables[g],
-                     attn_impl=self._attn_impls[g],
+        by_group = [dict(shared, block_tables=t, attn_impl=impl)
+                    for t, impl in zip(tables, self._attn_impls)]
+        # group None: a layer that keeps a state a request
+        by_group.append(None if row_slots is None else dict(
+            shared, row_slots=Tensor(row_slots), attn_impl=self._state_impl))
+        return [dict(by_group[-1 if g is None else g],
                      pools={n: Tensor(a) for n, a in p.items()})
                 for p, g in zip(pools, self.kv.group_of)]
 
@@ -324,7 +347,8 @@ class ServingEngine:
 
     def _ragged_body(self):
         """The whole scheduler round as one plain function of seven
-        arguments: embed the flat token stream at per-token positions,
+        arguments (and ``row_slots`` for a model with a state a request):
+        embed the flat token stream at per-token positions,
         scatter every row's K/V into its pages, run ragged paged
         attention, and hand back one next-token + logit row per batch row
         (the row's LAST valid token's logits — a decode row's next token,
@@ -335,14 +359,17 @@ class ServingEngine:
         from ..ops.pallas.ragged_attention import ragged_row_index
 
         def rstep(arrays, tokens, row_starts, row_lens, kv_lens, bt,
-                  pools):
+                  pools, row_slots=None):
+            if row_slots is None and self._state is not None:
+                raise ValueError("the model keeps a state a request: the "
+                                 "round needs row_slots, each row's slot")
             with no_grad(), _swap_params(params, arrays):
                 T = tokens.shape[0]
                 _, pos, valid = ragged_row_index(row_starts, row_lens,
                                                  kv_lens, T)
                 positions = jnp.where(valid, pos, 0).astype(jnp.int32)
                 caches = self._layer_caches(
-                    pools, bt, ragged=True,
+                    pools, bt, row_slots, ragged=True,
                     row_starts=Tensor(row_starts),
                     row_lens=Tensor(row_lens), kv_lens=Tensor(kv_lens))
                 logits = model(Tensor(tokens[None, :]), caches=caches,
@@ -399,10 +426,12 @@ class ServingEngine:
         aux)``."""
         step = self._ragged_body()
         R, bt_shape = self.max_slots, self._bt_shape()
+        slots = self._state is not None
 
         def round_step(arrays, message, pools):
+            *plan, bt = _plan_parts(message, R, bt_shape, slots)
             out, row_logits, pools, extras = step(
-                arrays, *_plan_parts(message, R, bt_shape), pools)
+                arrays, *plan[:4], bt, pools, *plan[4:])
             if "top" in extras:
                 out = jnp.concatenate([out, jax.lax.bitcast_convert_type(
                     extras["top"].astype(jnp.float32), jnp.int32)])
@@ -414,13 +443,16 @@ class ServingEngine:
         """The host's buffer for the plan of a round at token pad ``T``,
         reset to a round of no rows (every token padding, unused rows at
         the sentinel ``T``): one a pad, kept, so a round allocates nothing.
-        -> ``(message, its five parts as views)``."""
+        -> ``(message, its parts as views)``: a row's slot, where the
+        message carries it, starts at the scrap slot 0."""
         plan = self._plans.get(T)
         if plan is None:
             R, bt_shape = self.max_slots, self._bt_shape()
-            message = np.empty(_message_len(T, R, bt_shape), np.int32)
+            slots = self._state is not None
+            message = np.empty(_message_len(T, R, bt_shape, slots),
+                               np.int32)
             plan = self._plans[T] = (
-                message, _plan_parts(message, R, bt_shape))
+                message, _plan_parts(message, R, bt_shape, slots))
         message, parts = plan
         message[:] = 0
         parts[1][:] = T
@@ -540,7 +572,9 @@ class ServingEngine:
             return 0
         total = sum(take for _, take, _ in plan)
         T = self._pad(total)
-        message, (tokens, row_starts, row_lens, kv_lens, bt) = self._plan(T)
+        message, parts = self._plan(T)
+        tokens, row_starts, row_lens, kv_lens = parts[:4]
+        bt = parts[-1]
         tables = bt[None] if bt.ndim == 2 else bt       # one a page group
         cursor = 0
         for i, (req, take, seg) in enumerate(plan):
@@ -551,6 +585,11 @@ class ServingEngine:
             for table, pages in zip(tables, req.group_pages):
                 table[i, :len(pages)] = pages
             cursor += take
+        if self._state is not None:
+            # the state pools' index of each row's request: its slot, past
+            # the scrap slot. A row at the start of its context
+            # (kv_len == row_len) starts from zero whatever the slot held
+            parts[4][:len(plan)] = [req.slot + 1 for req, _, _ in plan]
         if T not in self._ragged_shapes:
             self._ragged_shapes.add(T)
             self._note_program(("ragged", T))
@@ -563,6 +602,12 @@ class ServingEngine:
             for a in self._attentions:
                 name, rows = a.rows_read(row_lens[:n], kv_lens[:n])
                 rows_read[name] = rows_read.get(name, 0) + rows
+            if self._state is not None:
+                # ... and the states it reads and writes: one a row and
+                # state layer
+                name, rows = self._state.rows_read(row_lens[:n],
+                                                   kv_lens[:n])
+                rows_read[name] = rows * len(self.kv.state_layers)
             rnd.set(pad=T, tokens=total, row_lens=row_lens[:n].tolist(),
                     kv_lens=kv_lens[:n].tolist(), **rows_read)
             if freed:
@@ -1106,8 +1151,8 @@ class ServingEngine:
         return self._ragged_fn.lower(
             [_aval(a) for a in self._param_arrays],
             jax.ShapeDtypeStruct((_message_len(
-                int(total_tokens), self.max_slots, self._bt_shape()),),
-                jnp.int32),
+                int(total_tokens), self.max_slots, self._bt_shape(),
+                self._state is not None),), jnp.int32),
             jax.tree_util.tree_map(_aval, self.kv.pools)
             ).compile().as_text()
 
@@ -1134,6 +1179,15 @@ class ServingEngine:
             # (beside what the same requests' tables spanned then) and
             # those a windowed group gave back as they slid out
             "page_groups": {g.name: g.stats() for g in self.kv.groups},
+            # what the layers that keep a state a request hold: a slot is
+            # one request's states over those layers, whatever its length
+            "state": None if self._state is None else {
+                "layers": len(self.kv.state_layers),
+                "slots": self.kv.state_slots,
+                "bytes_per_slot": self.kv.state_bytes_per_slot(),
+                "bytes": (self.kv.state_slots + 1)
+                * self.kv.state_bytes_per_slot(),
+                "backend": self.state_backend, "ab": self.state_ab},
             "prefill_chunk": self.prefill_chunk,
             "prefill_chunk_tokens": self._chunk_tokens,
             "distinct_programs": len(self._programs),

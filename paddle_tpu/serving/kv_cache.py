@@ -17,6 +17,14 @@ back (:class:`PageGroup`): each group has its own allocator, page count
 and block table a request, so that layers behind a sliding window give
 their pages back while full layers keep every token.
 
+A layer may instead keep ONE state of a fixed size a request
+(``LayerState.per_request``: the recurrent state of a linear-attention
+layer): its pools are ``[max_slots + 1, ...]``, held by the request's
+decode SLOT, with no block table, no growth and no window; such layers
+belong to no page group and take no pages. Pool index 0 is the **scrap
+slot** (a request in slot ``s`` owns index ``s + 1``): padded rows of a
+round read and write it.
+
 Physical page 0 of every group is reserved as the **scrap page**: padded
 block-table entries, the entries of pages a windowed group gave back and a
 round's pad tokens point at it, so masked lanes of the
@@ -46,13 +54,15 @@ class LayerState:
     entry of ``rows`` and hands each layer its own pools back.
 
     kind   which attention reads the rows: ``"kv"`` (keys and values by
-           head), ``"mla_latent"`` (one shared latent row a token) or
+           head), ``"mla_latent"`` (one shared latent row a token),
            ``"kv_windowed"`` (keys and values with the heads side by side
-           in one row, read by ``windowed_ragged_attention``)
+           in one row, read by ``windowed_ragged_attention``) or
+           ``"kda_state"`` (a delta-rule state a request, ``kda_ragged``)
     rows   name -> shape of a token's row, e.g. ``{"k": (KVH, Dh), "v":
            (KVH, Dh)}``, ``{"latent": (576,)}`` or ``{"k": (KVH * Dh,),
-           "v": (KVH * Dh,)}``
-    dtype  the pools' dtype
+           "v": (KVH * Dh,)}``; of a ``per_request`` layer, of what it
+           keeps a request: ``{"state": (H, Dk, Dv), "conv": (3, C)}``
+    dtype  the pools' dtype (``row_dtypes`` names the rows that differ)
     query  shape of a token's query as the attention takes it (the
            start-up gate times the backends at this shape)
     row_align  the pools' minor dimension is rounded up to a multiple of
@@ -64,6 +74,13 @@ class LayerState:
            = every earlier token. Layers of one ``(kind, window)`` form a
            **page group** (:class:`PageGroup`): a group with a window
            gives a page back once every token in it has slid out.
+    per_request  the rows are kept once a REQUEST, whatever its length
+           (a recurrent state): pools ``[max_slots + 1, *row]`` held by
+           slot, in no page group. Such a state cannot be re-read by
+           position, so the prefix cache, page sharing and migration
+           refuse a model that has one.
+    row_dtypes  name -> dtype of the rows whose pool is not ``dtype``'s
+           (a float32 state beside a bfloat16 tail)
     """
     kind: str
     rows: dict
@@ -71,13 +88,21 @@ class LayerState:
     query: tuple
     row_align: int = 1
     window: int = None
+    per_request: bool = False
+    row_dtypes: dict = None
 
     @property
     def group(self):
         """The name of the layer's page group: its kind, and its window
-        where it has one (``kv_windowed``, ``kv_windowed.w4096``)."""
+        where it has one (``kv_windowed``, ``kv_windowed.w4096``); None
+        for a layer that keeps a state a request and no pages."""
+        if self.per_request:
+            return None
         return self.kind if self.window is None \
             else f"{self.kind}.w{int(self.window)}"
+
+    def row_dtype(self, name):
+        return (self.row_dtypes or {}).get(name, self.dtype)
 
     def pool_row(self, name):
         """The row's shape as the pool holds it (minor dimension rounded
@@ -88,10 +113,20 @@ class LayerState:
 
     def bytes_per_token(self, padded=False):
         """Bytes of one token's rows: as declared, or as the pools hold
-        them."""
+        them. A state a request grows by nothing a token."""
+        if self.per_request:
+            return 0
         width = sum(int(np.prod(self.pool_row(n) if padded else shape))
                     for n, shape in self.rows.items())
         return width * jnp.dtype(self.dtype).itemsize
+
+    def bytes_per_request(self):
+        """Bytes of what a ``per_request`` layer keeps for one request."""
+        if not self.per_request:
+            return 0
+        return sum(int(np.prod(self.pool_row(n)))
+                   * jnp.dtype(self.row_dtype(n)).itemsize
+                   for n in self.rows)
 
 
 def kv_state(num_heads, num_kv_heads, head_dim, dtype):
@@ -322,15 +357,29 @@ class PagedKVCache:
     first appearance): ``num_pages`` is one count for every group, or a
     dict by group name. A model whose layers all keep the same has one
     group, ``allocator`` and ``num_pages`` are that group's.
+
+    A ``per_request`` layer (``state_layers``) is in no group: its pools
+    are ``[max_slots + 1, *row]``, index 0 the scrap slot.
     """
 
-    def __init__(self, specs, num_pages, page_size, reserved=1):
+    def __init__(self, specs, num_pages, page_size, reserved=1,
+                 max_slots=None):
         self.specs = list(specs)
         self.num_layers = len(self.specs)
         self.page_size = int(page_size)
+        self.state_layers = [l for l, spec in enumerate(self.specs)
+                             if spec.per_request]
+        self.state_slots = int(max_slots or 0)
+        if self.state_layers and not max_slots:
+            raise ValueError("layers that keep a state a request need "
+                             "max_slots: their pools are held by slot")
         by_name = {}
         for l, spec in enumerate(self.specs):
-            by_name.setdefault(spec.group, []).append(l)
+            if not spec.per_request:
+                by_name.setdefault(spec.group, []).append(l)
+        if not by_name:
+            raise ValueError("no layer of the model keeps rows a token: "
+                             "the scheduler admits and evicts by pages")
         if isinstance(num_pages, dict):
             if set(num_pages) != set(by_name):
                 raise ValueError(
@@ -343,14 +392,17 @@ class PagedKVCache:
             PageGroup(BlockAllocator(int(counts[name]), reserved=reserved),
                       name, self.specs[layers[0]].window, layers)
             for name, layers in by_name.items()]
-        self.group_of = [0] * self.num_layers
+        # a layer's page group; None for a layer that keeps a state
+        self.group_of = [None if spec.per_request else 0
+                         for spec in self.specs]
         for g, group in enumerate(self.groups):
             for l in group.layers:
                 self.group_of[l] = g
         self.pools = [
             {name: jnp.zeros(
-                (self.groups[self.group_of[l]].num_pages, self.page_size)
-                + spec.pool_row(name), spec.dtype)
+                ((self.state_slots + 1,) if spec.per_request else
+                 (self.groups[self.group_of[l]].num_pages, self.page_size))
+                + spec.pool_row(name), spec.row_dtype(name))
              for name in spec.rows}
             for l, spec in enumerate(self.specs)]
 
@@ -368,6 +420,13 @@ class PagedKVCache:
         """``what`` (the prefix cache, page sharing, migration) identifies
         a request's cached state with ONE list of pages that all hold
         their tokens for good; refuse a model that keeps it otherwise."""
+        if self.state_layers:
+            raise ValueError(
+                f"{what} cannot serve a model whose layers "
+                f"{self.state_layers} keep a recurrent state a request: a "
+                "state cannot be re-read by position, so it cannot be a "
+                "hit, be shared or be moved (snapshots of a state: "
+                "ROADMAP B5)")
         windowed = [g.name for g in self.groups if g.window is not None]
         if windowed:
             raise ValueError(
@@ -382,6 +441,11 @@ class PagedKVCache:
     @property
     def dtype(self):
         return self.specs[0].dtype
+
+    def state_bytes_per_slot(self):
+        """Bytes the ``per_request`` layers keep for one request."""
+        return sum(self.specs[l].bytes_per_request()
+                   for l in self.state_layers)
 
     @property
     def k(self):

@@ -625,3 +625,75 @@ def test_afmoe_round_program_at_published_widths(one_chip, monkeypatch):
                       ).compile().as_text()
     assert len(_named(text, "windowed_ragged_attention")) == 3
     assert len(_named(text, "moe_grouped_matmul")) == 4
+
+
+# ------------------------------------------------------------------------
+# The delta-rule / latent attention decoder at Ling-3.0-flash's published
+# widths: hidden 2560, 32 heads of 128 (a 128 x 128 float32 state a head
+# and request), latent 512 + 64, 768-wide experts, 32 of 512 held in 8
+# groups; 128 slots, pages of 256 tokens.
+@pytest.mark.parametrize("tokens", [128, 640, 1152])
+def test_kda_ragged_at_published_widths(one_chip, tokens):
+    """A decode round (one item a token) and the mixed rounds with one
+    and two 512-token chunks (items of 8 tokens) over 128 slots and the
+    scrap slot."""
+    from paddle_tpu.ops.pallas.kda_ragged import kda_ragged
+    tok, meta = ((tokens, 32, 128), F32), ((128,), jnp.int32)
+    text = _compile(kda_ragged, one_chip, tok, tok, tok, tok,
+                    ((tokens, 32), F32), ((129, 32, 128, 128), F32),
+                    meta, meta, meta, meta)
+    assert _named(text, "kda_ragged") == ["kda_ragged"]
+
+
+def test_kda_mla_moe_round_program_at_published_widths(one_chip,
+                                                       monkeypatch):
+    """The dense KDA layer, an expert KDA layer and the expert MLA layer
+    of the decoder behind the serving engine's ragged round at its
+    largest (128 decode rows and two 512-token chunks), every width as
+    published, the plan as the one message with each row's slot: the
+    program holds the recurrence once a KDA layer, the latent kernel once
+    and the grouped product twice an expert layer, under their names, and
+    updates the state pools in place."""
+    import json
+    import os
+    from paddle_tpu.models import KDAMLAMoEForCausalLM
+    from paddle_tpu.nn import initializer as init
+    from paddle_tpu.ops.pallas import _common as gate
+    from paddle_tpu.serving import ServingEngine
+    from paddle_tpu.serving.engine import _message_len
+    from benchmark.models import kda_mla_moe as family
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "ling-3p0-flash-serve.json")) as f:
+        cfg = json.load(f)
+    cfg.update(num_layers=3, layers_run=[1, 10, 11],
+               layer_types_run=["kda", "kda", "mla"])
+    init.set_global_initializer(init.Constant(0.01), init.Constant(0.0))
+    try:
+        model = KDAMLAMoEForCausalLM(family.model_config(
+            cfg, moe_backend="pallas"))
+    finally:
+        init.set_global_initializer(None, None)
+    eng = ServingEngine(model, page_size=256, num_pages=8, max_slots=128,
+                        prefill_chunk=512, prefill_token_budget=1024,
+                        attn_backend="pallas", prefix_cache=False,
+                        token_pads=[128, 1152])
+    assert eng.kv.state_layers == [0, 1] and eng.state_backend == "pallas"
+    assert eng.kv.pools[0]["state"].shape == (129, 32, 128, 128)
+    monkeypatch.setattr(gate, "on_tpu", lambda: True)
+    eng._jit = False
+    step = jax.jit(eng._build_round(), donate_argnums=(2,))
+
+    def aval(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    n = _message_len(1152, 128, eng._bt_shape(), True)
+    c = step.lower([aval(a) for a in eng._param_arrays],
+                   jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),
+                   jax.tree_util.tree_map(aval, eng.kv.pools)).compile()
+    text = c.as_text()
+    assert len(_named(text, "kda_ragged")) == 2
+    assert len(_named(text, "mla_ragged_attention")) == 1
+    assert len(_named(text, "moe_grouped_matmul")) == 4
+    m = c.memory_analysis()
+    assert m.alias_size_in_bytes >= eng.kv.nbytes()

@@ -50,7 +50,11 @@ and ``_serve_loop`` open them; ``cat`` is ``serving``):
     no rows a token, ``state_rows``: the launched rows times those layers,
     the states the round has to read and write (``kda_roofline_pct.serve``
     checks it, ``state_read_pct.serve`` weighs it against
-    ``latent_rows``).
+    ``latent_rows``), and ``state_chunk_tokens``: the tokens of the rows
+    longer than one token times those layers (prompt chunks; 0 in a
+    decode-only round), defined by the rows alone: it is also what
+    ``ops/pallas/kda_ragged.py`` takes in blocks through the matrix unit.
+    No metric reads it yet.
 ``round.schedule``
     ``scheduler.schedule()``, ``ensure_decode_capacity()``, admission and
     eviction bookkeeping.

@@ -203,6 +203,12 @@ class StateAttention:
         """One state a launched row, whatever its context."""
         return "state_rows", int(np.count_nonzero(row_lens))
 
+    def chunk_tokens(self, row_lens):
+        """The tokens of the rows longer than one token (prompt chunks):
+        the part of a round's state work that grows with its tokens and
+        not with its rows, whatever backend runs it."""
+        return int(row_lens[row_lens > 1].sum())
+
     def check_mesh(self, degree, axis):
         raise ValueError("the state kernel takes whole head blocks of a "
                          f"slot: no split over mesh axis {axis}")

@@ -607,7 +607,12 @@ class ServingEngine:
                 # state layer
                 name, rows = self._state.rows_read(row_lens[:n],
                                                    kv_lens[:n])
-                rows_read[name] = rows * len(self.kv.state_layers)
+                layers = len(self.kv.state_layers)
+                rows_read[name] = rows * layers
+                # ... and the tokens of its rows of several tokens
+                # (prompt chunks), a state layer each
+                rows_read["state_chunk_tokens"] = \
+                    self._state.chunk_tokens(row_lens[:n]) * layers
             rnd.set(pad=T, tokens=total, row_lens=row_lens[:n].tolist(),
                     kv_lens=kv_lens[:n].tolist(), **rows_read)
             if freed:

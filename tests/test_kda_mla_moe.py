@@ -339,50 +339,210 @@ CHUNKS = [(10, 10, 2), (20, 31, 5)]
 MIXED = [(1, 5, 3), (1, 7, 2), (20, 20, 1), (33, 50, 4)]
 
 
-@pytest.mark.parametrize("rows,total,block_q", [
-    (DECODE, 8, None), (DECODE, 8, 4), (CHUNKS, 32, None), (CHUNKS, 32, 1),
-    (MIXED, 64, None), (MIXED, 64, 16)])
-def test_kernel_interpreted_matches_its_xla_twin(rows, total, block_q):
-    """Decode rows, chunk rows and both in one launch; every slot no row
-    names (the scrap slot too) holds no number, and none comes out."""
-    args = list(_case(rows, total))
+def _recurrence64(args):
+    """The recurrence written out in float64, a row at a time: a row at
+    the start of its context starts from zero whatever its slot held.
+    -> (o [T, H, D], {slot: state after the row})."""
+    q, k, v, alpha, beta, state = (np.asarray(a, np.float64)
+                                   for a in args[:6])
+    sl, rs, rl, kl = (np.asarray(a) for a in args[6:])
+    o, new = np.zeros(q.shape), {}
+    for slot, t0, n, ctx in zip(sl, rs, rl, kl):
+        if n == 0:
+            continue
+        S = np.zeros(state.shape[1:]) if ctx == n else state[slot].copy()
+        for t in range(t0, t0 + n):
+            S = S * alpha[t][:, :, None]
+            S = S + beta[t][:, None, None] * k[t][:, :, None] \
+                * (v[t] - np.einsum("hk,hkv->hv", k[t], S))[:, None, :]
+            o[t] = np.einsum("hk,hkv->hv", q[t], S)
+        new[int(slot)] = S
+    return o, new
+
+
+def _poison(args, rows):
+    """Every slot no row names (the scrap slot too) holds no number."""
     named = sorted(s for _, _, s in rows)
     poisoned = np.full(args[5].shape, np.nan, np.float32)
     poisoned[named] = np.asarray(args[5])[named]
-    args[5] = jnp.asarray(poisoned)
+    return args[:5] + (jnp.asarray(poisoned),) + args[6:], named
+
+
+# how far the chunked form may lie from the float64 recurrence, in units of
+# the float32 twin's own distance on the same case (floored at the
+# rounding of one float32 sum of the case's size)
+CHUNKED_OVER_TWIN = 4.0
+
+
+def _held_to_float64(args, rows, got_o, got_s):
+    """Decode rows at the twin's rounding (1e-6: the token form, the same
+    sums in the same order); chunk rows within ``CHUNKED_OVER_TWIN`` times
+    the twin's own error against the recurrence in float64."""
+    o64, s64 = _recurrence64(args)
     o1, s1 = kda.kda_ragged_reference(*args)
-    # work items of another size than the launch would choose: ``_call``
-    o2, s2 = kda.kda_ragged(*args, interpret=True) if block_q is None \
-        else kda._call(*args, block_q=block_q, interpret=True)
-    assert np.isfinite(np.asarray(o2)).all()
-    assert float(jnp.abs(o1).max()) > 0.01
-    np.testing.assert_allclose(o2, o1, atol=1e-6, rtol=0)
-    np.testing.assert_allclose(np.asarray(s2)[named], np.asarray(s1)[named],
-                               atol=1e-6, rtol=0)
-    used = sum(n for n, _, _ in rows)
-    assert not np.asarray(o2)[used:].any()        # pad tokens come back 0
+    got_o, got_s, o1, s1 = (np.asarray(a) for a in (got_o, got_s, o1, s1))
+    assert np.isfinite(got_o).all()
+    at = 0
+    for n, _, slot in rows:
+        o_row, s_row = got_o[at:at + n], got_s[slot]
+        assert np.isfinite(s_row).all()
+        if n == 1:
+            np.testing.assert_allclose(o_row, o1[at:at + n], atol=1e-6,
+                                       rtol=0)
+            np.testing.assert_allclose(s_row, s1[slot], atol=1e-6, rtol=0)
+        else:
+            for got, twin, want in ((o_row, o1[at:at + n], o64[at:at + n]),
+                                    (s_row, s1[slot], s64[slot])):
+                floor = 4 * np.finfo(np.float32).eps * np.abs(want).max()
+                room = CHUNKED_OVER_TWIN * max(np.abs(twin - want).max(),
+                                               floor)
+                assert np.abs(got - want).max() <= room
+        at += n
+    assert not got_o[at:].any()                   # pad tokens come back 0
+
+
+# a launch of no more tokens than rows (``_case`` has 8 rows) that holds a
+# row of several tokens: a prompt's tail chunk beside few decode rows
+TAIL = [(1, 5, 3), (5, 12, 2), (1, 1, 4)]
+
+
+@pytest.mark.parametrize("rows,total,block", [
+    (DECODE, 8, None), (DECODE, 8, 16), (CHUNKS, 32, None), (CHUNKS, 32, 16),
+    (MIXED, 64, None), (MIXED, 64, 16), (TAIL, 8, None), (TAIL, 8, 16)])
+def test_kernel_interpreted_matches_its_xla_twin(rows, total, block):
+    """Decode rows, chunk rows and both in one launch; every slot no row
+    names (the scrap slot too) holds no number, and none comes out. A
+    decode row is the twin's to 1e-6. A chunk row takes the chunked form
+    (since PR 36), another order of the same sums: the twin's 1e-6 became
+    ``CHUNKED_OVER_TWIN`` times the twin's own distance from the recurrence
+    in float64, which is what that comparison justifies and no more
+    (``_held_to_float64``). The row's length alone says which form: the
+    launch's shape does not (``TAIL`` has as many tokens as rows, and its
+    row of five tokens goes on from a state)."""
+    args, named = _poison(_case(rows, total), rows)
+    # blocks of another size than the launch would choose: ``_call``
+    o2, s2 = kda.kda_ragged(*args, interpret=True) if block is None \
+        else kda._call(*args, block=block, interpret=True)
+    assert float(jnp.abs(o2).max()) > 0.01
+    _held_to_float64(args, rows, o2, s2)
+
+
+def _matrix_unit(a, b, contract=((2,), (1,))):
+    """A float32 product as the chip's matrix unit takes it at the highest
+    precision: each operand in three bfloat16 parts (the smallest 2^-16 of
+    it), whatever falls under float32's smallest normal flushed to zero,
+    six partial products summed in float32."""
+    tiny = jnp.finfo(jnp.float32).tiny
+
+    def flushed(x):
+        return jnp.where(jnp.abs(x) < tiny, 0.0, x)
+
+    def parts(x):
+        out, rest = [], flushed(x)
+        for _ in range(3):
+            p = flushed(rest.astype(jnp.bfloat16).astype(jnp.float32))
+            out.append(p)
+            rest = flushed(rest - p)
+        return out
+
+    def dot(x, y):
+        return jax.lax.dot_general(x, y, (contract, ((0,), (0,))),
+                                   precision="highest")
+
+    (ah, am, al), (bh, bm, bl) = parts(a), parts(b)
+    return dot(ah, bh) + (dot(ah, bm) + dot(am, bh)) \
+        + (dot(ah, bl) + dot(al, bh) + dot(am, bm))
+
+
+def _gated(g):
+    """``_case``'s rows of 80 and 70 tokens at head width 128 with the
+    log-decay drawn by ``g(rng, shape)``."""
+    rows = [(80, 80, 1), (70, 100, 2)]
+    args = _case(rows, 152, heads=2, dim=128, seed=3)
+    shape = args[3].shape
+    alpha = np.exp(g(np.random.default_rng(4), shape)).astype(np.float32)
+    return rows, args[:3] + (jnp.asarray(alpha),) + args[4:]
+
+
+# the safe gate's log-decay is in (-5, 0) a token and channel
+# (``kda_gates``, ``kda_lower_bound`` -5)
+GATES = {
+    "floor": lambda rng, s: np.full(s, -5.0),
+    "ceiling": lambda rng, s: np.full(s, -1e-4),
+    "mix": lambda rng, s: np.where(rng.random(s) < 0.5, -5.0, -1e-4),
+    # the second row's whole first block (tokens 80-143) at the floor,
+    # among tokens that hardly decay
+    "block": lambda rng, s: np.broadcast_to(np.where(
+        (np.arange(s[0]) // 80 == 1)[:, None, None], -5.0, -1e-4), s),
+}
+
+CHUNKED = {
+    # (rows, padded tokens, _case's sizes, block)
+    "512_from_zero": ([(512, 512, 2)], 512, {}, None),
+    "continues_a_poisoned_pool": ([(40, 100, 3)], 48, {}, None),
+    "partial_blocks": ([(33, 33, 1), (64, 80, 2), (65, 65, 3),
+                        (100, 130, 4)], 264, {}, None),
+    "partial_blocks_of_16": ([(33, 33, 1), (64, 80, 2), (65, 65, 3),
+                              (100, 130, 4)], 264, {}, 16),
+    "two_chunks_and_decode_rows": ([(1, 5, 3), (1, 9, 1), (70, 70, 2),
+                                    (90, 120, 4), (1, 1, 5)], 168, {}, None),
+    "head_width_128": ([(1, 7, 1), (100, 130, 2)], 104,
+                       {"heads": 8, "dim": 128, "slots": 2}, None),
+    # no more tokens than ``_case``'s 8 rows: the row's length decides,
+    # not the launch's shape
+    "as_many_tokens_as_rows": ([(1, 9, 1), (6, 40, 3), (1, 1, 2)], 8, {},
+                               None),
+    "as_many_tokens_as_rows_128": ([(3, 3, 1), (4, 20, 2), (1, 6, 3)], 8,
+                                   {"heads": 8, "dim": 128, "slots": 3},
+                                   None),
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNKED) + [
+    f"gates_{g}_{unit}" for g in GATES for unit in ("float32", "on_chip")
+    if unit == "float32" or g in ("floor", "block")])
+def test_chunk_rows_take_the_chunked_form_held_to_float64(case, monkeypatch):
+    """A row of several tokens goes through the recurrence's chunked form
+    (blocks of 64 tokens through the matrix unit, sub-blocks of 16): from
+    zero and continuing a state, whole and partial blocks, several chunk
+    rows beside decode rows, every slot no row names holding no number;
+    and with the gates at their bounds (the floor of -5 on every channel
+    for a whole block), every output finite. Held to the recurrence in
+    float64 (``_held_to_float64``), not to the twin's rounding. The
+    ``on_chip`` cases take every product as the chip's matrix unit does
+    (``_matrix_unit``): there a factor near exp(-80) loses its lower
+    parts, which is why a sub-block's factors meet at its middle."""
+    if case in CHUNKED:
+        rows, total, sizes, block = CHUNKED[case]
+        args = _case(rows, total, **sizes)
+    else:
+        _, gate, unit = case.split("_", 2)
+        rows, args = _gated(GATES[gate])
+        block = None
+        if unit == "on_chip":
+            monkeypatch.setattr(kda, "_dot", _matrix_unit)
+            kda._call.clear_cache()
+    args, _ = _poison(args, rows)
+    try:
+        o, s = kda.kda_ragged(*args, interpret=True) if block is None \
+            else kda._call(*args, block=block, interpret=True)
+    finally:
+        if case.endswith("on_chip"):
+            kda._call.clear_cache()
+    assert float(jnp.abs(o).max()) > 0.01
+    _held_to_float64(args, rows, o, s)
 
 
 def test_twin_is_the_recurrence_written_out():
     """A row at the start of its context starts from zero whatever its
     slot held; a later row goes on from its slot."""
-    q, k, v, alpha, beta, state, sl, rs, rl, kl = _case(
-        [(3, 3, 2), (2, 9, 4)], 8)
-    o, new = kda.kda_ragged_reference(q, k, v, alpha, beta, state, sl, rs,
-                                      rl, kl)
-    for (t0, n, slot, fresh) in ((0, 3, 2, True), (3, 2, 4, False)):
-        S = np.zeros((4, 16, 16)) if fresh else np.asarray(state[slot],
-                                                           np.float64)
-        for t in range(t0, t0 + n):
-            kt, vt, qt = (np.asarray(a[t], np.float64) for a in (k, v, q))
-            S = S * np.asarray(alpha[t], np.float64)[:, :, None]
-            S = S + np.asarray(beta[t], np.float64)[:, None, None] \
-                * kt[:, :, None] * (vt - np.einsum("hk,hkv->hv", kt, S)
-                                    )[:, None, :]
-            np.testing.assert_allclose(
-                o[t], np.einsum("hk,hkv->hv", qt, S), atol=1e-5)
-        np.testing.assert_allclose(new[slot], S, atol=1e-5)
-    np.testing.assert_array_equal(new[1], state[1])     # untouched slots
+    args = _case([(3, 3, 2), (2, 9, 4)], 8)
+    o, new = kda.kda_ragged_reference(*args)
+    o64, s64 = _recurrence64(args)
+    np.testing.assert_allclose(o[:5], o64[:5], atol=1e-5)
+    for slot in (2, 4):
+        np.testing.assert_allclose(new[slot], s64[slot], atol=1e-5)
+    np.testing.assert_array_equal(new[1], args[5][1])   # untouched slots
 
 
 def test_the_benchmark_counts_the_recurrence_not_the_kernels_form():
@@ -642,3 +802,31 @@ def test_a_traced_round_says_how_many_states_it_reads():
     assert max(r["state_rows"] for r in rounds) == 4
     # the two expert layers report, in layer order
     assert len(routes[0]["args"]["layers"]) == 2
+
+
+def test_a_traced_round_counts_the_tokens_of_its_chunk_rows():
+    """``state_chunk_tokens``: the tokens of the rows longer than one
+    token times the state layers. A round with one chunk row of n tokens
+    over two state layers reports 2n, a decode-only round 0, and
+    ``state_rows`` is what it was."""
+    from paddle_tpu.observability import tracing
+    model = build(experts_held=(0, 4))
+    buf = tracing.start()
+    try:
+        eng = engine(model)
+        a = GenerationRequest(IDS[:13].tolist(), max_new_tokens=3)
+        b = GenerationRequest(IDS[30:31].tolist(), max_new_tokens=2)
+        eng.submit_request(a)
+        eng.run_until_idle()
+        eng.submit_request(b)
+        eng.run_until_idle()
+        rounds = [e["args"] for e in buf.events
+                  if e.get("ph") == "X" and e["name"] == "decode_round"]
+    finally:
+        tracing.stop()
+    assert len(eng.kv.state_layers) == 2
+    # a chunk of 8, the prompt's last 5, two decode rounds; then a prompt
+    # of one token (a row of one token is no chunk row) and a decode round
+    assert [r["row_lens"] for r in rounds] == [[8], [5], [1], [1], [1], [1]]
+    assert [r["state_chunk_tokens"] for r in rounds] == [16, 10, 0, 0, 0, 0]
+    assert [r["state_rows"] for r in rounds] == [2] * 6

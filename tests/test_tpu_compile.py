@@ -634,15 +634,23 @@ def test_afmoe_round_program_at_published_widths(one_chip, monkeypatch):
 # groups; 128 slots, pages of 256 tokens.
 @pytest.mark.parametrize("tokens", [128, 640, 1152])
 def test_kda_ragged_at_published_widths(one_chip, tokens):
-    """A decode round (one item a token) and the mixed rounds with one
-    and two 512-token chunks (items of 8 tokens) over 128 slots and the
-    scrap slot."""
+    """A decode round and the mixed rounds with one and two 512-token
+    chunks over 128 slots and the scrap slot: whatever the launch's shape,
+    the token form for the rows of one token (one item a row) and the
+    chunked form in blocks of 64 through the matrix unit for the others
+    (no block in a decode round): two launches over the one pool, updated
+    in place, each under a name that holds ``kda_ragged`` (the benchmark's
+    readers sum the events that do)."""
     from paddle_tpu.ops.pallas.kda_ragged import kda_ragged
     tok, meta = ((tokens, 32, 128), F32), ((128,), jnp.int32)
-    text = _compile(kda_ragged, one_chip, tok, tok, tok, tok,
-                    ((tokens, 32), F32), ((129, 32, 128, 128), F32),
-                    meta, meta, meta, meta)
-    assert _named(text, "kda_ragged") == ["kda_ragged"]
+    pool = ((129, 32, 128, 128), F32)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (tok, tok, tok, tok, ((tokens, 32), F32), pool,
+                         meta, meta, meta, meta)]
+    c = jax.jit(kda_ragged, donate_argnums=(5,)).lower(*args).compile()
+    assert _named(c.as_text(), "kda_ragged") == \
+        ["kda_ragged_chunks", "kda_ragged_tokens"]
+    assert c.memory_analysis().alias_size_in_bytes >= 129 * 32 * 128 * 128 * 4
 
 
 def test_kda_mla_moe_round_program_at_published_widths(one_chip,
@@ -651,9 +659,9 @@ def test_kda_mla_moe_round_program_at_published_widths(one_chip,
     of the decoder behind the serving engine's ragged round at its
     largest (128 decode rows and two 512-token chunks), every width as
     published, the plan as the one message with each row's slot: the
-    program holds the recurrence once a KDA layer, the latent kernel once
-    and the grouped product twice an expert layer, under their names, and
-    updates the state pools in place."""
+    program holds the recurrence's two forms once each a KDA layer, the
+    latent kernel once and the grouped product twice an expert layer,
+    under their names, and updates the state pools in place."""
     import json
     import os
     from paddle_tpu.models import KDAMLAMoEForCausalLM
@@ -692,7 +700,9 @@ def test_kda_mla_moe_round_program_at_published_widths(one_chip,
                    jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),
                    jax.tree_util.tree_map(aval, eng.kv.pools)).compile()
     text = c.as_text()
-    assert len(_named(text, "kda_ragged")) == 2
+    # a KDA layer's rows of one token and its chunk rows: two launches
+    assert _named(text, "kda_ragged") == \
+        ["kda_ragged_chunks"] * 2 + ["kda_ragged_tokens"] * 2
     assert len(_named(text, "mla_ragged_attention")) == 1
     assert len(_named(text, "moe_grouped_matmul")) == 4
     m = c.memory_analysis()

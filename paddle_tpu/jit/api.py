@@ -24,11 +24,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _Annotation
 
 from ..core import random as _random
 from ..core.dispatch import apply
 from ..core.dtype import convert_dtype
 from ..core.tensor import Tensor
+from ..observability import tracing as _trc
 
 __all__ = ["to_static", "not_to_static", "InputSpec", "StaticFunction",
            "ignore_module"]
@@ -227,6 +229,7 @@ class StaticFunction:
         self._cache = {}
         self._state_cache = None   # cached _state() walk (invalidate())
         self._fast_step = {}       # steady-state whole-step dispatch memo
+        self._steps_run = 0        # whole steps executed (``train_step``)
         self._layer = None
         if isinstance(function, Layer):
             self._layer = function
@@ -414,6 +417,7 @@ class StaticFunction:
 
     def _build_forward(self, skel, params, buffers, n_args):
         fn = self._fn
+        _trc.listen_compiles()      # the compile log, from the first build
         meta = {}  # per-cache-entry output skeleton (set during trace)
 
         def pure(param_arrs, buf_arrs, arg_arrs, rng_spec):
@@ -526,6 +530,18 @@ class StaticFunction:
         array straight into the pjit call."""
         jitted, meta = entry
         params, buffers, slots, layers, opts = state
+        # the step's ONE tracing gate: the buffer is on, or a profile is
+        # being taken (the benchmark's training runner starts the profiler
+        # and not the buffer; the phases then go to the annotation alone).
+        # On: ``train_step`` and its three phases
+        # (observability/tracing.py lists them)
+        tr = _trc._TR if _trc._loaded else _trc._load()
+        stp = ph = None
+        if tr is not None or _Annotation.is_enabled():
+            stp = _trc.phase(tr, "train_step", cat="step",
+                             step=self._steps_run).open()
+            ph = stp.inner("step.gather")
+        self._steps_run += 1
         if meta.get("uses_rng", True):
             rng_spec = _random.next_key_spec()
         else:
@@ -543,9 +559,13 @@ class StaticFunction:
                                             (3,), jnp.uint32),
                                         jax.ShapeDtypeStruct(
                                             lrs.shape, jnp.float32)))
+        if ph is not None:
+            ph = ph.then("step.launch")
         out_arrs, new_state = jitted(state_in,
                                      [t._data for t in arg_tensors],
                                      rng_spec, lrs)
+        if ph is not None:
+            ph = ph.then("step.rebind")
         if meta.get("unstaged_accumulators"):
             raise RuntimeError(
                 "optimizer state was created during tracing and cannot be "
@@ -561,11 +581,15 @@ class StaticFunction:
             b._data = a
         for (cont, k), a in zip(slots, new_state[n_p + n_b:]):
             cont[k] = a
-        return _tree_rebuild(meta["out_skel"], [
+        out = _tree_rebuild(meta["out_skel"], [
             Tensor(a, stop_gradient=True) for a in out_arrs], lambda t: t)
+        if stp is not None:
+            stp.close(ph.close())
+        return out
 
     def _build_whole_step(self, skel, params, buffers, slots, opts, n_args):
         fn = self._fn
+        _trc.listen_compiles()      # the compile log, from the first build
         meta = {}  # per-cache-entry output skeleton (set during trace)
 
         def pure(state_arrs, arg_arrs, rng_spec, lrs):

@@ -22,17 +22,32 @@ Two clocks, stated plainly:
   and host spans of several processes line up in one waterfall. Nesting
   needs no explicit parent ids: Perfetto nests same-thread "X" events by
   interval containment.
-* The serving round and its phases (:class:`phase`) are ALSO recorded as
-  ``jax.profiler.TraceAnnotation`` (TraceMe level 1). While a profile is
-  being taken they land on ``/host:CPU`` of the same ``.xplane.pb`` as
-  the device's ops, on the profiler's clock, so a device idle gap can be
-  laid against the host phase that overlaps it. With no profile running
-  the annotation is one flag test; the buffer copy is there either way
-  (``PADDLE_TPU_TRACE=1`` alone, for hunting a slow round over many
-  untraced runs).
+* The serving round, the training step and their phases
+  (:class:`phase`) are ALSO recorded as ``jax.profiler.TraceAnnotation``
+  (TraceMe level 1). While a profile is being taken they land on
+  ``/host:CPU`` of the same ``.xplane.pb`` as the device's ops, on the
+  profiler's clock, so a device idle gap can be laid against the host
+  phase that overlaps it. With no profile running the annotation is one
+  flag test; the buffer copy is there either way (``PADDLE_TPU_TRACE=1``
+  alone, for hunting a slow round over many untraced runs).
+* A :class:`phase`'s record in the buffer (every ``decode_round``,
+  ``round.*``, ``serve.*``, ``train_step`` and ``step.*``; no other
+  event, and never the annotation) carries a third reading, ``cpu_us``:
+  the CPU time of the thread that ran it (``time.thread_time_ns()``)
+  between the same two instants as ``ts`` and ``dur``. ``dur`` minus
+  ``cpu_us`` is time off the CPU: waiting for the device, the interpreter
+  lock, another thread's lock or the OS. Phases that meet on a thread
+  share ONE reading of both clocks, so they tile its time with no hole.
+  A sandboxed kernel may move a thread's CPU clock in ticks (gVisor, the
+  benchmark's chip host: 10 ms a tick, 6 us a reading): one record then
+  reads 0 or a tick, and only sums over many records say what a phase
+  costs.
 
-The phase spans, named once, here (``serving/engine.py`` ``_step_ragged``
-and ``_serve_loop`` open them; ``cat`` is ``serving``):
+The phase spans, named once, here (``serving/engine.py`` ``_step_ragged``,
+``_end_round`` and ``_serve_loop`` open them; ``cat`` is ``serving``).
+With tracing on, every instant of the serve thread from ``start()`` to
+``stop()`` lies in exactly one of ``decode_round``, ``serve.idle_wait``
+and ``serve.turn``:
 
 ``decode_round``
     one whole scheduler round. Args: ``round`` (the engine's step
@@ -74,8 +89,18 @@ and ``_serve_loop`` open them; ``cat`` is ``serving``):
     sampling, ``complete_step`` (``on_token`` / ``on_done`` callbacks run
     here, a closed loop's resubmits among them), prefill bookkeeping,
     metrics hooks.
+``round.account``
+    the engine's accounting after the last token went out: the pool's
+    occupancy and peak, ``group.note``, ``metrics.sample_state``, the step
+    counter. The round closes where it closes.
 ``serve.idle_wait``
     one ``_wake.wait(0.02)`` of the serve loop: no work pending.
+``serve.turn``
+    the serve loop between two of the others, from the close of a round
+    or a wait to the opening of the next: the loop's condition and fault
+    site, ``scheduler.has_work()`` (the scheduler's lock, shared with
+    ``submit``) and the wait for ``_step_lock``. A round driven by
+    ``step()`` from another thread has none.
 
 ``cache.window_release``
     one zero-length event a round of an engine with a windowed page
@@ -95,10 +120,61 @@ and ``_serve_loop`` open them; ``cat`` is ``serving``):
     no token, the fullest one's pairs). Read by
     ``moe_gmm_roofline_pct.serve`` and ``experts_idle_pct.serve``.
 
-The five ``round.*`` phases follow one another inside their
-``decode_round`` and carry its ``round``, which ties a phase to its round
-and to the program launch it caused. A round that finds nothing to launch
-records no ``decode_round`` in the buffer.
+The six ``round.*`` phases tile their ``decode_round`` (which opens once
+``step()`` holds ``_step_lock`` and closes when ``step()`` is done) and
+carry its ``round``, which ties a phase to its round and to the program
+launch it caused. A round that finds nothing to launch is a
+``decode_round`` with no ``pad`` and the three phases it went through
+(``round.schedule``, ``round.assemble``, ``round.account``), in the
+buffer as on the profiler's clock.
+
+The training step's spans (``jit/api.py`` ``_exec_whole_step``; ``cat``
+``step``; the gate is the buffer OR a profile being taken, and with the
+profile alone they go to the annotation alone):
+
+``train_step``
+    one call of a whole-step ``to_static`` function. Args: ``step``, a
+    counter of that function. The time between two of them is the
+    caller's (the loss fetch, the next batch).
+``step.gather``
+    the rng spec, the learning rates, the list of state arrays.
+``step.launch``
+    the call of the jitted step (a first call traces, lowers and
+    compiles in here).
+``step.rebind``
+    the write-back of parameters, buffers and optimizer slots, and the
+    outputs' wrapping.
+
+What a stall was, while the buffer is on (these nest inside phases, so
+they carry neither prefix; buffer only):
+
+``host.gc``
+    one collection of Python's collector, on the thread it ran in (``cat``
+    ``host``). Args: ``generation``, ``collected``.
+``jit.trace`` / ``jit.lower`` / ``jit.compile``
+    what ``jax.monitoring`` reports when a jaxpr trace, a lowering to an
+    MLIR module or a backend compile (a load from the compile cache
+    included) ends; the event ends there and starts its duration earlier
+    (``cat`` ``jit``). Args: ``fun_name`` (jax's own: ``round_step`` for
+    the trace of the serving round's program, ``jit(round_step)`` for the
+    rest). An inner jit's trace lies inside its caller's; events shorter
+    than a millisecond (thousands of those a program) are not recorded.
+
+Beside the ``jit.*`` events and ALWAYS on (jax calls the listener only
+when it traces, lowers or compiles): :func:`compile_log`, the newest
+``_COMPILE_LOG_CAP`` of ``(t_end on time.perf_counter(), kind, fun_name,
+seconds)`` that took a millisecond or more (an inner jit's trace of a
+tenth of one is counted in the totals and not kept) since
+:func:`listen_compiles` was first called
+(``ServingEngine.__init__``, ``to_static``'s first build, and the buffer
+coming on), and :func:`compile_totals`
+(``ServingEngine.stats()["compile"]``: ``trace_s``, ``lower_s``,
+``compile_s``, ``events``).
+
+At ``_MAX_EVENTS`` the buffer keeps the NEWEST events and drops the
+oldest (an eighth of the cap at a time); an export then holds one
+``trace_truncated`` metadata event (``at_events``, ``dropped``,
+``wall_us`` of the first drop) and ``droppedEvents``.
 
 One event comes from a kernel, at trace time and not per step (``cat``
 ``kernels``; ``ops/pallas/flash_attention.py`` behind the same one gate):
@@ -136,13 +212,14 @@ else is dropped before export. Undecided traces still pending at export
 time are flushed as-is so a shutdown mid-request stays visible.
 
 Stdlib-only at import time (``jax`` is imported by the first
-:class:`phase` that opens).
+:class:`phase` that opens and by :func:`listen_compiles`).
 """
 from __future__ import annotations
 
 import atexit
 import collections
 import contextlib
+import gc
 import json
 import os
 import sys
@@ -151,12 +228,15 @@ import time
 import zlib
 
 __all__ = ["TraceBuffer", "span", "phase", "add_complete",
-           "collective_event",
+           "collective_event", "listen_compiles", "compile_log",
+           "compile_totals",
            "mint_context", "req_event", "finish_request",
            "enabled", "get_buffer", "start", "stop", "export",
            "_reset_state"]
 
-_MAX_EVENTS = 200_000  # runaway guard: ~40MB of JSON at most
+_MAX_EVENTS = 200_000  # the buffer's cap: ~40MB of JSON at most
+_COMPILE_LOG_CAP = 10_000   # compile_log() keeps the newest this many ...
+_COMPILE_LOG_FLOOR_S = 1e-3  # ... of those that took at least this long
 _DECIDED_CAP = 4096    # remembered tail-sampling verdicts (FIFO)
 _PENDING_CAP = 1024    # simultaneously-undecided request traces
 
@@ -191,15 +271,19 @@ def _metric_drop(n=1):
 
 
 class TraceBuffer:
-    """Append-only buffer of chrome-trace events for ONE process."""
+    """Buffer of chrome-trace events for ONE process: append-only up to
+    ``_MAX_EVENTS``, from there on the newest that many."""
 
     def __init__(self, rank=None, path=None):
         from .metrics import env_rank
         self.rank = env_rank() if rank is None else int(rank)
         self.path = path
         self.events = []
-        self._lock = threading.Lock()
-        self.dropped = 0
+        # re-entrant: a collection can start inside an append, and its
+        # ``host.gc`` event is appended from the same thread
+        self._lock = threading.RLock()
+        self.dropped = 0            # events the cap pushed out, oldest first
+        self._truncated_us = None   # when the first of them went
         # -------- request tracing (tail-based sampling) state
         self._req = {}              # tid -> pending event list
         self._decided = {}          # tid -> kept? (post-terminal verdict)
@@ -210,31 +294,42 @@ class TraceBuffer:
         self.slow_ms = _env_float(_SLOW_ENV)
 
     def _append_locked(self, ev):
-        """Append under self._lock; at the cap the FIRST drop leaves one
-        over-cap metadata marker so a truncated export never silently
-        looks complete. Returns False when the event was dropped."""
-        if len(self.events) >= _MAX_EVENTS:
+        """Append under self._lock. At the cap the OLDEST events go, so a
+        process left tracing still holds the minutes before a stall; they
+        go an eighth of the cap at a time (a full buffer does not shift
+        its every entry for one event). Returns how many went: an export
+        says so (``trace_truncated``, ``droppedEvents``)."""
+        lost = 0
+        over = len(self.events) + 1 - _MAX_EVENTS
+        if over > 0:
+            lost = min(len(self.events), max(over, _MAX_EVENTS // 8))
             if self.dropped == 0:
-                self.events.append({
-                    "name": "trace_truncated", "ph": "M",
-                    "pid": self.rank,
-                    "args": {"at_events": _MAX_EVENTS,
-                             "wall_us": time.time() * 1e6}})
-            self.dropped += 1
-            return False
+                self._truncated_us = time.time() * 1e6
+            del self.events[:lost]
+            self.dropped += lost
         self.events.append(ev)
-        return True
+        return lost
 
-    def add(self, name, ts_s, dur_s, cat="host", tid=None, args=None):
+    def _put(self, name, ts_s, dur_s, cat, tid=None, args=None,
+             cpu_us=None):
+        """Build and append one complete event; -> how many the cap
+        pushed out (no call outside this buffer: the collector's hook
+        uses it from inside a collection)."""
         ev = {"name": str(name), "ph": "X", "pid": self.rank,
               "tid": tid if tid is not None else threading.get_ident(),
               "ts": ts_s * 1e6, "dur": max(0.0, dur_s) * 1e6, "cat": cat}
+        if cpu_us is not None:
+            ev["cpu_us"] = cpu_us
         if args:
             ev["args"] = dict(args)
         with self._lock:
-            ok = self._append_locked(ev)
-        if not ok:
-            _metric_drop()
+            return self._append_locked(ev)
+
+    def add(self, name, ts_s, dur_s, cat="host", tid=None, args=None,
+            cpu_us=None):
+        lost = self._put(name, ts_s, dur_s, cat, tid, args, cpu_us)
+        if lost:
+            _metric_drop(lost)
 
     # ---------------------------------------------- request-trace feeds
 
@@ -259,24 +354,24 @@ class TraceBuffer:
         ev = {"name": str(name), "ph": "X", "pid": self.rank,
               "tid": self._lane(tid), "ts": ts_s * 1e6,
               "dur": max(0.0, dur_s) * 1e6, "cat": cat, "args": a}
-        dropped = False
+        lost = 0
         with self._lock:
             verdict = self._decided.get(tid)
             if verdict is False:
                 return
             if verdict is True:
-                dropped = not self._append_locked(ev)
+                lost = self._append_locked(ev)
             else:
                 pend = self._req.get(tid)
                 if pend is None:
                     if len(self._req) >= _PENDING_CAP:
-                        dropped = True    # overflow: runaway guard
+                        lost = 1          # overflow: runaway guard
                     else:
                         self._req[tid] = pend = []
                 if pend is not None:
                     pend.append(ev)
-        if dropped:
-            _metric_drop()
+        if lost:
+            _metric_drop(lost)
 
     def req_finish(self, tid, keep):
         """Apply the tail-sampling verdict for ``tid``: flush (keep) or
@@ -305,8 +400,7 @@ class TraceBuffer:
             if pending:
                 self._name_lane_locked(tid)
                 for ev in pending:
-                    if not self._append_locked(ev):
-                        lost += 1
+                    lost += self._append_locked(ev)
         if lost:
             _metric_drop(lost)
         return True
@@ -325,6 +419,7 @@ class TraceBuffer:
             self._flush_pending_locked()
             events = list(self.events)
             dropped = self.dropped
+            truncated_us = self._truncated_us
         meta = [{"name": "process_name", "ph": "M", "pid": self.rank,
                  "args": {"name": f"rank_{self.rank} host"}},
                 # clock provenance for the merge tool's --align: host
@@ -334,6 +429,14 @@ class TraceBuffer:
                 {"name": "clock_domain", "ph": "M", "pid": self.rank,
                  "args": {"domain": "wall", "export_wall_us":
                           time.time() * 1e6}}]
+        if dropped:
+            # never silently complete: the cap, how many of the oldest
+            # events it pushed out, and when the first went
+            meta.append({"name": "trace_truncated", "ph": "M",
+                         "pid": self.rank,
+                         "args": {"at_events": _MAX_EVENTS,
+                                  "dropped": dropped,
+                                  "wall_us": truncated_us}})
         d = {"traceEvents": meta + events, "displayTimeUnit": "ms"}
         if dropped:
             d["droppedEvents"] = dropped
@@ -379,6 +482,7 @@ def _load():
                             or _default_path(buf.rank))
             _TR = buf
             _arm_atexit()
+            _watch(True)
         _loaded = True
         return _TR
 
@@ -414,6 +518,7 @@ def start(path=None, rank=None) -> TraceBuffer:
         _TR = TraceBuffer(rank=rank, path=path)
         _loaded = True
         _arm_atexit()
+        _watch(True)
         return _TR
 
 
@@ -424,6 +529,7 @@ def stop(path=None):
         buf = _TR
         _TR = None
         _loaded = True
+        _watch(False)
     if buf is None:
         return None
     try:
@@ -444,6 +550,7 @@ def _reset_state():
     with _state_lock:
         _TR = None
         _loaded = False
+        _watch(False)
 
 
 # ------------------------------------------------------------------ feeds
@@ -483,31 +590,46 @@ def _stats(args):
             for k, v in args.items()}
 
 
+def _now(buf):
+    """One instant on a phase's two clocks: ``time.time()`` and, where
+    there is a buffer to hold it, the calling thread's CPU time."""
+    return time.time(), (time.thread_time_ns() if buf is not None else 0)
+
+
 class phase:
     """One named host phase, recorded twice from one call: into ``buf``
-    (``time.time()``, as every event here) and as a
+    (``time.time()``, as every event here, and ``cpu_us``, the thread's
+    CPU time between the same two instants) and as a
     ``jax.profiler.TraceAnnotation``, which a running profile puts on
     ``/host:CPU`` of its ``.xplane.pb`` on the device ops' clock.
 
     The caller has passed the gate and hands in the buffer, so the off
     path never reaches this class: ``with phase(buf, "serve.idle_wait"):``,
-    or ``p = phase(buf, name, round=n).open()`` ... ``p.set(pad=T)`` ...
-    ``p = p.then("round.launch")`` ... ``p.close()`` where the phases of
-    one round follow one another."""
+    or ``rnd = phase(buf, name, round=n).open()`` ... ``p =
+    rnd.inner("round.schedule")`` ... ``p.set(pad=T)`` ... ``p =
+    p.then("round.launch")`` ... ``rnd.close(p.close())`` where the phases
+    of one round follow one another: phases that meet share ONE reading
+    of the clocks, so they tile their thread's time with no hole. ``buf``
+    may be ``None`` (a profile is being taken and the buffer is off): the
+    phase then goes to the annotation alone."""
 
-    __slots__ = ("buf", "name", "cat", "args", "t0", "_ann", "_opened")
+    __slots__ = ("buf", "name", "cat", "args", "t0", "c0", "_ann",
+                 "_opened")
 
     def __init__(self, buf, name, cat="serving", **args):
         self.buf, self.name, self.cat, self.args = buf, name, cat, args
         self._ann = None
         self._opened = args
+        self.t0 = None                    # None: not open
 
-    def open(self):
+    def open(self, at=None):
+        """``at``: the instant another phase closed at (its ``close()``),
+        where this one takes over from it."""
         ann = _ANNOTATION or _annotation()
         if ann and ann.is_enabled():      # a profile is being taken
             self._ann = ann(self.name, **_stats(self.args))
             self._ann.__enter__()
-        self.t0 = time.time()
+        self.t0, self.c0 = at or _now(self.buf)
         return self
 
     def set(self, **args):
@@ -517,28 +639,141 @@ class phase:
         if self._ann is not None:
             self._ann.set_metadata(**_stats(args))
 
-    def close(self, record=True):
-        """``record=False`` leaves the buffer as it was (a round that
-        launched nothing); the annotation, once opened, is closed."""
-        dur = time.time() - self.t0
+    def close(self, at=None):
+        """-> the instant it closed at, for the phase that ends or starts
+        with it."""
+        at = at or _now(self.buf)
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
-        if record:
-            self.buf.add(self.name, self.t0, dur, cat=self.cat,
-                         args=self.args)
+        if self.buf is not None:
+            self.buf.add(self.name, self.t0, at[0] - self.t0, cat=self.cat,
+                         args=self.args, cpu_us=(at[1] - self.c0) / 1e3)
+        self.t0 = None
+        return at
 
-    def then(self, name):
-        """Close this phase and open the next one of the same round
-        (with what this one was opened with: its ``round``)."""
-        self.close()
-        return phase(self.buf, name, self.cat, **self._opened).open()
+    def then(self, name, **args):
+        """Close this phase and open the next one at the same instant
+        (with what this one was opened with, its ``round``, and
+        ``args``)."""
+        return phase(self.buf, name, self.cat, **self._opened,
+                     **args).open(self.close())
+
+    def inner(self, name):
+        """The first phase INSIDE this one: opens at this one's opening,
+        with what it was opened with."""
+        return phase(self.buf, name, self.cat,
+                     **self._opened).open((self.t0, self.c0))
 
     __enter__ = open
 
     def __exit__(self, *exc):
         self.close()
         return False
+
+
+# ------------------------------------------- what a stall was: gc, compiles
+#
+# While the buffer is on, a collection and a trace / lowering / compile
+# are events of their own (``host.gc``, ``jit.*``), nested inside whatever
+# phase they held up. The compile log beside them is always on: jax calls
+# its listener only when it traces, lowers or compiles.
+
+_gc_t0 = None      # a collection under way (they never nest or overlap)
+
+
+def _on_gc(when, info):
+    global _gc_t0
+    if when == "start":
+        _gc_t0 = time.time()
+        return
+    buf, t0, _gc_t0 = _TR, _gc_t0, None
+    if buf is not None and t0 is not None:
+        # not through add(): no call into the metrics registry from
+        # inside a collection (whatever the thread held when it began)
+        buf._put("host.gc", t0, time.time() - t0, "host",
+                 args={"generation": info["generation"],
+                       "collected": info["collected"]})
+
+
+def _watch(on):
+    """The buffer's own hooks, put in when it comes on and taken out
+    when it goes."""
+    if on:
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+        if "jax" in sys.modules:    # never the importer (a launcher)
+            listen_compiles()
+    elif _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
+_JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("jit.trace", "trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("jit.lower", "lower_s"),
+    "/jax/core/compile/backend_compile_duration":
+        ("jit.compile", "compile_s"),
+}
+_compiles = collections.deque(maxlen=_COMPILE_LOG_CAP)
+_compile_totals = {"trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+                   "events": 0}
+_compile_lock = threading.Lock()
+_listening = False
+
+
+def _on_duration(event, seconds, **kw):
+    """jax.monitoring's duration listener: fires when a trace, a lowering
+    or a backend compile (a load from the compile cache included) ENDS."""
+    kind = _JIT_EVENTS.get(event)
+    if kind is None:
+        return
+    name, total = kind
+    # a program's trace holds thousands of inner jits' traces of a tenth
+    # of a millisecond (9,900 events a GPT serving start): they are
+    # counted, and the log and the buffer keep what can be told apart
+    kept = seconds >= _COMPILE_LOG_FLOOR_S
+    fun = str(kw.get("fun_name", ""))
+    with _compile_lock:
+        if kept:
+            _compiles.append((time.perf_counter(), name, fun, seconds))
+        _compile_totals[total] += seconds
+        _compile_totals["events"] += 1
+    buf = _TR
+    if kept and buf is not None:
+        buf.add(name, time.time() - seconds, seconds, cat="jit",
+                args={"fun_name": fun})
+
+
+def listen_compiles():
+    """Start the compile log (idempotent; ``ServingEngine.__init__`` and
+    ``to_static``'s first build call it, and the buffer when it comes on
+    in a process that has jax)."""
+    global _listening
+    with _compile_lock:
+        if _listening:
+            return
+        _listening = True
+    from jax import monitoring
+    monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def compile_log():
+    """-> ``[(t_end, kind, fun_name, seconds)]``, oldest first: every
+    ``jit.trace`` / ``jit.lower`` / ``jit.compile`` of at least
+    ``_COMPILE_LOG_FLOOR_S`` since the log began (the newest
+    ``_COMPILE_LOG_CAP``), ``t_end`` on ``time.perf_counter()``. An inner
+    jit's trace lies inside its caller's, so plain sums count nested time
+    twice."""
+    with _compile_lock:
+        return list(_compiles)
+
+
+def compile_totals():
+    """-> ``{"trace_s", "lower_s", "compile_s", "events"}`` of the whole
+    log, whatever the cap dropped (``ServingEngine.stats()["compile"]``)."""
+    with _compile_lock:
+        return dict(_compile_totals)
 
 
 def add_complete(name, ts_s, dur_s, cat="host", tid=None, args=None):

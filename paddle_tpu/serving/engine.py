@@ -263,6 +263,7 @@ class ServingEngine:
         # _ragged_shapes; every installed program lands in _programs,
         # feeding serving_compiles_total / serving_distinct_programs
         self._ragged_fn = self._build_round()
+        _trc.listen_compiles()       # stats()["compile"], always on
         self._ragged_shapes: set = set()
         self._programs: set = set()
         # one host buffer a token pad holds the round's plan (_plan)
@@ -510,21 +511,27 @@ class ServingEngine:
         return (self.max_slots, self.max_pages) if G == 1 \
             else (G, self.max_slots, self.max_pages)
 
-    def _step_ragged(self):
+    def _step_ragged(self, turn=None):
         """One scheduler round: admit, grow/evict, then assemble decode
         rows + prefill chunks (budget-bounded FIFO: a row advances by one
         chunk a round and emits its first token in the round that
-        completes its prompt) into ONE flat launch. -> decode tokens
-        emitted."""
+        completes its prompt) into ONE flat launch, then the round's
+        accounting. -> decode tokens emitted. ``turn`` is the serve
+        loop's open ``serve.turn`` (tracing on): it closes where the round
+        opens and opens again where the round closes."""
         # the ONE tracing gate of the round (standing contract: off =
-        # one check, no allocation, no call). On, the round and its five
+        # one check, no allocation, no call). On, the round and its six
         # phases are spans in the buffer and annotations on the
         # profiler's clock (observability/tracing.py lists them)
         tr = _trc._TR if _trc._loaded else _trc._load()
         rnd = ph = None
         if tr is not None:
-            rnd = _trc.phase(tr, "decode_round", round=self._steps).open()
-            ph = _trc.phase(tr, "round.schedule", round=self._steps).open()
+            if turn is not None and turn.buf is not tr:
+                turn = None           # tracing was restarted under the loop
+            rnd = turn.then("decode_round", round=self._steps) \
+                if turn is not None else \
+                _trc.phase(tr, "decode_round", round=self._steps).open()
+            ph = rnd.inner("round.schedule")
         # pages that slid out of a windowed group's window since the last
         # round go back first: this round's admissions may take them
         freed = self.scheduler.release_slid_pages()
@@ -566,10 +573,7 @@ class ServingEngine:
                 plan.append((req, take,
                              p[req.num_cached:req.num_cached + take]))
         if not plan:
-            if rnd is not None:
-                ph.close()
-                rnd.close(record=False)
-            return 0
+            return self._end_round(rnd, ph, turn, 0)
         total = sum(take for _, take, _ in plan)
         T = self._pad(total)
         message, parts = self._plan(T)
@@ -703,13 +707,11 @@ class ServingEngine:
             self._chunk_tokens += spent
             self.metrics.on_prefill_chunk(spent)
         if rnd is not None:
-            ph.close()
             # engine-lane round span: batched, ONE per round, row counts
             # in args (the waterfall's decode cadence)
             rnd.set(decode_rows=len(by_slot),
                     prefill_rows=len(plan) - len(decode_rows),
                     prefill_tokens=spent)
-            rnd.close()
             t0, now = rnd.t0, time.time()
             for req, take, _ in plan[len(decode_rows):]:
                 if req.trace is not None:
@@ -718,7 +720,36 @@ class ServingEngine:
                                    args={"tokens": take,
                                          "cached": req.num_cached})
         self._decode_tokens += len(by_slot)
-        return len(by_slot)
+        return self._end_round(rnd, ph, turn, len(by_slot))
+
+    def _end_round(self, rnd, ph, turn, emitted):
+        """What every round ends with, one that launched nothing too: the
+        engine's accounting (``round.account`` where tracing is on), then
+        the round's close, where the serve loop's ``turn`` opens again.
+        -> ``emitted``."""
+        if rnd is not None:
+            ph = ph.then("round.account")
+        occ = self.kv.occupancy_pct()
+        self._peak_occupancy = max(self._peak_occupancy, occ)
+        for group, unreleased in zip(
+                self.kv.groups, self.scheduler.unreleased_pages()):
+            group.note(unreleased)
+        alloc = self.kv.allocator
+        share = getattr(self.prefix, "share", None)
+        self.metrics.sample_state(
+            len(self.scheduler.active), self.scheduler.queue_depth(),
+            occ,
+            shared_pages=alloc.shared_pages() if self.prefix else None,
+            cached_pages=alloc.cached_pages if self.prefix else None,
+            remote_hits=share.remote_hits if share else None,
+            remote_hit_tokens=share.remote_hit_tokens
+            if share else None)
+        self._steps += 1
+        if rnd is not None:
+            at = rnd.close(ph.close())
+            if turn is not None:
+                turn.open(at)
+        return emitted
 
     # ------------------------------------------------------------- prefill
     def _finish_prompt(self, req, prompt, tok, logit=None):
@@ -766,13 +797,14 @@ class ServingEngine:
                       "decoding locally", file=sys.stderr, flush=True)
 
     # ------------------------------------------------------------ stepping
-    def step(self):
+    def step(self, _turn=None):
         """One scheduler round -> decode tokens emitted (0 when idle):
         admission, budgeted prefill chunks and every active row's decode
         token ride ONE flat launch of one program. A newcomer prefilling
         never stalls in-flight rows — the gap between two decode tokens
         is bounded by the chunk budget, not by the longest prompt in the
-        queue."""
+        queue. (``_turn`` is the serve loop's own: its open
+        ``serve.turn`` span.)"""
         if self._loop_error is not None:
             raise EngineClosed(
                 f"engine unhealthy: serve loop crashed with "
@@ -781,24 +813,7 @@ class ServingEngine:
         if self._closed:
             raise EngineClosed("engine is closed")
         with self._step_lock:
-            emitted = self._step_ragged()
-            occ = self.kv.occupancy_pct()
-            self._peak_occupancy = max(self._peak_occupancy, occ)
-            for group, unreleased in zip(
-                    self.kv.groups, self.scheduler.unreleased_pages()):
-                group.note(unreleased)
-            alloc = self.kv.allocator
-            share = getattr(self.prefix, "share", None)
-            self.metrics.sample_state(
-                len(self.scheduler.active), self.scheduler.queue_depth(),
-                occ,
-                shared_pages=alloc.shared_pages() if self.prefix else None,
-                cached_pages=alloc.cached_pages if self.prefix else None,
-                remote_hits=share.remote_hits if share else None,
-                remote_hit_tokens=share.remote_hit_tokens
-                if share else None)
-            self._steps += 1
-            return emitted
+            return self._step_ragged(_turn)
 
     def run_until_idle(self, max_steps=100000):
         steps = 0
@@ -974,21 +989,30 @@ class ServingEngine:
         # hit the site Nth would die)
         target = os.environ.get("PADDLE_TPU_FAULT_ENGINE")
         honored = target in (None, "") or target == str(self.engine_id)
+        # tracing on, the thread's time is tiled: every instant lies in a
+        # ``decode_round``, a ``serve.idle_wait`` or the ``serve.turn``
+        # between them, which the round closes and opens again itself
+        turn = None
         while not self._stop_evt.is_set():
             try:
+                # the loop's one tracing gate a turn, as the round's
+                tr = _trc._TR if _trc._loaded else _trc._load()
+                if tr is None:
+                    turn = None
+                elif turn is None or turn.buf is not tr:
+                    turn = _trc.phase(tr, "serve.turn").open()
                 if honored and _inject("serve_loop") == "engine_die":
                     raise RuntimeError(
                         "injected fault: engine_die@serve_loop")
                 if self.scheduler.has_work():
-                    self.step()
+                    self.step(turn)
                 else:
-                    # the loop's one tracing gate, as the round's
-                    tr = _trc._TR if _trc._loaded else _trc._load()
                     if tr is None:
                         self._wake.wait(0.02)
                     else:
-                        with _trc.phase(tr, "serve.idle_wait"):
-                            self._wake.wait(0.02)
+                        idle = turn.then("serve.idle_wait")
+                        self._wake.wait(0.02)
+                        turn.open(idle.close())
                     self._wake.clear()
             except Exception as e:
                 # a broken step is terminal, not a silent hang: fail every
@@ -1002,6 +1026,8 @@ class ServingEngine:
                       f"{type(e).__name__}: {e}", file=sys.stderr,
                       flush=True)
                 break
+        if turn is not None and turn.t0 is not None:
+            turn.close()
 
     def stop(self, timeout=10.0):
         self._stop_evt.set()
@@ -1202,6 +1228,10 @@ class ServingEngine:
             # each, so either over the rounds reads 1.0
             "round_uploads": self._uploads,
             "round_fetches": self._fetches,
+            # seconds this process has spent tracing, lowering and
+            # compiling (cache loads included) since its compile log began
+            # (observability/tracing.py compile_log), and the events
+            "compile": _trc.compile_totals(),
         }
         if self.prefix is not None:
             out.update({

@@ -246,6 +246,114 @@ def test_fused_step_no_per_step_eager_rng(monkeypatch):
     assert np.isfinite(float(loss.numpy()))
 
 
+STEP_PHASES = ["step.gather", "step.launch", "step.rebind"]
+
+
+def _xy():
+    rng = np.random.RandomState(1)
+    return (paddle.to_tensor(rng.randn(8, 16).astype("float32")),
+            paddle.to_tensor(rng.randn(8, 4).astype("float32")))
+
+
+def test_fused_step_records_train_step_and_its_phases():
+    """Tracing ON, a whole-step function records one ``train_step`` a call,
+    numbered in order, and inside it ``step.gather`` / ``step.launch`` /
+    ``step.rebind`` tiling it, each with the thread's CPU time; the first
+    call's trace, lowering and compile show inside its ``step.launch``."""
+    from paddle_tpu.observability import tracing
+    net, opt, step = _linear_step()
+    x, y = _xy()
+    buf = tracing.start()
+    try:
+        losses = [float(step(x, y).numpy()) for _ in range(4)]
+    finally:
+        tracing.stop()
+    assert losses[-1] < losses[0]
+    events = [e for e in buf.events if e.get("cat") == "step"]
+    steps = [e for e in events if e["name"] == "train_step"]
+    assert [e["args"] for e in steps] == [{"step": i} for i in range(4)]
+    eps = 1.0                            # us: float rounding of ts + dur
+    for st in steps:
+        inside = [e for e in events if e["name"] != "train_step"
+                  and e["args"]["step"] == st["args"]["step"]]
+        assert [e["name"] for e in inside] == STEP_PHASES
+        assert abs(inside[0]["ts"] - st["ts"]) <= eps
+        for a, b in zip(inside, inside[1:]):
+            assert abs(b["ts"] - (a["ts"] + a["dur"])) <= eps
+        assert abs(inside[-1]["ts"] + inside[-1]["dur"]
+                   - (st["ts"] + st["dur"])) <= eps
+        for e in inside + [st]:
+            assert 0.0 <= e["cpu_us"] <= e["dur"] + 100.0
+    # the time between two steps is the caller's: in no span
+    for a, b in zip(steps, steps[1:]):
+        assert b["ts"] >= a["ts"] + a["dur"] - eps
+    launch0 = next(e for e in events if e["name"] == "step.launch")
+    jit = [e for e in buf.events if e["name"].startswith("jit.")
+           and launch0["ts"] <= e["ts"] + eps
+           and e["ts"] + e["dur"] <= launch0["ts"] + launch0["dur"] + eps]
+    assert {"jit.trace", "jit.lower", "jit.compile"} <= \
+        {e["name"] for e in jit}
+    assert all(e["args"]["fun_name"] for e in jit)
+
+
+@pytest.mark.parametrize("profile", [False, True],
+                         ids=["all-off", "profile-only"])
+def test_fused_step_gate_off_structurally_zero_overhead(monkeypatch,
+                                                        profile):
+    """Buffer OFF: the step neither reads the thread's CPU clock nor
+    records anything, and with no profile running it allocates no phase
+    either (one gate a step is the whole budget). While a profile is being
+    taken the phases go to the annotation alone (the benchmark's training
+    runner starts the profiler and not the buffer)."""
+    import time
+    from paddle_tpu.jit import api
+    from paddle_tpu.observability import tracing
+    net, opt, step = _linear_step()
+    x, y = _xy()
+    step(x, y)
+    step(x, y)                           # fast memo armed
+    assert not tracing.enabled()
+    log, calls = [], {"thread_time_ns": 0, "add": 0, "phase": 0}
+
+    class Ann:
+        is_enabled = staticmethod(lambda: profile)
+
+        def __init__(self, name, **kw):
+            self.name = name
+            log.append((name, kw))
+
+        def __enter__(self):
+            pass
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    def count(key, inner=None):
+        def h(*a, **k):
+            calls[key] += 1
+            return inner(*a, **k) if inner else 0
+        return h
+
+    monkeypatch.setattr(api, "_Annotation", Ann)
+    monkeypatch.setattr(tracing, "_ANNOTATION", Ann)
+    monkeypatch.setattr(time, "thread_time_ns", count("thread_time_ns"))
+    monkeypatch.setattr(tracing.TraceBuffer, "add", count("add"))
+    monkeypatch.setattr(tracing, "phase", count("phase", tracing.phase))
+    n0 = step._steps_run
+    for _ in range(2):
+        assert np.isfinite(float(step(x, y).numpy()))
+    assert calls["thread_time_ns"] == calls["add"] == 0
+    if not profile:
+        assert calls["phase"] == 0 and log == []
+        return
+    assert calls["phase"] == 2 * 4       # the step and its three phases
+    opened = [e for e in log if e[0] != "exit"]
+    assert opened == [(name, {"step": n0 + i}) for i in range(2)
+                      for name in ["train_step"] + STEP_PHASES]
+    assert [e[1] for e in log if e[0] == "exit"] == \
+        [name for _ in range(2) for name in STEP_PHASES + ["train_step"]]
+
+
 def test_fused_step_rng_step_keys_advance():
     """A dropout step consumes randomness: consecutive steps must use
     DIFFERENT keys (the uint32 spec advances the generator), and two

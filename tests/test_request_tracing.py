@@ -192,7 +192,7 @@ def test_tracing_off_structurally_zero_overhead(tiny_model, monkeypatch):
     counting-dict convention. One module gate check per round is the
     entire budget."""
     calls = {"req_event": 0, "finish_request": 0, "add": 0,
-             "req_add": 0, "phase": 0}
+             "req_add": 0, "phase": 0, "thread_time_ns": 0}
 
     def count(key, ret=None):
         def h(*a, **k):
@@ -208,6 +208,8 @@ def test_tracing_off_structurally_zero_overhead(tiny_model, monkeypatch):
                         count("req_add"))
     # the round/phase primitive: constructing one is already a call
     monkeypatch.setattr(tracing, "phase", count("phase"))
+    # ... and the second clock of a phase is read behind the gate only
+    monkeypatch.setattr(time, "thread_time_ns", count("thread_time_ns", 0))
     from tests.test_serving import _engine
     eng = _engine(tiny_model)
     # direct-step path
@@ -222,7 +224,7 @@ def test_tracing_off_structurally_zero_overhead(tiny_model, monkeypatch):
     time.sleep(0.06)                 # the idle loop ticks a few times
     eng.close()
     assert calls == {"req_event": 0, "finish_request": 0, "add": 0,
-                     "req_add": 0, "phase": 0}
+                     "req_add": 0, "phase": 0, "thread_time_ns": 0}
 
 
 def test_phase_records_twice_from_one_call(monkeypatch):
@@ -230,7 +232,7 @@ def test_phase_records_twice_from_one_call(monkeypatch):
     annotation of the same name; lists ride as space-separated text
     (the profiler splits an annotation's arguments on commas), late
     arguments reach both, ``then`` hands the round on to the next phase,
-    and ``close(record=False)`` leaves the buffer alone."""
+    and a phase with no buffer goes to the annotation alone."""
     log = []
 
     class Ann:
@@ -259,7 +261,7 @@ def test_phase_records_twice_from_one_call(monkeypatch):
     rnd.close()
     with tracing.phase(buf, "serve.idle_wait"):
         pass
-    tracing.phase(buf, "decode_round", round=8).open().close(record=False)
+    tracing.phase(None, "decode_round", round=8).open().close()
     assert log == [
         ("init", "decode_round", {"round": 7}), ("enter", "decode_round"),
         ("init", "round.schedule", {"round": 7}),
@@ -282,15 +284,15 @@ def test_phase_records_twice_from_one_call(monkeypatch):
 
 
 ROUND_PHASES = ["round.schedule", "round.assemble", "round.launch",
-                "round.fetch", "round.emit"]
+                "round.fetch", "round.emit", "round.account"]
 
 
 def test_tracing_on_round_phases(tiny_model):
     """Tracing ON: every round yields ONE ``decode_round`` that says what
     it launched (``pad``, ``tokens``, ``row_lens``, ``kv_lens``,
-    ``round``) with its five phases nested inside it in order, and an
-    idle serve loop yields ``serve.idle_wait``; the traced twin stays
-    token-identical."""
+    ``round``) with its six phases nested inside it in order, and an
+    idle serve loop yields ``serve.idle_wait`` between ``serve.turn``s;
+    the traced twin stays token-identical."""
     from tests.test_serving import _engine
     prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3]
     eng = _engine(tiny_model)
@@ -343,7 +345,232 @@ def test_tracing_on_round_phases(tiny_model):
     idle = [e for e in events if e["name"] == "serve.idle_wait"]
     assert idle and all(e["dur"] <= 0.5e6 for e in idle)
     assert {e["name"] for e in events} == \
-        {"decode_round", "serve.idle_wait", *ROUND_PHASES}
+        {"decode_round", "serve.idle_wait", "serve.turn", *ROUND_PHASES}
+
+
+SERVE_SPANS = ("decode_round", "serve.idle_wait", "serve.turn")
+
+
+def _served(tiny_model, on_token=None, prompts=((3, 1, 4, 1, 5, 9, 2, 6),
+                                                 (2, 7, 1, 8))):
+    """An engine driven through ``_serve_loop`` with tracing on: idle
+    ticks, two bursts of requests with an idle stretch between them ->
+    (buffer, serve thread's id, the engine's stats)."""
+    from tests.test_serving import _engine
+    buf = tracing.start()
+    try:
+        eng = _engine(tiny_model)
+        eng.start()
+        time.sleep(0.05)                 # the loop idles before any work
+        for burst in (prompts, prompts[:1]):
+            reqs = [eng.submit(list(p), max_new_tokens=5,
+                               on_token=on_token) for p in burst]
+            for r in reqs:
+                assert len(r.result(60)) == 5
+            time.sleep(0.05)
+        tid = eng._thread.ident
+        stats = eng.stats()
+        eng.close()
+    finally:
+        tracing.stop()
+    return buf, tid, stats
+
+
+@pytest.fixture(scope="module")
+def served(tiny_model):
+    return _served(tiny_model)
+
+
+def test_serve_thread_spans_tile_its_time(served):
+    """Tracing ON, through ``_serve_loop``: from the first round's opening
+    to the last one's close every instant of the serve thread lies in a
+    ``decode_round``, a ``serve.idle_wait`` or the ``serve.turn`` between
+    them: no hole over 1 ms, holes under 2 % in sum, no overlap."""
+    buf, tid, _ = served
+    spans = sorted((e for e in buf.events if e["name"] in SERVE_SPANS),
+                   key=lambda e: e["ts"])
+    assert {e["tid"] for e in spans} == {tid}
+    rounds = [i for i, e in enumerate(spans) if e["name"] == "decode_round"]
+    assert len(rounds) >= 8
+    spans = spans[rounds[0]:rounds[-1] + 1]
+    assert {e["name"] for e in spans} == set(SERVE_SPANS)
+    whole = spans[-1]["ts"] + spans[-1]["dur"] - spans[0]["ts"]
+    holes = [b["ts"] - (a["ts"] + a["dur"]) for a, b in zip(spans, spans[1:])]
+    eps = 1.0                            # us: float rounding of ts + dur
+    assert min(holes) >= -eps            # disjoint on the thread
+    assert max(holes) <= 1e3
+    assert sum(h for h in holes if h > 0) <= 0.02 * whole
+    # a turn is what lies between two of the others, never beside a turn
+    for a, b in zip(spans, spans[1:]):
+        assert (a["name"] == "serve.turn") != (b["name"] == "serve.turn")
+
+
+def test_six_phases_tile_their_round(served):
+    """The six ``round.*`` phases follow one another from their round's
+    opening to its close with no hole, and ``round.account`` is the
+    last."""
+    buf, _, stats = served
+    events = [e for e in buf.events if e.get("cat") == "serving"]
+    rounds = [e for e in events if e["name"] == "decode_round"]
+    assert len(rounds) == stats["steps"]
+    eps = 1.0
+    for r in rounds:
+        inside = [c for c in events if c["name"].startswith("round.")
+                  and c["args"]["round"] == r["args"]["round"]]
+        assert [c["name"] for c in inside] == ROUND_PHASES
+        assert abs(inside[0]["ts"] - r["ts"]) <= eps
+        for a, b in zip(inside, inside[1:]):
+            assert abs(b["ts"] - (a["ts"] + a["dur"])) <= eps
+        assert abs(inside[-1]["ts"] + inside[-1]["dur"]
+                   - (r["ts"] + r["dur"])) <= eps
+        assert abs(sum(c["dur"] for c in inside) - r["dur"]) <= 6 * eps
+
+
+@pytest.mark.parametrize("name", SERVE_SPANS + tuple(ROUND_PHASES))
+def test_every_phase_records_its_cpu_time(served, name):
+    """Every ``round.*`` / ``serve.*`` record (and the round's own) has
+    ``cpu_us``, the thread's CPU time between the same two instants: never
+    negative and at most the duration plus the clocks' resolution; a wait
+    is off the CPU."""
+    buf, _, _ = served
+    mine = [e for e in buf.events if e["name"] == name]
+    assert mine and all("cpu_us" in e for e in mine)
+    slack = 1e6 * time.get_clock_info("thread_time").resolution + 50.0
+    for e in mine:
+        assert 0.0 <= e["cpu_us"] <= e["dur"] + slack
+    if name == "serve.idle_wait":
+        waits = [e for e in mine if e["dur"] >= 15e3]
+        assert waits and all(e["cpu_us"] < 0.5 * e["dur"] for e in waits)
+    if name == "decode_round":
+        for r in mine:
+            inside = [c for c in buf.events if c["name"].startswith("round.")
+                      and c["args"]["round"] == r["args"]["round"]]
+            assert abs(sum(c["cpu_us"] for c in inside) - r["cpu_us"]) <= 1.0
+
+
+def test_a_round_with_nothing_to_launch_is_recorded(tiny_model):
+    """A round that finds nothing to launch leaves its time under a named
+    span in the buffer too: a ``decode_round`` that says no ``pad``, with
+    the phases it went through, and the step counter moves on."""
+    from tests.test_serving import _engine
+    buf = tracing.start()
+    try:
+        eng = _engine(tiny_model)
+        assert eng.step() == 0
+        assert eng.generate([1, 2, 3], max_new_tokens=2)
+        eng.close()
+    finally:
+        tracing.stop()
+    events = [e for e in buf.events if e.get("cat") == "serving"]
+    rounds = [e for e in events if e["name"] == "decode_round"]
+    assert [e["args"]["round"] for e in rounds] == list(range(len(rounds)))
+    assert rounds[0]["args"] == {"round": 0}
+    assert [e["name"] for e in events if e["name"].startswith("round.")
+            and e["args"]["round"] == 0] == \
+        ["round.schedule", "round.assemble", "round.account"]
+    assert all("pad" in e["args"] for e in rounds[1:])
+    # step() drives rounds from any thread: no serve loop, no serve.turn
+    assert not [e for e in events if e["name"].startswith("serve.")]
+
+
+def test_a_stall_names_its_cause(tiny_model):
+    """While the buffer is on a collection is one ``host.gc`` event on the
+    thread it ran in (inside the phase it held up), and a first launch at
+    a new token pad shows as ``jit.trace`` / ``jit.lower`` / ``jit.compile``
+    of the round's program inside that round's ``round.launch``."""
+    import gc
+    import threading
+    fired = []
+
+    def on_token(req, tok, finished):
+        if not fired:
+            fired.append(threading.get_ident())
+            gc.collect()                 # on the serve thread, in round.emit
+
+    buf, tid, _ = _served(tiny_model, on_token=on_token)
+    assert fired == [tid]
+    buf2 = tracing.start()
+    gc.collect(1)                        # ... and one on this thread
+    tracing.stop()
+    assert tracing._on_gc not in gc.callbacks      # out with the buffer
+    here = [e for e in buf2.events if e["name"] == "host.gc"]
+    assert [(e["tid"], e["args"]["generation"]) for e in here] == \
+        [(threading.get_ident(), 1)]
+    full = [e for e in buf.events if e["name"] == "host.gc"
+            and e["args"]["generation"] == 2]
+    assert len(full) == 1 and full[0]["tid"] == tid
+    assert set(full[0]["args"]) == {"generation", "collected"}
+
+    def inside(ev, name):
+        return [p for p in buf.events if p["name"] == name
+                and p["tid"] == ev["tid"] and p["ts"] <= ev["ts"] + 1.0
+                and ev["ts"] + ev["dur"] <= p["ts"] + p["dur"] + 1.0]
+
+    assert inside(full[0], "round.emit")
+    # the engine never warmed its pads: each pad's first round compiles
+    # (jax names the trace ``round_step``, the rest ``jit(round_step)``)
+    jit = [e for e in buf.events if e["name"].startswith("jit.")
+           and "round_step" in e["args"]["fun_name"]]
+    assert {e["name"] for e in jit} == {"jit.trace", "jit.lower",
+                                        "jit.compile"}
+    pads = {r["args"]["pad"] for r in buf.events
+            if r["name"] == "decode_round"}
+    for kind in ("jit.trace", "jit.lower", "jit.compile"):
+        mine = [e for e in jit if e["name"] == kind]
+        assert len(mine) == len(pads)
+        for e in mine:
+            assert e["tid"] == tid and e["cat"] == "jit"
+            assert inside(e, "round.launch")
+
+
+def test_compile_log_grows_at_a_compile_and_not_at_a_cached_call(
+        tiny_model):
+    """``stats()["compile"]`` totals the always-on compile log (tracing
+    OFF here): a first launch at a token pad adds a trace, a lowering and
+    a compile of ``round_step``; a launch at a pad already served adds
+    nothing."""
+    from tests.test_serving import _engine
+    assert not tracing.enabled()
+    eng = _engine(tiny_model)
+    before = eng.stats()["compile"]
+    assert set(before) == {"trace_s", "lower_s", "compile_s", "events"}
+    t0 = time.perf_counter()
+    eng.generate([1, 2, 3, 4, 5], max_new_tokens=3)     # pads 8 and... 8
+    first = eng.stats()["compile"]
+    log = [e for e in tracing.compile_log()
+           if e[0] >= t0 and "round_step" in e[2]]
+    assert sorted(e[1] for e in log) == ["jit.compile", "jit.lower",
+                                         "jit.trace"]
+    assert all(e[3] > 0.0 and e[0] <= time.perf_counter() for e in log)
+    assert first["events"] >= before["events"] + 3
+    for key in ("trace_s", "lower_s", "compile_s"):
+        assert first[key] > before[key]
+    eng.generate([5, 4, 3, 2, 1], max_new_tokens=3)     # the same pad
+    assert eng.stats()["compile"] == first
+    eng.close()
+
+
+def test_buffer_at_its_cap_keeps_the_newest(monkeypatch, tmp_path):
+    """At the cap the OLDEST events go (an eighth of the cap at a time)
+    and the newest stay, so a process left tracing holds the minutes
+    before a stall; the export says how many went, and a reader that
+    iterates ``events`` sees ordinary events only."""
+    monkeypatch.setattr(tracing, "_MAX_EVENTS", 64)
+    buf = tracing.start(path=str(tmp_path / "t.json"), rank=0)
+    for i in range(200):
+        buf.add(f"ev{i}", float(i), 0.5)
+    names = [e["name"] for e in buf.events if e.get("ph") == "X"]
+    assert len(names) == len(buf.events)          # no marker among them
+    assert 64 - 8 <= len(names) <= 64
+    assert names == [f"ev{i}" for i in range(200 - len(names), 200)]
+    assert buf.dropped == 200 - len(names)
+    doc = json.loads(open(tracing.stop()).read())
+    marks = [e for e in doc["traceEvents"]
+             if e.get("name") == "trace_truncated"]
+    assert len(marks) == 1 and marks[0]["ph"] == "M"
+    assert marks[0]["args"]["at_events"] == 64
+    assert marks[0]["args"]["dropped"] == doc["droppedEvents"] == buf.dropped
+    assert doc["traceEvents"][-1]["name"] == "ev199"
 
 
 def test_tracing_on_greedy_parity(tiny_model, tmp_path):

@@ -163,6 +163,55 @@ def test_a_round_uploads_once_and_fetches_once(models, family, traced,
                 == rounds
 
 
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_the_serve_loops_spans_tile_its_thread(models, family):
+    """Through ``_serve_loop`` with tracing on, every kind of engine: the
+    serve thread's ``decode_round`` / ``serve.idle_wait`` / ``serve.turn``
+    follow one another with no hole from the first round to the last, a
+    round's six phases tile it, and the round still says what it
+    launched."""
+    import time
+    eng = _engine(models, family)
+    spans = tracing.start()
+    try:
+        eng.start()
+        time.sleep(0.03)
+        reqs = [eng.submit(IDS[a:b].tolist(), max_new_tokens=4)
+                for a, b in ((0, 21), (30, 35))]
+        for r in reqs:
+            assert len(r.result(120)) == 4
+        time.sleep(0.03)
+        tid = eng._thread.ident
+        rounds = eng.stats()["steps"]
+        eng.close()
+    finally:
+        tracing.stop()
+    tiles = ("decode_round", "serve.idle_wait", "serve.turn")
+    mine = sorted((e for e in spans.events if e["tid"] == tid
+                   and (e["name"] in tiles
+                        or e["name"].startswith("round."))),
+                  key=lambda e: e["ts"])
+    top = [e for e in mine if e["name"] in tiles]
+    assert {e["name"] for e in top} == set(tiles)
+    eps = 1.0                            # us: float rounding of ts + dur
+    for a, b in zip(top, top[1:]):
+        assert abs(b["ts"] - (a["ts"] + a["dur"])) <= eps, (a, b)
+    got = [e for e in top if e["name"] == "decode_round"]
+    assert [e["args"]["round"] for e in got] == list(range(rounds))
+    for r in got:
+        inside = [e for e in mine if e["name"].startswith("round.")
+                  and e["args"]["round"] == r["args"]["round"]]
+        assert [e["name"] for e in inside] == [
+            "round.schedule", "round.assemble", "round.launch",
+            "round.fetch", "round.emit", "round.account"]
+        assert abs(sum(e["dur"] for e in inside) - r["dur"]) <= 6 * eps
+        assert r["args"]["tokens"] == sum(r["args"]["row_lens"]) \
+            <= r["args"]["pad"]
+        assert all("cpu_us" in e for e in inside + [r])
+    if family != "gpt":                  # the layers' reports, a round each
+        assert sum(e["name"] == "moe.route" for e in spans.events) == rounds
+
+
 def test_sampling_and_captured_logits_ride_the_same_fetch(models,
                                                           monkeypatch):
     """What a round reads only sometimes (the logit rows, for a request

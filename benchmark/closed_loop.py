@@ -1,11 +1,23 @@
 """Runner for serving cells of kind ``closed_loop``: every client sends
 its next request from the completion callback of its last (see serve.py's
 definitions).
+
+A client never runs out of requests whatever the engine's speed: its
+queue is ``requests_per_client`` requests a *block*, block after block
+(``traffic.closed_loop_pool``). Block 0 is the frozen sequence every run
+to date has sent; the blocks are made before the window opens, as many as
+an engine whose mean round is ``FLOOR_ROUND_S`` could use up between the
+loop's start and the window's end.
 """
+import math
 import threading
 import time
 
 from . import serve, traffic
+from .harness import say
+
+# the fastest mean round the pool is made for; under it the guard speaks
+FLOOR_ROUND_S = 0.002
 
 KEYS = {"": serve.KEYS[""] | {"clients", "requests_per_client",
                               "stagger_first", "ramp_timeout_s"},
@@ -18,15 +30,24 @@ def run(run, fam, tracer, t_process):
 
 def _loop(run, eng, tracer, t_process):
     wl, cfg = run.cell.workload, run.cell.config
-    clients = traffic.closed_loop_clients(
-        wl, cfg["vocab_size"], run.seed, int(wl["requests_per_client"]))
-    n = len(clients)
+    per = int(wl["requests_per_client"])
+    # a client takes one token a round; the first request may be cut
+    rounds = math.ceil((run.seconds + float(wl["ramp_timeout_s"]))
+                       / FLOOR_ROUND_S)
+    t0 = time.perf_counter()
+    blocks = traffic.closed_loop_pool(
+        wl, cfg["vocab_size"], run.seed, per,
+        rounds + traffic.longest(wl["output_len"]))
+    n = len(blocks[0])
+    say(f"closed loop: {len(blocks)} blocks of {n} x {per} requests made "
+        f"in {time.perf_counter() - t0:.2f} s, enough for {rounds} rounds "
+        f"a client (a mean round of {1e3 * FLOOR_ROUND_S:g} ms)")
     if wl.get("stagger_first"):
         # a closed loop in its steady state holds requests at every stage
         # of their output; cut each client's first request to a different
         # share of its length so the window opens on that mix and not on
         # n requests in step
-        for i, q in enumerate(clients):
+        for i, q in enumerate(blocks[0]):
             q[0] = dict(q[0], max_new_tokens=max(
                 2, int(q[0]["max_new_tokens"] * (i + 0.5) / n)))
     sent, lock = [], threading.Lock()
@@ -35,12 +56,15 @@ def _loop(run, eng, tracer, t_process):
 
     def send(c):
         i = cursor[c]
-        if i >= len(clients[c]):
-            state["errors"].append(f"client {c} ran out of requests")
+        if i >= per * len(blocks):
+            state["errors"].append(
+                f"client {c} ran out of requests after {len(blocks)} "
+                f"blocks of {per}")
             return
         cursor[c] = i + 1
         now = time.perf_counter()
-        req = serve.submit(eng, clients[c][i], on_done=lambda r, c=c: done(c))
+        req = serve.submit(eng, blocks[i // per][c][i % per],
+                           on_done=lambda r, c=c: done(c))
         with lock:
             sent.append(serve.Sent(req, now, now, c))
 
@@ -80,4 +104,7 @@ def _loop(run, eng, tracer, t_process):
         sent = list(sent)
     if state["errors"]:
         raise RuntimeError(f"closed loop: {state['errors'][:3]}")
+    say(f"closed loop: the furthest client sent {max(cursor)} requests, so "
+        f"the run entered {-(-max(cursor) // per)} of its {len(blocks)} "
+        "blocks")
     return sent, t_open, t_close, host

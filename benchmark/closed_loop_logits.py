@@ -28,7 +28,7 @@ all positions at serve.py's first call and remembered.
 import numpy as np
 
 from . import closed_loop, serve
-from .harness import median, pct, say
+from .harness import median, pct, say, within
 
 KEYS = {"": closed_loop.KEYS[""],
         "correct": closed_loop.KEYS["correct"] | {
@@ -112,9 +112,11 @@ def _check_logits(run, ref, tol):
         return
     flips = sum(d > float(tol["logit_abs"]) for d in off) / len(off)
     med, worst = median(off), max(off)
-    ok = med <= float(tol["logit_median_abs"]) \
-        and flips <= float(tol["flip_share"]) \
-        and worst <= float(tol["logit_flip_abs"])
+    ok = all([
+        within(run, "logit_diff_median", med, float(tol["logit_median_abs"])),
+        within(run, "logit_diff_flip_share", flips, float(tol["flip_share"])),
+        within(run, "logit_diff_largest", worst,
+               float(tol["logit_flip_abs"]))])
     say(f"emitted logits against the reference at {len(off)} positions of "
         f"{checked} requests: |difference| median {med:.5f} (limit "
         f"{tol['logit_median_abs']}), p90 {pct(off, 90):.5f}, p99 "

@@ -205,6 +205,8 @@ class Run:
     attempted: int = 0
     failed: int = 0
     correct: bool = False
+    # every number ``correct`` compared: short name -> (value, limit)
+    compared: dict = dataclasses.field(default_factory=dict)
     # training
     step_s: list = dataclasses.field(default_factory=list)
     losses: list = dataclasses.field(default_factory=list)
@@ -217,6 +219,13 @@ class Run:
     # device trace (``--trace 1`` only): trace_reduce.Summary or None
     trace: object = None
     pallas_ops: set = dataclasses.field(default_factory=set)
+
+
+def within(run, name, value, limit):
+    """``value <= limit``, noted under ``name`` for the result line's
+    ``compared`` and the last lines of standard error."""
+    run.compared[name] = (float(value), float(limit))
+    return value <= limit
 
 
 def pct(values, q):
@@ -318,6 +327,9 @@ def result_line(run, devs, trace_on):
         device["window_s"] = run.trace.window_s
         out["breakdown"] = {"device_ops": run.trace.top_ops[:10],
                             "idle_gaps": run.trace.top_gaps[:10]}
+    # last: each number ``correct`` compared, beside its limit
+    out["compared"] = {k: {"value": v, "limit": lim}
+                       for k, (v, lim) in run.compared.items()}
     return out
 
 

@@ -26,8 +26,12 @@ the instruction's).
   thread that overlap it. The phases of one thread never overlap; the part
   of a ``decode_round`` that none of its phases covers goes to
   ``decode_round`` itself, time no span covers to ``unattributed``.
-* a round's program: the ``XLA Modules`` event that starts nearest to the
-  close of that round's ``round.launch`` (programs lie tens of ms apart).
+* a round's program: the chip runs programs in the order they were
+  launched, so rounds and programs are joined *by order* under two bounds
+  of causality (a program starts no earlier than its ``round.launch``
+  opened and ends no later than the ``round.fetch`` of the same round
+  closed), whether or not a round waits for its program before the next
+  is launched. ``round_programs`` says how, and what is checked.
 * the clocks: the device planes' clock was found to run behind the host
   plane's by 0.3 to 1.3 ms, another amount in every trace (v5e, PR 27: a
   program "started" before the runtime had enqueued it). Causality
@@ -35,21 +39,24 @@ the instruction's).
   events: a program starts after its ``DoEnqueueProgram`` (inside
   ``round.launch``) and ends before its ``tpu::System::Execute=>Done``
   (inside ``round.fetch``). The lag is taken as the middle of the largest
-  lower and the smallest upper bound over the rounds, and the serve
-  thread's spans are moved onto the device's clock by it; without those
-  events (another runtime) the clocks are taken as one, and the
-  ``[bench]`` line says so.
+  lower and the smallest upper bound over the rounds (and no further than
+  0.5 ms under the upper one: where programs queue behind one another the
+  lower bound is loose), and the serve thread's spans are moved onto the
+  device's clock by it; without those events (another runtime) the clocks
+  are taken as one, and the ``[bench]`` line says so.
 
 A program that lacks the annotations or the kernel names (the parent of
 the PR that added them) gives nothing to read: every function here then
 returns ``None`` or an empty result and never raises.
 """
 import bisect
+import collections
 import dataclasses
 import functools
 import os
 
 from . import trace_reduce
+from .harness import say
 
 HOST_PLANE = "/host:CPU"
 MODULE_LINE = "XLA Modules"
@@ -59,6 +66,10 @@ FETCH = "round.fetch"
 ENQUEUED, DONE = "DoEnqueueProgram", "tpu::System::Execute=>Done"
 PHASE_PREFIXES = ("round.", "serve.")
 UNATTRIBUTED = "unattributed"
+# a program "near" a launch's close (no clock is that far off), and what
+# the two clocks may differ by before and after they are brought together
+NEAR_NS, SLACK_APART_NS, SLACK_ONE_CLOCK_NS = 5e6, 3e6, 1e6
+LAG_WIDTH_NS = 1e6
 FLASH, RAGGED = "flash_attention", "ragged_paged_attention"
 
 
@@ -91,6 +102,8 @@ class HostTrace:
     # bounds found (None without the runtime's events), the value applied
     lag_bounds: tuple = None
     lag_ns: float = 0.0
+    # round_programs' answers by chip, made once
+    joins: dict = dataclasses.field(default_factory=dict)
 
     @property
     def window_ns(self):
@@ -154,10 +167,16 @@ def load(path):
     ht.lag_bounds = clock_lag(ht, sorted(enqueued), sorted(done))
     if ht.lag_bounds is not None:
         low, up = ht.lag_bounds
-        ht.lag_ns = (low + up) / 2 if low <= up else low
+        # a program that queues behind another starts long after it was
+        # enqueued: the lower bound is then loose and the middle wrong, so
+        # the lag is taken no further under the upper bound than the two
+        # lay apart where every round waited for its program (0.3-0.5 ms)
+        ht.lag_ns = max((low + up) / 2, up - LAG_WIDTH_NS / 2) \
+            if low <= up else low
         for s in serve:         # onto the device's clock
             s.start -= ht.lag_ns
             s.end -= ht.lag_ns
+        ht.joins.clear()        # made on two clocks; make them on one
     return ht
 
 
@@ -239,26 +258,111 @@ def _by_round(ht, name):
     return {s.stats.get("round"): s for s in ht.serve if s.name == name}
 
 
+def _stem(program):
+    """``jit_rstep(1205857459157590206)`` -> ``jit_rstep``: one jitted
+    function's programs differ in the number alone (one a token pad)."""
+    return program.split("(", 1)[0]
+
+
 def round_programs(ht, chip):
     """[(decode_round Span, (program start, end))] in the order of the
     rounds: every round that says what it launched, joined to the program
-    that launch caused on ``chip``: the one that starts nearest to the
-    launch's close, and within 5 ms of it (no clock is that far off, and
-    no two programs lie that close)."""
-    launches = _by_round(ht, LAUNCH)
-    starts = [m[1] for m in chip.modules]
-    out = []
-    for r in ht.serve:
-        launch = launches.get(r.stats.get("round"))
-        if r.name != ROUND or "pad" not in r.stats or launch is None \
-                or not starts:
+    that launch caused on ``chip``. The join goes by order, whether or not
+    a round's program has ended before the next round is launched:
+
+    * anchors: a launch made with the device idle (the round before it
+      had been fetched) at whose close exactly one program starts within
+      ``NEAR_NS``, that program near no other such launch's close: the
+      rule this module had until PR 38, which every round but the first
+      of an engine that fetches before it launches again meets. A program
+      that is no round's (the reference's forward, a warm-up) is told by
+      its name: only programs of the anchors' jitted functions are
+      candidates (of the most frequent one where nothing anchors);
+    * from the last round back, a round takes the latest candidate not yet
+      taken that ends before the round's ``round.fetch`` closed and starts
+      after its ``round.launch`` opened, each with the slack the clocks
+      need. A launch or a program cut by the stretch's edge finds no
+      partner and is left out;
+    * checked: between the first and the last joined pair no launch and no
+      candidate is left over, and every anchor is joined to its program.
+      Where that fails the chip gives NO join and a ``[bench]`` line says
+      so: the seven readers that stand on this then read nothing, rather
+      than a wrong round's numbers."""
+    if chip.name not in ht.joins:
+        ht.joins[chip.name] = _join(ht, chip)
+    return ht.joins[chip.name]
+
+
+def _join(ht, chip):
+    launches, fetches = _by_round(ht, LAUNCH), _by_round(ht, FETCH)
+    # a round whose fetch is not on record (cut by the stretch's edge) has
+    # no upper bound and takes no part
+    rounds = [r for r in ht.serve if r.name == ROUND and "pad" in r.stats
+              and r.stats.get("round") in launches
+              and r.stats.get("round") in fetches]
+    mods = chip.modules
+    if not rounds or not mods:
+        return []
+    if ht.lag_bounds is not None and \
+            ht.lag_bounds[0] - ht.lag_bounds[1] > LAG_WIDTH_NS / 2:
+        # bounds taken from a join on two clocks; a join that is off by a
+        # round has programs start before they were enqueued
+        say(f"rounds and programs on {chip.name} do NOT join by order: "
+            f"joined before the clocks were brought together, a program "
+            f"starts {ht.lag_bounds[0] / 1e6:.3f} ms before the runtime "
+            f"enqueued it by the lower bound of the clocks' lag and ends "
+            f"{ht.lag_bounds[1] / 1e6:.3f} ms before the runtime heard of "
+            "it by the upper: the bounds contradict each other, so some "
+            "round was joined to another round's program. No round is "
+            "joined on this chip")
+        return []
+    slack = SLACK_APART_NS if ht.lag_bounds is None or not ht.lag_ns \
+        else SLACK_ONE_CLOCK_NS
+    starts = [m[1] for m in mods]
+    near = {}
+    for k in range(1, len(rounds)):
+        # launched with the device idle: the round before has been fetched
+        launch = launches[rounds[k].stats["round"]]
+        if fetches[rounds[k - 1].stats["round"]].end > launch.start:
             continue
-        i = bisect.bisect_left(starts, launch.end)
-        i = min((j for j in (i - 1, i) if 0 <= j < len(starts)),
-                key=lambda j: abs(starts[j] - launch.end))
-        if abs(starts[i] - launch.end) <= 5e6:
-            out.append((r, chip.modules[i][1:]))
-    return out
+        lo = bisect.bisect_left(starts, launch.end - NEAR_NS)
+        if bisect.bisect_right(starts, launch.end + NEAR_NS) - lo == 1:
+            near[k] = lo
+    claimed = collections.Counter(near.values())
+    anchors = {k: j for k, j in near.items() if claimed[j] == 1}
+    stems = {_stem(mods[j][0]) for j in anchors.values()} or {
+        collections.Counter(_stem(m[0]) for m in mods).most_common(1)[0][0]}
+    cand = [j for j, m in enumerate(mods) if _stem(m[0]) in stems]
+    match, c = {}, len(cand) - 1
+    for k in range(len(rounds) - 1, -1, -1):
+        n = rounds[k].stats["round"]
+        while c >= 0 and mods[cand[c]][2] > fetches[n].end + slack:
+            c -= 1              # ends too late for this and every earlier
+        if c >= 0 and mods[cand[c]][1] >= launches[n].start - slack:
+            match[k] = cand[c]
+            c -= 1
+    if not match:
+        return []
+    taken = set(match.values())
+    left_rounds = [rounds[k].stats["round"]
+                   for k in range(min(match), max(match)) if k not in match]
+    left_progs = [j for j in cand
+                  if min(taken) < j < max(taken) and j not in taken]
+    off = [rounds[k].stats["round"] for k, j in anchors.items()
+           if match.get(k) != j]
+    if left_rounds or left_progs or off:
+        say(f"rounds and programs on {chip.name} do NOT join by order: "
+            f"between the first and the last joined pair "
+            f"{len(left_rounds)} launch(es) found no program (rounds "
+            f"{left_rounds[:5]}) and {len(left_progs)} program(s) no launch "
+            f"(starting at {[mods[j][1] for j in left_progs[:5]]} ns); "
+            f"{len(off)} round(s) whose launch has one program near its "
+            f"close joined another (rounds {off[:5]}); {len(rounds)} "
+            f"launches, {len(cand)} of {len(mods)} programs candidates. No "
+            "round is joined on this chip, so the readers that need the "
+            "join read nothing")
+        return []
+    return [(rounds[k], mods[match[k]][1:]) for k in sorted(match)]
 
 
 def clock_lag(ht, enqueued, done):

@@ -5,7 +5,9 @@
 Refuses (exit code 2, no result line) without a TPU, with fewer chips than
 the cell asks for, or where the program under test is not beside it.
 Earlier lines of standard output are information, each starting
-``[bench]``; the last line is the result, one JSON object.
+``[bench]``; the last line is the result, one JSON object. Its last key,
+``compared``, holds each number that ``correct`` compared beside its limit;
+the same are the last lines of standard error.
 """
 import time
 
@@ -26,6 +28,10 @@ def main(argv=None):
     from benchmark import harness
     line = harness.run_cell(args.workload, args.seed, args.seconds,
                             bool(args.trace), t_process=T_PROCESS)
+    for name, c in line["compared"].items():
+        print(f"[bench] compared {name}: {c['value']!r}, limit "
+              f"{c['limit']!r}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

@@ -7,7 +7,8 @@ from benchmark import harness
 
 from .conftest import STANDS_FOR, cpu_devices
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -31,6 +32,10 @@ def test_end_to_end_line(layout, cell):
     assert set(line["metrics"]) == _declared(layout, cell, "end_to_end")
     for m in line["metrics"].values():
         assert set(m) == {"value", "unit"} and m["value"] > 0
+    # last, each number ``correct`` compared beside its limit
+    assert list(line)[-1] == "compared" and line["compared"]
+    for c in line["compared"].values():
+        assert set(c) == {"value", "limit"} and c["value"] <= c["limit"]
     json.dumps(line)
 
 

@@ -7,6 +7,9 @@ from benchmark import harness
 
 from .conftest import cpu_devices
 
+BOUNDS = {"train_tokens_per_s": 0.015, "serve_tokens_per_s": 0.08,
+          "itl_p99_ms": 0.06, "ttft_p90_ms": 0.035, "setup_s": 0.1}
+
 
 def test_add_config_cell_and_metric(layout):
     data = layout.data
@@ -79,6 +82,8 @@ def test_real_files_agree_with_benchmark_json():
     with open(layout.bench_json) as f:
         bench = json.load(f)
     e2e = {m["name"] for m in bench["end_to_end"]}
+    # the bounds as PR 38 set them (PERF.md section 2 has the runs)
+    assert {m["name"]: m["bound"] for m in bench["end_to_end"]} == BOUNDS
     for w in bench["workloads"]:
         cell = harness.load_cell(w["name"], layout)
         assert cell.workload["config"] == w["config"]
@@ -91,10 +96,17 @@ def test_real_files_agree_with_benchmark_json():
             cfg = json.load(f)
         assert cfg["reduced"] == c["reduced"] and cfg["source"] == c["source"]
         pub = cfg["published"]
+        # a width the source states is run as stated; which widths a
+        # source states is the family's (the GPT table gives heads x
+        # head size = hidden and an unreduced context; the three MoE
+        # configurations' own tests hold them to their sources'
+        # config.json: test_new_kinds, test_afmoe_cell, test_kda_cell)
         for key in ("hidden_size", "num_heads", "head_dim",
                     "intermediate_size", "max_seq_len"):
-            assert cfg[key] == pub[key], (c["name"], key)
-        assert cfg["hidden_size"] == cfg["num_heads"] * cfg["head_dim"]
+            if key in pub or cfg["family"] == "gpt":
+                assert cfg[key] == pub[key], (c["name"], key)
+        if cfg["family"] == "gpt":
+            assert cfg["hidden_size"] == cfg["num_heads"] * cfg["head_dim"]
         changed = {k for k in pub if k in cfg and cfg[k] != pub[k]}
         assert changed <= set(cfg["reduced"]) | set(cfg["assumed"])
     for m in bench["per_layer"]:

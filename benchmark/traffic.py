@@ -23,10 +23,12 @@ import numpy as np
 SCHEDULE_SEED = 0
 
 
-def rng_for(seed, stream):
-    """Independent generator per purpose; ``seed`` may exceed 2**31."""
-    return np.random.default_rng([int(seed) & 0xFFFFFFFFFFFFFFFF,
-                                  int(stream)])
+def rng_for(seed, stream, block=0):
+    """Independent generator per purpose; ``seed`` may exceed 2**31. A
+    closed loop's block k > 0 has generators of its own; block 0's are
+    the two-word keys every run to date was drawn from."""
+    key = [int(seed) & 0xFFFFFFFFFFFFFFFF, int(stream)]
+    return np.random.default_rng(key + [int(block)] if block else key)
 
 
 # ------------------------------------------------------------- lengths
@@ -41,6 +43,12 @@ def _quantile(dist, u):
     else:
         raise ValueError(f"unknown length distribution {kind!r}")
     return int(round(x))
+
+
+def longest(dist):
+    """The largest length ``dist`` can give."""
+    return int(dist["value"]) if dist["dist"] == "fixed" \
+        else _quantile(dist, 1.0)
 
 
 def stratified_lengths(dist, n, rng):
@@ -97,13 +105,50 @@ def open_loop_schedule(spec, vocab, seed, seconds):
     return [(float(t), r) for t, r in zip(due, reqs) if t < seconds]
 
 
-def closed_loop_clients(spec, vocab, seed, per_client):
+def closed_loop_clients(spec, vocab, seed, per_client, block=0):
     """``spec['clients']`` queues of ``per_client`` requests each, dealt
     round-robin from the one sequence, so no client is all-long or
-    all-short."""
+    all-short: one *block* of a closed loop's pool. A client that ends
+    its queue of block k goes on into its queue of block k + 1.
+
+    Block 0 is ``request_stream`` over ``clients x per_client`` requests,
+    the frozen sequence of every run to date. Block k > 0 is the same
+    stratified set of lengths in a further frozen order (generators keyed
+    by ``k``: ``request_stream`` stratifies over its ``n``, so a longer
+    stream would have changed what block 0 sends); its prompts are
+    ``int32`` arrays cut from one draw, not lists, since a pool of many
+    blocks holds tens of millions of ids."""
     c = int(spec["clients"])
-    reqs = request_stream(spec, vocab, seed, c * per_client)
+    n = c * per_client
+    if not block:
+        reqs = request_stream(spec, vocab, seed, n)
+    else:
+        base = rng_for(SCHEDULE_SEED, 1, block)
+        plens = stratified_lengths(spec["prompt_len"], n, base)
+        olens = stratified_lengths(spec["output_len"], n, base)
+        ids = rng_for(seed, 1, block).integers(
+            0, vocab, size=sum(plens), dtype=np.int32)
+        cuts = np.cumsum([0] + plens)
+        reqs = [{"prompt": ids[a:b], "max_new_tokens": o}
+                for a, b, o in zip(cuts, cuts[1:], olens)]
     return [reqs[i::c] for i in range(c)]
+
+
+def closed_loop_pool(spec, vocab, seed, per_client, tokens_a_client):
+    """Blocks 0, 1, ... of ``closed_loop_clients`` until every client's
+    queues hold ``tokens_a_client`` output tokens (a closed loop gives a
+    client one token a round, so that is a count of rounds): -> list of
+    blocks. What a block holds depends on the mix, the seed and its number
+    alone, and how many are made on the mix and ``tokens_a_client``:
+    nothing a run observes."""
+    c = int(spec["clients"])
+    held, blocks = [0] * c, []
+    while min(held) < tokens_a_client:
+        blocks.append(closed_loop_clients(spec, vocab, seed, per_client,
+                                          len(blocks)))
+        for i, q in enumerate(blocks[-1]):
+            held[i] += sum(r["max_new_tokens"] for r in q)
+    return blocks
 
 
 # ----------------------------------------------------------- training

@@ -9,7 +9,7 @@ import math
 import time
 
 from . import traffic
-from .harness import median, say
+from .harness import median, say, within
 
 # keys of a training mix's file that some code reads (prose apart)
 KEYS = {"": {"batch", "warm_steps", "trace_seconds", "correct"},
@@ -98,6 +98,10 @@ def run(run, fam, tracer, t_process):
         f"{median(run.step_s) * 1e3:.2f} ms, loss {run.losses[0]:.4f} -> "
         f"{run.losses[-1]:.4f} (mean of first {k} {sum(head) / k:.4f}, of "
         f"last {k} {sum(tail) / k:.4f})")
+    within(run, "first_loss_diff", first_diff, tol["first_loss_abs"])
+    within(run, "losses_not_finite", run.failed, 0)
+    # falling: the last steps' mean loss strictly under the first steps'
+    within(run, "loss_last_over_first", sum(tail) / sum(head), 1.0)
     run.correct = (first_diff <= tol["first_loss_abs"] and run.failed == 0
                    and falling)
     if not run.correct:

@@ -234,6 +234,15 @@ def pct(values, q):
     return float(np.percentile(np.asarray(values, dtype=float), q))
 
 
+def host_use():
+    """CPU seconds the process has used so far, (user, kernel). A run, or a
+    set-up phase, that is slower on the same work shows here whether the
+    host worked more or waited more."""
+    import resource
+    u = resource.getrusage(resource.RUSAGE_SELF)
+    return (u.ru_utime, u.ru_stime)
+
+
 def median(values):
     return float(statistics.median(values))
 
@@ -312,13 +321,20 @@ def result_line(run, devs, trace_on):
                     f"{sorted(run.e2e)})")
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
     else:
+        took = []
         for m in cell.per_layer:
+            t0 = time.perf_counter()
             value = load_reader(m["name"], cell.layout).read(run)
+            took.append((time.perf_counter() - t0, m["name"]))
             if value is None:
                 say(f"per-layer metric {m['name']}: nothing to read, left "
                     "out")
                 continue
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+        # the first reader that asks for the host plane pays its parse
+        say(f"per-layer readers: {sum(t for t, _ in took):.2f} s in all; "
+            "slowest " + ", ".join(f"{name} {t:.2f} s" for t, name in
+                                   sorted(took, reverse=True)[:3]))
     device = device_report(devs, run.chips)
     out = {"correct": bool(run.correct), "attempted": int(run.attempted),
            "failed": int(run.failed), "metrics": metrics, "device": device}
@@ -344,9 +360,12 @@ def run_cell(name, seed, seconds, trace, layout=None, device_check=None,
         import paddle_tpu  # noqa: F401
     except ImportError as e:
         raise Refused(f"the program under test is not here: {e}") from None
+    t_imported = time.perf_counter()
     devs = (device_check or require_tpu)(cell.chips)
     say(f"imports and devices ready {time.perf_counter() - t_process:.1f} s "
-        "after the process started")
+        f"after the process started (the devices "
+        f"{time.perf_counter() - t_imported:.1f} s of it); "
+        f"{sum(host_use()):.1f} s of CPU")
     say(f"cell {cell.name}: config {cell.config['name']} on {cell.chips} "
         f"chip(s); device {devs[0].platform} {devs[0].device_kind!r} x "
         f"{len(devs)}; seed {seed}, window {seconds} s, trace {int(trace)}")

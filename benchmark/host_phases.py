@@ -32,7 +32,9 @@ round, the training step against the device, and the compile log.
 
 A program that lacks the spans (the parent of the PR that added them)
 gives nothing to read: every function here then returns ``None`` or an
-empty result and never raises.
+empty result and never raises. Cost: as ``host_trace``'s (since PR 40),
+O((ops + spans) log) in the stretch; a chip's op list is merged once,
+never once a step.
 """
 import bisect
 import dataclasses
@@ -299,18 +301,18 @@ def step_host_ms(st):
 def step_gaps_ms(st):
     """{chip name: [ms]}: device idle between the end of one step's
     program and the start of the next step's, and what the join found:
-    ``st.joined`` {chip name: (steps, programs, joined)}."""
+    ``st.joined`` {chip name: (steps, programs, joined)}. The chip's busy
+    list is merged once, as ``host_trace.round_gaps_ms`` does."""
     out = {}
     for chip in st.chips:
-        busy = [(s, e) for _, s, e in chip.ops]
+        merged = trace_reduce.union((s, e) for _, s, e in chip.ops)
         pairs = step_programs(st, chip)
         st.joined[chip.name] = (len(st.steps), len(chip.modules),
                                 len(pairs))
         gaps = []
         for (s0, (_, e0)), (s1, (p1, _)) in zip(pairs, pairs[1:]):
             if s1.stats["step"] == s0.stats["step"] + 1 and p1 > e0:
-                gaps.append(trace_reduce.measure(
-                    trace_reduce.subtract([(e0, p1)], busy)) / 1e6)
+                gaps.append(trace_reduce.idle_within(merged, e0, p1) / 1e6)
         if gaps:
             out[chip.name] = gaps
     return out

@@ -48,11 +48,18 @@ the instruction's).
 A program that lacks the annotations or the kernel names (the parent of
 the PR that added them) gives nothing to read: every function here then
 returns ``None`` or an empty result and never raises.
+
+Cost (since PR 40): O((ops + spans) log) in the stretch, as
+``trace_reduce`` says. A chip's op list is merged once however many rounds
+ask (``round_gaps_ms``, ``idle_gaps``); a program's ops are found by
+bisecting ``Chip.starts``; the waits a gap may overlap by bisection.
+Nothing is repeated per round over the whole op list.
 """
 import bisect
 import collections
 import dataclasses
 import functools
+import itertools
 import os
 
 from . import trace_reduce
@@ -344,10 +351,10 @@ def _join(ht, chip):
     if not match:
         return []
     taken = set(match.values())
+    lo, hi = min(taken), max(taken)
     left_rounds = [rounds[k].stats["round"]
                    for k in range(min(match), max(match)) if k not in match]
-    left_progs = [j for j in cand
-                  if min(taken) < j < max(taken) and j not in taken]
+    left_progs = [j for j in cand if lo < j < hi and j not in taken]
     off = [rounds[k].stats["round"] for k, j in anchors.items()
            if match.get(k) != j]
     if left_rounds or left_progs or off:
@@ -391,18 +398,29 @@ def clock_lag(ht, enqueued, done):
 def round_gaps_ms(ht):
     """Device idle, in ms, between the end of one round's program and the
     start of the next round's, for every such pair in which the serve loop
-    never waited for work (no ``serve.idle_wait`` overlaps the gap)."""
-    waits = [(s.start, s.end) for s in ht.serve if s.name == IDLE_WAIT]
+    never waited for work (no ``serve.idle_wait`` overlaps the gap). The
+    chip's busy list is merged once and each gap read from it, and the
+    waits are looked up by bisection: O((ops + rounds) log) in all, where
+    a ``subtract`` against every op a gap was quadratic in the rounds
+    (PR 39's 846 rounds held a traced run past the driver's limit)."""
+    waits = sorted((s.start, s.end) for s in ht.serve if s.name == IDLE_WAIT)
+    starts = [a for a, _ in waits]
+    reach = list(itertools.accumulate((b for _, b in waits), max))
+
+    def waited(e0, s1):
+        # a wait that opened before the gap closed and ended after it opened
+        i = bisect.bisect_left(starts, s1)
+        return i > 0 and reach[i - 1] > e0
+
     out = []
     for chip in ht.chips:
-        busy = [(s, e) for _, s, e in chip.ops]
+        merged = trace_reduce.union((s, e) for _, s, e in chip.ops)
         joined = round_programs(ht, chip)
         for (r0, (_, e0)), (r1, (s1, _)) in zip(joined, joined[1:]):
             if r1.stats["round"] != r0.stats["round"] + 1 or s1 <= e0 \
-                    or any(a < s1 and b > e0 for a, b in waits):
+                    or waited(e0, s1):
                 continue
-            out.append(trace_reduce.measure(
-                trace_reduce.subtract([(e0, s1)], busy)) / 1e6)
+            out.append(trace_reduce.idle_within(merged, e0, s1) / 1e6)
     return out
 
 

@@ -17,14 +17,13 @@ Definitions (also in README.md):
   failed and its TTFT, if it has none, is the time it waited.
 """
 import gc
-import resource
 import threading
 import time
 
 import numpy as np
 
 from . import traffic
-from .harness import median, pct, say, within
+from .harness import host_use, median, pct, say, within
 
 # keys of a serving mix's file that some code reads (``why``-like prose
 # apart); each kind adds its own. harness.check_keys refuses any other.
@@ -40,14 +39,6 @@ class Sent:
 
     def __init__(self, req, due, sent, client=None):
         self.req, self.due, self.sent, self.client = req, due, sent, client
-
-
-def host_use():
-    """CPU seconds the process has used so far, (user, kernel). A run
-    whose every round is slower on the same work shows here whether the
-    host worked more or waited more."""
-    u = resource.getrusage(resource.RUSAGE_SELF)
-    return (u.ru_utime, u.ru_stime)
 
 
 class GcWatch:
@@ -105,18 +96,16 @@ def run(run, fam, tracer, t_process, loop, closed):
     is the kind's load; ``closed`` says whether the window cuts requests
     (closed loop) or every request of it is drained (open loop)."""
     cfg, wl = run.cell.config, run.cell.workload
-    t0 = time.perf_counter()
+    lap = _Lap()
     model = fam.build_model(cfg, run.seed)
-    say(f"model built in {time.perf_counter() - t0:.1f} s")
-    t0 = time.perf_counter()
+    say(f"model built in {lap()}")
     eng = fam.make_engine(model, cfg)
     pool = eng.kv.nbytes()
-    say(f"engine up in {time.perf_counter() - t0:.1f} s: attention backend "
+    say(f"engine up in {lap()}: attention backend "
         f"{eng.attn_backend!r}, start-up gate {eng.attn_ab}; pool "
         f"{pool / 2**30:.2f} GiB")
-    t0 = time.perf_counter()
     pads = eng.warm_ragged()
-    say(f"warm_ragged: token pads {pads} in {time.perf_counter() - t0:.1f} s")
+    say(f"warm_ragged: token pads {pads} in {lap()}")
     spans = None
     if tracer.on:
         from paddle_tpu.observability import tracing
@@ -129,12 +118,11 @@ def run(run, fam, tracer, t_process, loop, closed):
                 for r in traffic.request_stream(
                     wl, cfg["vocab_size"], run.seed + 1,
                     int(wl["warm_requests"]))]
-        t0 = time.perf_counter()
         for req in [submit(eng, r) for r in warm]:
             req.result(timeout=120.0)
-        say(f"{len(warm)} warm request(s) in "
-            f"{time.perf_counter() - t0:.1f} s")
+        say(f"{len(warm)} warm request(s) in {lap()}")
         with GcWatch() as watch:
+            t_warm = time.perf_counter()
             sent, t_open, t_close, host = loop(run, eng, tracer, t_process)
     finally:
         # the engine first: whatever is still in flight ends here and not
@@ -151,6 +139,9 @@ def run(run, fam, tracer, t_process, loop, closed):
     skew = time.time() - time.perf_counter()
     run.window_wall = (t_open + skew, t_close + skew)
     run.requests = sent
+    say(f"set-up: {run.setup_s:.2f} s, {sum(host[0]):.2f} s of CPU; the "
+        f"kind's own before the window (a collection, a head, a ramp) "
+        f"{t_open - t_warm:.2f} s")
     _metrics(run, sent, t_open, t_close, closed)
     _check(run, fam, model, sent, t_close)
     c = run.counters
@@ -184,6 +175,20 @@ def run(run, fam, tracer, t_process, loop, closed):
             f"{sorted(run.pallas_ops)[:6]} "
             f"({time.perf_counter() - t0:.1f} s)")
         run.trace = tracer.summary(run.pallas_ops)
+
+
+class _Lap:
+    """``lap()`` -> the wall and CPU seconds since the last call, as text:
+    a set-up phase that reads slower on more CPU ran on a slower host."""
+
+    def __init__(self):
+        self.t, self.c = time.perf_counter(), sum(host_use())
+
+    def __call__(self):
+        t, c = time.perf_counter(), sum(host_use())
+        out = f"{t - self.t:.1f} s ({c - self.c:.1f} s of CPU)"
+        self.t, self.c = t, c
+        return out
 
 
 def stop_later(tracer, stretch_s):

@@ -6,6 +6,7 @@ for the host side of the path, and ``BENCHMARK.json``'s new entries
 against the files they name."""
 import json
 import os
+import random
 import sys
 import types
 
@@ -14,7 +15,8 @@ import pytest
 from benchmark import harness, host_phases, host_trace, trace_reduce
 
 from .conftest import ROOT, cpu_devices
-from .test_host_trace import SERVE_CFG, _program, _reader, _run
+from .test_host_trace import (SERVE_CFG, _program, _reader, _run,
+                              count_unions, program_ops)
 
 NEW = ("unattributed_idle_pct.serve", "round_host_cpu_ms.serve",
        "step_host_ms.train", "step_gap_ms.train", "setup_lower_s")
@@ -488,3 +490,65 @@ def test_the_tiny_training_cell_records_its_steps(layout, capsys):
         inside = [p for p in st.phases if p.stats["step"] == s.stats["step"]]
         assert [p.name for p in inside] == list(host_phases.STEP_PHASES)
         assert s.start <= inside[0].start and inside[-1].end <= s.end
+
+
+# -------------------------------------------- step gaps in linear time (PR 40)
+
+def old_step_gaps_ms(st):
+    """``step_gaps_ms`` as it was until PR 40, kept as the oracle: a
+    ``subtract`` against every op of the chip for each gap."""
+    out = {}
+    for chip in st.chips:
+        busy = [(s, e) for _, s, e in chip.ops]
+        pairs = host_phases.step_programs(st, chip)
+        gaps = []
+        for (s0, (_, e0)), (s1, (p1, _)) in zip(pairs, pairs[1:]):
+            if s1.stats["step"] == s0.stats["step"] + 1 and p1 > e0:
+                gaps.append(trace_reduce.measure(
+                    trace_reduce.subtract([(e0, p1)], busy)) / 1e6)
+        if gaps:
+            out[chip.name] = gaps
+    return out
+
+
+def random_steps(rng, monkeypatch, steps, chips=2):
+    """A StepTrace with its joins made: programs of 150-250 ms with nested
+    ops, back to back, overlapping or some ms apart; a step number skipped
+    now and then. ``step_programs`` is made to return the joins."""
+    pairs, t, n = [], 1e6 + rng.random(), 0
+    for _ in range(steps):
+        p0 = t + rng.choice([0.0, -rng.uniform(0, 1e6), rng.uniform(0, 5e6)])
+        p1 = p0 + rng.uniform(150e6, 250e6)
+        pairs.append((host_trace.Span("train_step", p0 - 3e6, p1, {"step": n}),
+                      p0, p1))
+        t, n = p1, n + (2 if rng.random() < 0.05 else 1)
+    chip_list, joins = [], {}
+    for c in range(chips):
+        ops = []
+        for _, p0, p1 in pairs:
+            ops += program_ops(rng, p0 + 7.3 * c, p1 + 7.3 * c, 30)
+        ops.sort(key=lambda e: (e[1], -e[2]))
+        chip_list.append(host_trace.Chip(f"/device:TPU:{c}", ops, []))
+        joins[chip_list[-1].name] = [(s, (p0 + 7.3 * c, p1 + 7.3 * c))
+                                     for s, p0, p1 in pairs]
+    monkeypatch.setattr(host_phases, "step_programs",
+                        lambda st, chip: joins[chip.name])
+    return host_phases.StepTrace(chip_list, [s for s, _, _ in pairs], [],
+                                 chip_list[0].ops[0][1], t)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_step_gaps_are_the_old_formulas_number_for_number(seed, monkeypatch):
+    st = random_steps(random.Random(seed), monkeypatch, steps=60)
+    gaps = host_phases.step_gaps_ms(st)
+    assert gaps == old_step_gaps_ms(st)         # equal, not approximately
+    assert set(gaps) == {c.name for c in st.chips}
+
+
+def test_step_gaps_merge_the_op_list_once_a_chip(monkeypatch):
+    calls = count_unions(monkeypatch)
+    for steps in (8, 200):
+        st = random_steps(random.Random(steps), monkeypatch, steps=steps)
+        calls.clear()
+        assert host_phases.step_gaps_ms(st)
+        assert len(calls) == 2

@@ -3,13 +3,15 @@ enough to work out by hand, on a recorded cut of a real v5e traced run of
 ``decode-sat`` (host plane included), and on the tiny CPU cell's own
 profile for the host side of the path."""
 import os
+import random
 import shutil
 import statistics
+import time
 import types
 
 import pytest
 
-from benchmark import harness, host_trace, trace_reduce
+from benchmark import harness, host_phases, host_trace, trace_reduce
 
 from .conftest import cpu_devices
 
@@ -365,6 +367,7 @@ def test_recorded_decode_sat_rounds(tmp_path):
         [k + 1 for k in host_trace.ints(joined[0][0].stats["kv_lens"])]
     gaps = host_trace.round_gaps_ms(ht)
     assert gaps == pytest.approx([3.445693, 4.003831, 3.679789])
+    assert gaps == old_round_gaps_ms(ht)
     assert _reader("round_gap_ms").read(run) == statistics.median(gaps)
     by = host_trace.idle_by_phase(ht)
     idle = sum(by.values())
@@ -406,7 +409,8 @@ def test_annotations_of_the_tiny_cell_reach_the_profile(layout):
     """The host side of the path, for real: a traced run of the tiny
     closed-loop cell on the CPU leaves a profile whose ``/host:CPU`` plane
     holds the serve thread's ``decode_round`` annotations with their
-    stats and the five phases inside them. (No TPU plane: the four
+    stats and inside them the phases ``host_phases.ROUND_PHASES`` lists
+    (six since PR 37). (No TPU plane: the four
     trace-sourced readers return None here, ``test_cells`` checks.)"""
     from jax.profiler import ProfileData
     line = harness.run_cell("tiny-serve.closed", seed=11, seconds=1.0,
@@ -435,7 +439,123 @@ def test_annotations_of_the_tiny_cell_reach_the_profile(layout):
         assert len(host_trace.ints(stats["kv_lens"])) == len(rows)
         inside = [e for e in serve[0] if e[0].startswith("round.")
                   and e[3].get("round") == stats["round"]]
-        assert [e[0] for e in inside] == [
-            "round.schedule", "round.assemble", "round.launch",
-            "round.fetch", "round.emit"]
+        assert [e[0] for e in inside] == list(host_phases.ROUND_PHASES)
         assert start <= inside[0][1] and inside[-1][2] <= end
+
+
+# ------------------------------------------- round gaps in linear time (PR 40)
+
+def old_round_gaps_ms(ht):
+    """``round_gaps_ms`` as it was until PR 40, kept as the oracle: a
+    ``subtract`` against every op of the chip for each gap (which merges
+    the whole op list again) and a scan of every wait for each gap."""
+    waits = [(s.start, s.end) for s in ht.serve
+             if s.name == host_trace.IDLE_WAIT]
+    out = []
+    for chip in ht.chips:
+        busy = [(s, e) for _, s, e in chip.ops]
+        joined = host_trace.round_programs(ht, chip)
+        for (r0, (_, e0)), (r1, (s1, _)) in zip(joined, joined[1:]):
+            if r1.stats["round"] != r0.stats["round"] + 1 or s1 <= e0 \
+                    or any(a < s1 and b > e0 for a, b in waits):
+                continue
+            out.append(trace_reduce.measure(
+                trace_reduce.subtract([(e0, s1)], busy)) / 1e6)
+    return out
+
+
+def program_ops(rng, p0, p1, n):
+    """``n`` ops of a program at float times: one op over the whole
+    program holding the rest (nested), some of them of no length, and
+    now and then a copy that runs on past the program's end."""
+    ops = [("%while.1", p0, p1)]
+    cuts = sorted(rng.uniform(p0, p1) for _ in range(n - 1))
+    for i, (a, b) in enumerate(zip([p0] + cuts, cuts + [p1])):
+        if i == n - 1:
+            break
+        ops.append((f"%fusion.{i}", a, a if rng.random() < 0.05 else b))
+    if rng.random() < 0.2:
+        ops[-1] = ("%copy-done.1", ops[-1][1],
+                   p1 + rng.uniform(0, 2e6))
+    return ops
+
+
+def random_serving(rng, rounds, ops_a_round=20, chips=2):
+    """A HostTrace of ``chips`` chips with its joins made (what
+    ``round_programs`` would return), so that only the gaps are read:
+    programs back to back, with no gap, overlapping the one before or a
+    few ms apart; a round number skipped now and then; a foreign op in
+    some gaps; ``serve.idle_wait`` spans that cover a gap, reach into it
+    from either side, or lie between rounds apart from any gap."""
+    serve, progs, t, n = [], [], 1e6 + rng.random(), 100
+    for _ in range(rounds):
+        gap = rng.choice([0.0, -rng.uniform(0, 1e6), rng.uniform(0, 4e6)])
+        p0 = t + gap
+        p1 = p0 + rng.uniform(2e6, 9e6)
+        progs.append((n, p0, p1))
+        serve.append(host_trace.Span(host_trace.ROUND, p0 - 1e6, p1 + 5e5,
+                                     {"round": n}))
+        if rng.random() < 0.3:
+            a = rng.uniform(t - 2e6, t + 4e6)
+            serve.append(host_trace.Span(host_trace.IDLE_WAIT, a,
+                                         a + rng.uniform(0, 3e6), {}))
+        t, n = p1, n + (2 if rng.random() < 0.05 else 1)
+    serve.sort(key=lambda s: (s.start, -s.end))
+    chip_list, joins = [], {}
+    for c in range(chips):
+        shift = c * 13.7
+        ops = []
+        for k, (_, p0, p1) in enumerate(progs):
+            ops += program_ops(rng, p0 + shift, p1 + shift, ops_a_round)
+            if k and rng.random() < 0.1:     # a foreign op in the gap
+                a = progs[k - 1][2] + shift
+                ops.append(("%fusion.99", a + 10.5, a + 20.25))
+        ops.sort(key=lambda e: (e[1], -e[2]))
+        chip = host_trace.Chip(f"/device:TPU:{c}", ops, [])
+        chip_list.append(chip)
+        rnds = [s for s in serve if s.name == host_trace.ROUND]
+        joins[chip.name] = [(r, (p0 + shift, p1 + shift))
+                            for r, (_, p0, p1) in zip(rnds, progs)]
+    ht = host_trace.HostTrace(chip_list, serve, chip_list[0].ops[0][1],
+                              max(e for c in chip_list for _, _, e in c.ops))
+    ht.joins = joins
+    return ht
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_round_gaps_are_the_old_formulas_number_for_number(seed):
+    ht = random_serving(random.Random(seed), rounds=120)
+    gaps = host_trace.round_gaps_ms(ht)
+    assert gaps == old_round_gaps_ms(ht)        # equal, not approximately
+    # some pairs are read, the rest left out (overlapping or touching
+    # programs, a wait, a round number skipped); two chips
+    assert 0 < len(gaps) < 2 * 119
+
+
+def count_unions(monkeypatch):
+    """-> the list that ``trace_reduce.union`` appends to at each call."""
+    calls, union = [], trace_reduce.union
+    monkeypatch.setattr(trace_reduce, "union",
+                        lambda intervals: calls.append(1) or union(intervals))
+    return calls
+
+
+def test_the_op_list_is_merged_once_a_chip(monkeypatch):
+    calls = count_unions(monkeypatch)
+    for rounds in (10, 300):
+        ht = random_serving(random.Random(rounds), rounds=rounds, chips=2)
+        calls.clear()
+        assert host_trace.round_gaps_ms(ht)
+        assert len(calls) == 2
+
+
+def test_two_thousand_rounds_of_five_hundred_ops_in_seconds():
+    """PR 39's engine gave 846 rounds in an 8 s stretch; the old reader
+    took minutes there. 2,000 rounds of 500 ops: well under 5 s here."""
+    ht = random_serving(random.Random(7), rounds=2000, ops_a_round=500,
+                        chips=1)
+    assert len(ht.chips[0].ops) > 1_000_000
+    t0 = time.perf_counter()
+    gaps = host_trace.round_gaps_ms(ht)
+    took = time.perf_counter() - t0
+    assert len(gaps) > 300 and took < 5.0, took

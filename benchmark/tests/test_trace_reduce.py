@@ -106,6 +106,34 @@ def test_interval_arithmetic():
         not tr.is_collective("fusion.9")
 
 
+@pytest.mark.parametrize("seed", range(6))
+def test_idle_within_is_subtract_to_the_bit(seed):
+    """``idle_within`` on the merged list against PR 39's formula,
+    ``measure(subtract([(a, b)], busy))``, over nested, touching,
+    zero-length and overlapping intervals at float times: equal, not
+    approximately equal."""
+    import random
+    rng = random.Random(4000 + seed)
+    busy = []
+    for _ in range(300):
+        s = rng.uniform(0, 1e6)
+        e = s + rng.choice([0.0, rng.uniform(0, 50), rng.uniform(0, 5000)])
+        busy.append((s, e))
+        if rng.random() < 0.3:          # a child inside, or one touching
+            busy.append(((s + e) / 2, e) if rng.random() < 0.5
+                        else (e, e + rng.choice([0.0, 7.25])))
+    merged = tr.union(busy)
+    cuts = [rng.uniform(-1e4, 1.1e6) for _ in range(400)] + \
+        [x for iv in merged[:20] for x in iv]
+    for _ in range(600):
+        a, b = rng.choice(cuts), rng.choice(cuts)
+        for lo, hi in ((a, b), (b, a), (a, a)):
+            assert tr.idle_within(merged, lo, hi) == \
+                tr.measure(tr.subtract([(lo, hi)], busy))
+    assert tr.idle_within([], 2.0, 5.5) == 3.5
+    assert tr.idle_within(tr.union([(0, 2), (2, 3), (5, 12)]), 0, 10) == 2
+
+
 def test_pallas_instructions_from_hlo_text():
     text = '''
   %fusion.3 = bf16[8]{0} fusion(%p), kind=kLoop

@@ -69,7 +69,11 @@ and ``serve.turn``:
     longer than one token times those layers (prompt chunks; 0 in a
     decode-only round), defined by the rows alone: it is also what
     ``ops/pallas/kda_ragged.py`` takes in blocks through the matrix unit.
-    No metric reads it yet.
+    No metric reads it yet. Where layers keep keys and values by head,
+    ``attn_items`` and ``attn_page_blocks``: the work items of
+    ``ops/pallas/ragged_attention.py`` that read a page and the page
+    blocks they fetch, a layer, defined by the rows and the token pad
+    alone (``item_plan``). No metric reads them.
 ``round.schedule``
     ``scheduler.schedule()``, ``ensure_decode_capacity()``, admission and
     eviction bookkeeping.

@@ -21,7 +21,9 @@ pools as a dict ``name -> [P, page, *row]``.
   ``ops/pallas/kda_ragged.py``.
 
 Each class also says what a layer of its kind has to read in a round
-(``rows_read``), under the name the ``decode_round`` span carries it.
+(``rows_read``), under the name the ``decode_round`` span carries it;
+``kv`` also says what its kernel's work items make of the round
+(``work``).
 """
 from __future__ import annotations
 
@@ -29,6 +31,7 @@ import jax
 import numpy as np
 
 from ..ops.pallas import _common as _gate
+from ..ops.pallas.ragged_attention import item_plan, item_schedule
 from .ragged_attention import (ab_compare_ragged, pad_total_tokens,
                                ragged_paged_attention,
                                sharded_ragged_attention)
@@ -49,6 +52,16 @@ class KVAttention:
         """-> (the key of ``decode_round``'s row count, the rows a layer
         reads for these launched rows)."""
         return "kv_rows", int(np.sum(kv_lens))
+
+    def work(self, row_lens, kv_lens, tokens, page_size):
+        """What the Pallas kernel makes of these launched rows at token pad
+        ``tokens``, a layer, from the plan alone (whatever backend runs):
+        ``attn_items``, its work items that read a page, and
+        ``attn_page_blocks``, the page blocks they fetch."""
+        block_q, pages = item_schedule(page_size, self.spec.query[1], tokens)
+        items, blocks = item_plan(row_lens, kv_lens, page_size, block_q,
+                                  pages)
+        return {"attn_items": items, "attn_page_blocks": blocks}
 
     def check_mesh(self, degree, axis):
         heads, kv_heads = self.spec.query[0], self.spec.rows["k"][0]

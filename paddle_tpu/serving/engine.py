@@ -606,6 +606,10 @@ class ServingEngine:
             for a in self._attentions:
                 name, rows = a.rows_read(row_lens[:n], kv_lens[:n])
                 rows_read[name] = rows_read.get(name, 0) + rows
+                if a.kind == "kv":
+                    # ... and the kernel's work items over them
+                    rows_read.update(a.work(row_lens[:n], kv_lens[:n], T,
+                                            self.page_size))
             if self._state is not None:
                 # ... and the states it reads and writes: one a row and
                 # state layer

@@ -240,25 +240,73 @@ def test_paged_attention_padded_table_and_zero_context():
                                rtol=1e-6, atol=1e-6)
 
 
-def test_ragged_paged_attention_kernel_matches_reference():
-    """ISSUE 13: the flat-token ragged kernel (interpret mode) matches
-    the gather/segment reference on a mixed launch — a decode row, a
-    whole-prompt prefill, a mid-stream chunk continuation, GQA pools,
-    an unused row and padded tail tokens (zeroed, never NaN)."""
+# rounds of the ragged kernel: (token pad, page size, rows as (query
+# tokens, context after them), 0 tokens = an unused row); heads of 128 as
+# GPT's, 4 query heads over 2 KV heads
+RAGGED_ROUNDS = {
+    "decode_rows": (8, 16, [(1, 1), (1, 16), (1, 17), (1, 300)]),
+    "one_chunk_of_64": (64, 16, [(64, 64)]),
+    "decode_and_chunks": (128, 16, [(1, 20), (1, 129), (64, 64),
+                                    (1, 7), (40, 190)]),
+    # 13 tokens ending at 301: the last of its three 8-page blocks holds
+    # three pages, the last page 13 of its 16 slots
+    "prompt_tail": (32, 16, [(13, 301)]),
+    "row_longer_than_block_q": (64, 16, [(1, 33), (50, 170)]),
+    # rows after the last used one unused, 21 pad tokens after its tokens
+    "unused_and_pad_rows": (32, 16, [(1, 40), (9, 9), (1, 3), (0, 0),
+                                     (0, 0), (0, 0)]),
+    "pad_8": (8, 16, [(1, 100), (5, 5), (2, 60)]),
+    "pad_16": (16, 16, [(1, 100), (12, 40), (1, 2)]),
+    "pad_32": (32, 16, [(1, 100), (25, 25), (1, 2)]),
+    "pad_64": (64, 16, [(1, 100), (33, 80), (20, 20)]),
+    "pad_128": (128, 16, [(1, 100), (64, 140), (40, 40), (1, 65)]),
+    "page_256": (32, 256, [(1, 300), (20, 700), (1, 256), (0, 0)]),
+}
+
+
+@pytest.mark.parametrize("case", ["mixed"] + list(RAGGED_ROUNDS))
+def test_ragged_paged_attention_kernel_matches_reference(case):
+    """The ragged kernel (interpret mode) matches the gather/segment
+    reference: ``mixed`` is a small mixed launch (a decode row, a
+    whole-prompt prefill, a mid-stream chunk continuation, GQA pools, an
+    unused row and padded tail tokens); the others are rounds of work items at heads of
+    128: decode rows, chunks, a prompt tail behind a cached head whose
+    last page block is partly filled, rows longer than an item, unused
+    rows, every pad of the engine's ladder and pages of 16 and 256. Pad
+    tokens come back zeroed, never NaN."""
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas.ragged_attention import (
         ragged_paged_attention, ragged_paged_attention_reference,
         ragged_row_index,
     )
     rng = np.random.RandomState(4)
-    H, KVH, D, PS, NP, MP, T = 4, 2, 32, 4, 16, 4, 16
+    if case == "mixed":
+        H, KVH, D, PS, NP, MP, T = 4, 2, 32, 4, 16, 4, 16
+        bt = jnp.asarray(rng.randint(1, NP, size=(4, MP)), jnp.int32)
+        rs = jnp.asarray([0, 1, 6, T], jnp.int32)   # row 3 unused
+        rl = jnp.asarray([1, 5, 3, 0], jnp.int32)
+        kl = jnp.asarray([7, 5, 9, 0], jnp.int32)
+        used = 9
+    else:
+        T, PS, rows = RAGGED_ROUNDS[case]
+        H, KVH, D = 4, 2, 128
+        MP = max(-(-kv // PS) for _, kv in rows)
+        # every row its own pages, the scrap page 0 behind its table
+        NP = 1 + len(rows) * MP
+        bt = np.zeros((len(rows), MP), np.int32)
+        for i, (_, kv) in enumerate(rows):
+            n = -(-kv // PS)
+            bt[i, :n] = 1 + rng.permutation(len(rows) * MP)[:n]
+        starts = np.cumsum([0] + [n for n, _ in rows])[:-1]
+        rs = jnp.asarray([s if n else T for s, (n, _) in zip(starts, rows)],
+                         jnp.int32)
+        rl = jnp.asarray([n for n, _ in rows], jnp.int32)
+        kl = jnp.asarray([kv for _, kv in rows], jnp.int32)
+        bt = jnp.asarray(bt)
+        used = int(np.sum(rl))
     q = jnp.asarray(rng.randn(T, H, D), jnp.float32)
     kc = jnp.asarray(rng.randn(NP, PS, KVH, D), jnp.float32)
     vc = jnp.asarray(rng.randn(NP, PS, KVH, D), jnp.float32)
-    bt = jnp.asarray(rng.randint(1, NP, size=(4, MP)), jnp.int32)
-    rs = jnp.asarray([0, 1, 6, T], jnp.int32)   # row 3 unused (sentinel)
-    rl = jnp.asarray([1, 5, 3, 0], jnp.int32)
-    kl = jnp.asarray([7, 5, 9, 0], jnp.int32)
     ref = np.asarray(
         ragged_paged_attention_reference(q, kc, vc, rs, rl, kl, bt))
     out = np.asarray(
@@ -266,12 +314,51 @@ def test_ragged_paged_attention_kernel_matches_reference():
                                interpret=True))
     assert np.isfinite(out).all()
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(out[9:], 0.0)    # padded tail zeroed
-    # the shared segment decomposition is the contract both sides use
-    rid, pos, valid = ragged_row_index(rs, rl, kl, T)
-    assert np.asarray(rid)[:9].tolist() == [0, 1, 1, 1, 1, 1, 2, 2, 2]
-    assert np.asarray(pos)[:9].tolist() == [6, 0, 1, 2, 3, 4, 6, 7, 8]
-    assert not bool(np.asarray(valid)[9:].any())
+    np.testing.assert_allclose(out[used:], 0.0)    # padded tail zeroed
+    if case == "mixed":
+        # the shared segment decomposition is the contract both sides use
+        rid, pos, valid = ragged_row_index(rs, rl, kl, T)
+        assert np.asarray(rid)[:9].tolist() == [0, 1, 1, 1, 1, 1, 2, 2, 2]
+        assert np.asarray(pos)[:9].tolist() == [6, 0, 1, 2, 3, 4, 6, 7, 8]
+        assert not bool(np.asarray(valid)[9:].any())
+
+
+def test_ragged_item_schedule_follows_the_shapes():
+    """``item_schedule`` reads the page size, the head width and the token
+    pad and nothing else: the same shapes give the same blocks, a decode
+    pad small items and a chunk pad larger ones, a step some 128 keys of
+    a 128-wide head. The launch's item count is ``rows + T // block_q``,
+    and an unused item plans no page."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas.mla_ragged_attention import work_items
+    from paddle_tpu.ops.pallas.ragged_attention import (item_plan,
+                                                        item_schedule)
+    assert item_schedule(16, 128, 32) == item_schedule(16, 128, 32) == (8, 8)
+    assert item_schedule(16, 128, 128) == (32, 8)
+    assert item_schedule(16, 128, 8) == (8, 8)
+    assert item_schedule(256, 128, 64) == (32, 1)
+    assert item_schedule(16, 64, 32) == (8, 16)
+    assert item_schedule(16, 128, 4) == (4, 8)
+    for T, rows in ((32, [(1, 30), (1, 200), (0, 0)]),
+                    (128, [(1, 30), (64, 64), (40, 301), (0, 0)])):
+        block_q, pages = item_schedule(16, 128, T)
+        rs = np.cumsum([0] + [n for n, _ in rows])[:-1]
+        rl = np.array([n for n, _ in rows], np.int32)
+        kl = np.array([kv for _, kv in rows], np.int32)
+        row, pos0, nq, tok = work_items(
+            jnp.asarray(np.where(rl > 0, rs, T), jnp.int32),
+            jnp.asarray(rl), jnp.asarray(kl), T, block_q)
+        assert row.shape[0] == len(rows) + T // block_q
+        nq, pos0 = np.asarray(nq), np.asarray(pos0)
+        # the pages each item reads: its last token's, none if unused
+        n_pages = np.where(nq > 0, (pos0 + nq - 1) // 16 + 1, 0)
+        assert (n_pages[nq == 0] == 0).all()
+        items, blocks = item_plan(rl, kl, 16, block_q, pages)
+        assert items == int((nq > 0).sum())
+        assert blocks == int(np.sum(-(-n_pages // pages)))
+    # a decode row of 300: 19 pages, three blocks; a 40-token tail ending
+    # at 301 at block_q 32: two items, both three blocks
+    assert item_plan([1, 40, 0], [300, 301, 0], 16, 32, 8) == (3, 9)
 
 
 def test_asp_indivisible_dim_warns():

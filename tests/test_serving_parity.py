@@ -277,6 +277,41 @@ def test_sharded_ragged_attention_parity(kv_heads):
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("kv_heads", [8, 2], ids=["mha", "gqa"])
+def test_sharded_ragged_kernel_parity(kv_heads, monkeypatch):
+    """The Pallas kernel (interpret mode) under the same 2-device 'model'
+    mesh: each shard's work items over its KV heads' slice of the pages
+    reproduce the unsharded reference, chunk rows of several items and
+    pad tokens included."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+    from paddle_tpu.ops.pallas import ragged_attention as ops
+    from paddle_tpu.serving import ragged_attention as serving
+    monkeypatch.setattr(serving, "_pallas_ragged", functools.partial(
+        ops.ragged_paged_attention, interpret=True))
+    rng = np.random.RandomState(13)
+    H, KVH, D, P, page, maxp, R, T = 8, kv_heads, 8, 16, 4, 4, 3, 32
+    q = jnp.asarray(rng.randn(T, H, D).astype("float32"))
+    kp = jnp.asarray(rng.randn(P, page, KVH, D).astype("float32"))
+    vp = jnp.asarray(rng.randn(P, page, KVH, D).astype("float32"))
+    bt = jnp.asarray(rng.randint(1, P, size=(R, maxp)).astype("int32"))
+    # a decode row, a fresh 12-token prefill (two items of 8), a chunk
+    # continuation at 6; tokens 16.. are padding
+    rs = jnp.asarray(np.array([0, 1, 13], np.int32))
+    rl = jnp.asarray(np.array([1, 12, 3], np.int32))
+    kl = jnp.asarray(np.array([7, 12, 9], np.int32))
+    ref = np.asarray(ops.ragged_paged_attention_reference(
+        q, kp, vp, rs, rl, kl, bt))
+    mesh = Mesh(np.array(jax.devices()[:2]), ("model",))
+    out = np.asarray(serving.sharded_ragged_attention(
+        mesh, backend="pallas")(q, kp, vp, rs, rl, kl, bt))
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(out[16:], 0.0)
+
+
 @pytest.mark.slow
 def test_ragged_kills_bucket_matrix_on_mixed_length_workload(
         seeded_model):

@@ -367,3 +367,38 @@ def test_the_rounds_body_lowers_with_seven_arguments(models, family):
     assert set(aux) == set(extras["aux"])
     eng.warm_ragged(16)
     assert eng.compiled_text(16).count("ENTRY") == 1
+
+
+def test_a_traced_round_counts_the_ragged_kernels_work_items(models):
+    """``attn_items`` / ``attn_page_blocks`` on ``decode_round``: the work
+    items of the GPT kernel that read a page and the page blocks they
+    fetch, a layer, from the round's rows and token pad as
+    ``item_plan`` plans them, whatever backend runs (here the XLA
+    reference). A prompt of 21 tokens goes as chunks of 8, 8 and 5 (one
+    item each at a pad of 8), the second prompt's 5 tokens beside its
+    first decode row; a decode row is one item; every item here reads its
+    pages in one block (gpt_tiny's heads are 16 wide: 1,024 keys a
+    block)."""
+    from paddle_tpu.ops.pallas.ragged_attention import (item_plan,
+                                                        item_schedule)
+    eng = _engine(models, "gpt")
+    spans = tracing.start()
+    try:
+        for a, b in ((0, 21), (30, 35)):
+            eng.submit(IDS[a:b].tolist(), max_new_tokens=3)
+        eng.run_until_idle()
+        rounds = [e["args"] for e in spans.events
+                  if e.get("ph") == "X" and e["name"] == "decode_round"
+                  and "pad" in e["args"]]
+    finally:
+        tracing.stop()
+    head_dim = models["gpt"].config.hidden_size \
+        // models["gpt"].config.num_heads
+    for r in rounds:
+        block_q, pages = item_schedule(4, head_dim, r["pad"])
+        assert (r["attn_items"], r["attn_page_blocks"]) == item_plan(
+            r["row_lens"], r["kv_lens"], 4, block_q, pages)
+    assert [r["row_lens"] for r in rounds] == [[8], [8], [5], [1, 5],
+                                               [1, 1], [1]]
+    assert [r["attn_items"] for r in rounds] == [1, 1, 1, 2, 2, 1]
+    assert [r["attn_page_blocks"] for r in rounds] == [1, 1, 1, 2, 2, 1]

@@ -414,9 +414,9 @@ def test_paged_decode_attention(one_chip):
              ((b, MAX_PAGES), jnp.int32), ((b,), jnp.int32))
 
 
-# the round sizes of the smoke's engine (8 slots + a 64-token chunk pads
-# to 128) and a larger one
-@pytest.mark.parametrize("tokens", [128, 256])
+# a decode round's pad (items of 8 tokens), the round sizes of the smoke's
+# engine (8 slots + a 64-token chunk pads to 128) and a larger one
+@pytest.mark.parametrize("tokens", [32, 128, 256])
 def test_ragged_paged_attention(one_chip, tokens):
     from paddle_tpu.ops.pallas.ragged_attention import \
         ragged_paged_attention
